@@ -92,7 +92,7 @@ RUNS = {
         "config": "veles_tpu/samples/cifar_config.py",
         # the r4 low-data recipe (VERDICT r3 #6): in-graph flip/crop/
         # cutout augmentation + cosine LR + longer patience — measured
-        # 23.4% in the round-4 tuning run (ROUND4_NOTES.md §5), well
+        # 23.4% in the round-4 tuning run, well
         # inside (and past) the published 35.10 band the bare recipe
         # missed by 8pp
         "overrides": (
@@ -106,7 +106,7 @@ RUNS = {
             "'lr_schedule': 'cosine',"
             # warmup de-risks the strict-relu plateau: without it the
             # default seed can sit at chance for 60+ epochs (the
-            # escape is luck; ROUND4_NOTES.md §5)
+            # escape is luck)
             "'lr_schedule_params': {'total_steps': 15000,"
             "                       'floor': 0.05, 'warmup': 500},"
             "'snapshot_time_interval': 1e9})"),

@@ -1,7 +1,7 @@
 """Native pallas flash-attention kernels (ops/pallas_attention.py) —
 exactness against the dense reference, fwd and all three gradients,
-causal and not (interpret mode on the CPU mesh; the real-TPU numbers
-live in ROUND4_NOTES.md)."""
+causal and not (interpret mode on the CPU mesh; chip_smoke.py's
+``kernels`` phase holds the same comparison on a real TPU)."""
 
 import jax
 import jax.numpy as jnp
@@ -80,6 +80,30 @@ def test_mha_apply_pallas_impl():
     ref = mha_apply(params, x, heads, True, attn_impl="dense")
     numpy.testing.assert_allclose(numpy.asarray(out),
                                   numpy.asarray(ref), atol=5e-2)
+
+
+def test_mha_apply_kernel_core_runs_per_shard_under_a_mesh():
+    """Under the trainer's mesh the kernel cores run inside shard_map
+    (a Mosaic call cannot be partitioned by GSPMD): same numbers as
+    the unsharded call, batch over dp and heads over tp."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from veles_tpu.models.attention import mha_apply
+    from veles_tpu.parallel import build_mesh
+    rng = numpy.random.default_rng(2)
+    d, heads = 32, 2
+    x = jnp.asarray(rng.normal(size=(4, 32, d)), jnp.float32)
+    params = {n: jnp.asarray(rng.normal(size=(d, d)) * 0.2,
+                             jnp.float32)
+              for n in ("wq", "wk", "wv", "wo")}
+    ref = mha_apply(params, x, heads, True, attn_impl="pallas")
+    mesh = build_mesh({"dp": 2, "tp": 2}, devices=jax.devices()[:4])
+    fn = jax.jit(lambda p, x: mha_apply(
+        p, x, heads, True, attn_impl="pallas", sp_mesh=mesh))
+    out = fn(params, jax.device_put(
+        x, NamedSharding(mesh, P("dp"))))
+    numpy.testing.assert_allclose(numpy.asarray(out),
+                                  numpy.asarray(ref), atol=1e-5)
 
 
 class TestOddLengthsAndDmaSkip:

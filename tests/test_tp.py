@@ -95,6 +95,11 @@ def test_tp_specs_and_gate(f32, spec_trained_chain):
     dense = InferenceScheduler(fw, max_slots=2, window=64, tp=2,
                                kv="dense", warm_buckets=False)
     assert dense.tp == 0
+    # more shards than devices is NOT a degrade: the caller sized the
+    # model for 1/tp of it per chip
+    with pytest.raises(ValueError, match="tp=16 needs 16 devices"):
+        InferenceScheduler(fw, max_slots=2, window=64, tp=16,
+                           warm_buckets=False)
     # config keys are declared with the documented defaults
     assert root.common.serving.tp == 0
     assert root.common.serving.role == "both"

@@ -1,0 +1,154 @@
+"""Main-path kernels compiled for a DESCRIBED TPU v5e at real widths.
+
+The only file that describes the chip.  The TPU compiler is installed
+beside the CPU backend and compiles for a topology that is described,
+not attached: a kernel the Mosaic lowering refuses (block shapes off the
+(8, 128) tiling, VMEM budget, unsupported ops) fails HERE instead of on
+the first chip run.  Interpret-mode parity lives with each kernel's own
+tests; nothing below runs a kernel, so nothing below is a result or a
+time.
+
+The topology is described inside a module-scoped fixture and nowhere
+else — only one process may load libtpu, and every xdist worker imports
+every test file.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A described-device executable is written to the persistent
+    cache but cannot be read back without a chip (the next compile
+    warns and redoes it) — keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    saved = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", saved)
+    compilation_cache.reset_cache()
+
+
+def _attention(grad, shape):
+    from veles_tpu.ops.pallas_attention import pallas_attention
+    fwd = functools.partial(pallas_attention, causal=True, backend="tpu")
+    if grad:
+        def fn(q, k, v):
+            return jax.grad(
+                lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                argnums=(0, 1, 2))(q, k, v)
+    else:
+        fn = fwd
+    return fn, [(shape, jnp.bfloat16)] * 3
+
+
+def _paged(quant, k1):
+    """The serving decode (K1 = 1) / verify (K1 = spec_k + 1) shapes:
+    B 8, d 1024, 8 heads, block 16, a 1024-block pool, 64-block
+    tables (window 1024); pools in the compute dtype or int8."""
+    from veles_tpu.ops.pallas_paged import pallas_paged_attend
+    b, d, heads, bs, nb, t = 8, 1024, 8, 16, 1024, 64
+    pool = ((nb, bs, d), jnp.int8 if quant else jnp.bfloat16)
+    args = [((b, k1, d), jnp.bfloat16), pool, pool,
+            ((b, t), jnp.int32), ((b, k1), jnp.int32)]
+    if quant:
+        args += [((nb, bs), jnp.float32)] * 2
+
+        def fn(q, pk, pv, tables, qpos, sk, sv):
+            return pallas_paged_attend(q, pk, pv, tables, qpos, heads,
+                                       scale_k=sk, scale_v=sv,
+                                       backend="tpu")
+    else:
+        def fn(q, pk, pv, tables, qpos):
+            return pallas_paged_attend(q, pk, pv, tables, qpos, heads,
+                                       backend="tpu")
+    return fn, args
+
+
+def _int8_matmul():
+    from veles_tpu.ops.gemm import int8_matmul
+    return (functools.partial(int8_matmul, backend="tpu"),
+            [((1, 1024), jnp.bfloat16), ((1024, 32768), jnp.int8),
+             ((32768,), jnp.float32)])
+
+
+def _flash():
+    from veles_tpu.ops.flash import flash_attention
+    return (functools.partial(flash_attention, causal=True,
+                              backend="tpu"),
+            [((4, 2048, 16, 128), jnp.bfloat16)] * 3)
+
+
+CASES = {
+    "attention_fwd_4x2048x16x128":
+        functools.partial(_attention, False, (4, 2048, 16, 128)),
+    "attention_grad_4x2048x16x128":
+        functools.partial(_attention, True, (4, 2048, 16, 128)),
+    "attention_fwd_8x1024x8x128":
+        functools.partial(_attention, False, (8, 1024, 8, 128)),
+    "attention_grad_8x1024x8x128":
+        functools.partial(_attention, True, (8, 1024, 8, 128)),
+    "paged_fp_k1": functools.partial(_paged, False, 1),
+    "paged_fp_k5": functools.partial(_paged, False, 5),
+    "paged_int8_k1": functools.partial(_paged, True, 1),
+    "paged_int8_k5": functools.partial(_paged, True, 5),
+    "int8_matmul_1x1024x32768": _int8_matmul,
+    "flash_4x2048x16x128": _flash,
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(case, one_chip, no_compile_cache):
+    fn, args = CASES[case]()
+    shapes = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+              for s, dt in args]
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_attention_kernel_partitions_over_a_dp_mesh(topo,
+                                                    no_compile_cache):
+    """GSPMD refuses to partition a Mosaic call, so under the
+    trainer's mesh ``mha_apply`` runs the kernel per shard: the d1024
+    block's attention, forward and backward, global batch 8 over the
+    four described chips."""
+    import numpy
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from veles_tpu.models.attention import mha_apply
+    mesh = Mesh(numpy.array(topo.devices), ("dp",))
+    rep = NamedSharding(mesh, P())
+    x = jax.ShapeDtypeStruct((8, 1024, 1024), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("dp")))
+    params = {n: jax.ShapeDtypeStruct((1024, 1024), jnp.float32,
+                                      sharding=rep)
+              for n in ("wq", "wk", "wv", "wo")}
+
+    def loss(params, x):
+        return mha_apply(params, x, 8, True, sp_mesh=mesh,
+                         backend="tpu").astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss)).lower(params, x).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-reduce" in text

@@ -28,36 +28,20 @@ from veles_tpu.snapshotter import SnapshotterToFile
 
 
 def _enable_compilation_cache(path):
-    """Point jax at a persistent on-disk compilation cache: the first
-    run writes compiled executables there, every later CLI launch
-    loads them back instead of recompiling (compile_tracker labels
-    those loads ``cache="hit"`` in ``veles_jit_compiles_total``).
-    The thresholds are dropped to zero because CLI runs re-pay even
-    sub-second compiles on every launch; each knob is best-effort
-    across jax versions."""
+    """``--compilation-cache DIR``: the explicit placement of the
+    persistent XLA compile cache (the first run writes compiled
+    executables there, every later launch loads them back —
+    compile_tracker labels those loads ``cache="hit"`` in
+    ``veles_jit_compiles_total``).  ``JAX_COMPILATION_CACHE_DIR``
+    still wins when set; see
+    ``accelerated_units.enable_persistent_compile_cache``."""
+    from veles_tpu.accelerated_units import (
+        enable_persistent_compile_cache)
     import jax
-    log = logging.getLogger("Main")
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(path))
-    except Exception as e:  # pragma: no cover - ancient jax
-        log.warning("persistent compilation cache unavailable: %s", e)
-        return
-    for knob, value in (
-            ("jax_persistent_cache_min_compile_time_secs", 0.0),
-            ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(knob, value)
-        except Exception:  # knob not in this jax — keep its default
-            pass
-    try:
-        # the cache initializes lazily at the FIRST compile and then
-        # pins its directory — re-point it if something already jitted
-        from jax.experimental.compilation_cache import (
-            compilation_cache)
-        compilation_cache.reset_cache()
-    except Exception:
-        pass
-    log.info("persistent XLA compilation cache: %s", path)
+    enable_persistent_compile_cache(path)
+    logging.getLogger("Main").info(
+        "persistent XLA compilation cache: %s",
+        jax.config.jax_compilation_cache_dir)
 
 
 class Main:

@@ -26,6 +26,8 @@ mode (``root.common.engine.eager = True``) skips jit entirely for
 debugging, like the reference's numpy fallback path.
 """
 
+import os
+
 import jax
 
 from veles_tpu.config import root
@@ -33,21 +35,50 @@ from veles_tpu.memory import Array
 from veles_tpu.units import Unit
 from veles_tpu.workflow import Workflow
 
-_compile_cache_enabled = [False]
+#: where the compile cache lives when nothing outside says otherwise:
+#: a FIXED path beside the package (the directory is part of the cache
+#: key, so one that moves with a pid, a time or a temp name never hits)
+CHECKOUT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
+#: the directory this process last placed the cache at (None = left to
+#: JAX_COMPILATION_CACHE_DIR); unset until the first call
+_placed_compile_cache = []
 
 
-def enable_persistent_compile_cache():
-    """XLA's on-disk compile cache — replaces the reference's tar.gz
-    kernel binary cache (ref: veles/accelerated_units.py:605-673)."""
-    if _compile_cache_enabled[0]:
+def enable_persistent_compile_cache(path=None):
+    """Place XLA's on-disk compile cache — replaces the reference's
+    tar.gz kernel binary cache (ref: veles/accelerated_units.py:605-673)
+    and is the ONE place this program decides where it goes:
+
+    - ``JAX_COMPILATION_CACHE_DIR`` set: jax already took the directory
+      from the environment and nothing here overrides it (whoever runs
+      the program places the cache from outside);
+    - else ``path`` (the CLI's ``--compilation-cache`` /
+      ``root.common.trace.compilation_cache_dir``);
+    - else :data:`CHECKOUT_COMPILE_CACHE`.
+
+    Either way every compile is persisted (the thresholds drop to
+    zero): a relaunch re-pays even sub-second compiles otherwise, and
+    compile_tracker labels the reloads ``cache="hit"``."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        chosen = None
+    else:
+        chosen = str(
+            path or root.common.trace.get("compilation_cache_dir")
+            or CHECKOUT_COMPILE_CACHE)
+    if _placed_compile_cache == [chosen]:
         return
-    cache_dir = root.common.dirs.get("cache")
-    if cache_dir:
-        try:
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            _compile_cache_enabled[0] = True
-        except Exception:
-            pass
+    _placed_compile_cache[:] = [chosen]
+    if chosen is not None:
+        jax.config.update("jax_compilation_cache_dir", chosen)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # the cache initializes lazily at the FIRST compile and then pins
+    # its directory — re-point it if something already jitted
+    from jax.experimental.compilation_cache import compilation_cache
+    compilation_cache.reset_cache()
 
 
 class AcceleratedUnit(Unit):

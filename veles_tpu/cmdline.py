@@ -125,7 +125,10 @@ def build_parser():
                         "(jax_compilation_cache_dir) — later runs "
                         "reuse compiled executables instead of paying "
                         "multi-second recompiles; sets "
-                        "root.common.trace.compilation_cache_dir")
+                        "root.common.trace.compilation_cache_dir "
+                        "(default: .jax_cache beside the package; "
+                        "JAX_COMPILATION_CACHE_DIR, when set, wins "
+                        "over both)")
     p.add_argument("--admin-token", default=None, metavar="TOKEN",
                    help="bearer token a NON-loopback caller must "
                         "present (Authorization: Bearer TOKEN) to hit "

@@ -126,8 +126,11 @@ root = Config("root")
 
 root.common.update({
     "dirs": {
+        # downloads and the device-power table; the XLA compile cache
+        # is placed by accelerated_units.enable_persistent_compile_cache
         "cache": os.path.join(
-            os.environ.get("XDG_CACHE_HOME", str(Path.home() / ".cache")),
+            os.environ.get("XDG_CACHE_HOME") or str(
+                Path(__file__).resolve().parent.parent / ".cache"),
             "veles_tpu"),
         "snapshots": os.path.join(os.getcwd(), "snapshots"),
         "datasets": os.environ.get(

@@ -165,6 +165,21 @@ class Launcher(Logger):
             raise ValueError(
                 "-l :0 (OS-assigned port) cannot be combined with -w "
                 "worker spawning; pick a fixed port")
+        local_hosts = ("localhost", "127.0.0.1", "")
+        local = [s for s in specs
+                 if s.partition("/")[0] in local_hosts]
+        if local and self.device is not None \
+                and self.device.jax_device.platform == "tpu":
+            # a chip belongs to ONE process at a time: this master
+            # opened the device in initialize(), so a local worker
+            # that needs it fails or hangs at its first JAX call
+            raise RuntimeError(
+                "-w cannot spawn %d local worker(s) on a TPU host: "
+                "the master process already holds the chip(s) and a "
+                "second process cannot open them — train across this "
+                "host's chips in ONE process with root.common.mesh "
+                "= {'dp': -1}, or name workers on other hosts"
+                % len(local))
         n_local_devices = len(self.device.jax_devices) \
             if self.device is not None else 1
         local_count = 0
@@ -173,7 +188,7 @@ class Launcher(Logger):
             # host/0:0x3 device syntax); plain local workers round-robin
             # over this host's devices
             spec, _, dev = spec.partition("/")
-            is_local = spec in ("localhost", "127.0.0.1", "")
+            is_local = spec in local_hosts
             if not dev:
                 dev = str(local_count % n_local_devices) if is_local \
                     else "0"

@@ -3,12 +3,17 @@ zoo (no reference analogue: RNN/LSTM existed only untested in the
 absent Znicz submodule, manualrst_veles_algorithms.rst:115-140).
 
 This unit's ``apply`` is the single-program formulation (XLA/GSPMD
-shards it like any other op).  For long contexts where each chip must
-hold only 1/sp of K/V, the trainer hands the unit its mesh
-(``sp_mesh_``) and the attention core switches to the RING schedule
-under ``shard_map`` — sequence-sharded training end-to-end, gradients
-flowing through the ppermute ring (ops/attention.py); GSPMD cannot
-derive that communication schedule from the single-program form."""
+shards it like any other op), with two exceptions the trainer's mesh
+(``sp_mesh_``, handed over by GradientDescent.initialize) decides:
+
+- long contexts where each chip must hold only 1/sp of K/V switch the
+  attention core to the RING schedule under ``shard_map`` —
+  sequence-sharded training end-to-end, gradients flowing through the
+  ppermute ring (ops/attention.py); GSPMD cannot derive that
+  communication schedule from the single-program form;
+- a Mosaic kernel is opaque to GSPMD ("cannot be automatically
+  partitioned"), so on any other mesh the pallas cores run per shard
+  under ``shard_map`` too: batch over dp/fsdp, heads over tp."""
 
 import functools
 
@@ -17,6 +22,10 @@ import numpy
 from veles_tpu.models.nn_units import ForwardBase
 
 
+def _batch_axes(mesh):
+    """The mesh axes a minibatch's leading dim shards over."""
+    return tuple(a for a in ("dp", "fsdp")
+                 if mesh.shape.get(a, 1) > 1) or None
 
 
 def _ring_mha(mesh, q, k, v, causal):
@@ -24,21 +33,31 @@ def _ring_mha(mesh, q, k, v, causal):
     with seq over ``sp`` (and batch over dp/fsdp when present); K/V
     rotate around the ring so each chip only ever holds seq/sp of
     them."""
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.5 keeps it in experimental
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from veles_tpu.ops.attention import ring_attention
-    batch_axes = tuple(a for a in ("dp", "fsdp")
-                       if mesh.shape.get(a, 1) > 1) or None
-    spec = P(batch_axes, "sp", None, None)
+    spec = P(_batch_axes(mesh), "sp", None, None)
     fn = shard_map(
         functools.partial(ring_attention, axis_name="sp",
                           causal=causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
     return fn(q, k, v)
+
+
+def _per_shard(mesh, core, q, k, v):
+    """Run a kernel core per mesh shard: q/k/v [batch, seq, heads,
+    hd] with batch over dp/fsdp and whole heads over tp (attention
+    never mixes batch rows or heads, so no collective is needed)."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+    tp = mesh.shape.get("tp", 1)
+    head_axis = "tp" if tp > 1 and q.shape[2] % tp == 0 else None
+    spec = P(_batch_axes(mesh), None, head_axis, None)
+    # check_vma off: pallas_call's out_shape carries no varying-axes
+    # annotation, and there is no collective here for it to check
+    return shard_map(core, mesh=mesh, in_specs=(spec, spec, spec),
+                     out_specs=spec, check_vma=False)(q, k, v)
 
 
 def mha_apply(params, x, heads, causal, block_size=None, sp_mesh=None,
@@ -49,13 +68,18 @@ def mha_apply(params, x, heads, causal, block_size=None, sp_mesh=None,
     run in the compute dtype (bf16 trunk policy); the attention core
     is selected in priority order:
 
-    - ``sp_mesh`` with an sp axis > 1 → the ppermute RING (sequence
-      parallelism is a communication schedule, it overrides the rest);
+    - ``sp_mesh`` (the trainer's mesh) with an sp axis > 1 → the
+      ppermute RING (sequence parallelism is a communication schedule,
+      it overrides the rest);
     - ``attn_impl`` "flash" | "blockwise" | "dense" → that core;
     - default (None/"auto") → the framework's NATIVE pallas flash
       kernels on TPU at any sequence length (lane-multiple head_dim;
       ops/pallas_attention.py), else blockwise streaming if
-      ``block_size`` says so, else the plain single-program form."""
+      ``block_size`` says so, else the plain single-program form.
+
+    Under any other ``sp_mesh`` the kernel cores ("flash", "pallas")
+    run per shard (:func:`_per_shard`): GSPMD refuses to partition a
+    Mosaic call."""
     import jax.numpy as jnp
 
     from veles_tpu import dtypes
@@ -84,7 +108,7 @@ def mha_apply(params, x, heads, causal, block_size=None, sp_mesh=None,
             # clamped causal index maps skip dead-block DMAs and
             # 1024-token K blocks fix the long-context bookkeeping —
             # measured past the jax-shipped kernel at 2048, 8192 AND
-            # 32768; ROUND5_NOTES.md §5).  Odd lengths pad-and-mask
+            # 32768 in round 5, jax 0.4.37).  Odd lengths pad-and-mask
             # inside the kernel.  head_dim off the lane width falls
             # back (the MXU would run mostly idle); attn_impl pins
             # either kernel explicitly.
@@ -96,21 +120,26 @@ def mha_apply(params, x, heads, causal, block_size=None, sp_mesh=None,
         q, k, v = (proj(params[n]) for n in ("wq", "wk", "wv"))
         if impl == "flash":
             from veles_tpu.ops.flash import flash_attention
-            o = flash_attention(q, k, v, causal=causal,
-                                backend=backend)
+            core = functools.partial(flash_attention, causal=causal,
+                                     backend=backend)
         elif impl == "pallas":
             # the framework's OWN flash kernels (ops/pallas_attention)
             from veles_tpu.ops.pallas_attention import pallas_attention
-            o = pallas_attention(q, k, v, causal=causal,
-                                 backend=backend)
+            core = functools.partial(pallas_attention, causal=causal,
+                                     backend=backend)
         elif impl == "blockwise":
             from veles_tpu.ops.attention import blockwise_attention
-            o = blockwise_attention(q, k, v, block_size or 512,
-                                    causal=causal)
+            core = functools.partial(
+                blockwise_attention, block_size=block_size or 512,
+                causal=causal)
         elif impl == "dense":
-            o = attention(q, k, v, causal=causal)
+            core = functools.partial(attention, causal=causal)
         else:
             raise ValueError("unknown attn_impl %r" % (attn_impl,))
+        if sp_mesh is not None and impl in ("flash", "pallas"):
+            o = _per_shard(sp_mesh, core, q, k, v)
+        else:
+            o = core(q, k, v)
     return jnp.einsum("bsd,de->bse", o.reshape(b, s, d).astype(cd),
                       params["wo"].astype(cd),
                       precision=prec,

@@ -84,8 +84,8 @@ class Conv(ForwardBase):
         self.activation = activation or self.ACTIVATION
         #: stride-matched space-to-depth stem (TPU emitter fix for
         #: tiny-C strided stems like AlexNet's 11×11/4 over RGB: the
-        #: blocked form measured 5.42 vs 7.88 ms fwd+dk on v5e,
-        #: ROUND5_NOTES.md §1a).  Weights stay in the LOGICAL
+        #: blocked form measured 5.42 vs 7.88 ms fwd+dk on v5e in
+        #: round 5).  Weights stay in the LOGICAL
         #: [ky, kx, C, O] convention — the blocked kernel is built
         #: in-graph, so export/snapshot/autodiff are unchanged.  The
         #: loader must feed pre-blocked data (``space_to_depth()``).
@@ -95,7 +95,7 @@ class Conv(ForwardBase):
         self.space_to_depth = int(space_to_depth or 0)
         #: (hb, wb) of the blocked input when the loader stores it
         #: FLAT [batch, hb·wb·n²·C] — 4D-blocked dataset layouts
-        #: gather pathologically (ROUND5_NOTES.md §1c), so the fast
+        #: gather pathologically, so the fast
         #: path is flat storage + this in-graph reshape
         self.space_to_depth_hw = tuple(space_to_depth_hw) \
             if space_to_depth_hw else None
@@ -193,8 +193,8 @@ class Conv(ForwardBase):
         # regardless; the loss is computed in f32 at the evaluator.
         # (The space_to_depth branch above is the r5 stem rewrite:
         # 2.2 ms faster in isolation but net-negative in the full
-        # step because of the blocked dataset's gather layout — see
-        # ROUND5_NOTES.md §1c; it therefore ships opt-in.)
+        # step because of the blocked dataset's gather layout; it
+        # therefore ships opt-in.)
         cd = dtypes.compute_dtype()
         return jax.lax.conv_general_dilated(
             x.astype(cd), kernel.astype(cd),

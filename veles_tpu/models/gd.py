@@ -186,13 +186,15 @@ class GradientDescent(AcceleratedUnit):
             raise MissingDemand(self, {"loader.minibatch_targets"})
         if self.mesh is not None and self.mesh.shape.get("pp", 1) > 1:
             self._pp_plan_ = self._make_pp_plan()
-        if self.mesh is not None \
-                and self.mesh.shape.get("sp", 1) > 1:
-            # sequence parallelism is a COMMUNICATION SCHEDULE, not a
-            # sharding GSPMD can derive: hand each forward the mesh so
-            # attention units switch to the ppermute ring
-            # (models/attention.mha_apply).  Volatile (trailing _) —
-            # re-established here on every snapshot resume.
+        if self.mesh is not None and self._pp_plan_ is None:
+            # hand each forward the mesh for what GSPMD cannot derive
+            # from the single-program form (models/attention.mha_apply):
+            # sequence parallelism is a COMMUNICATION SCHEDULE (the
+            # ppermute ring), and a Mosaic kernel is opaque to the
+            # partitioner, so it runs per shard.  Not under pp: the
+            # GPipe trunk already runs inside its own shard_map.
+            # Volatile (trailing _) — re-established here on every
+            # snapshot resume.
             for u in self.forwards:
                 u.sp_mesh_ = self.mesh
         solver = get_solver(self.solver_name)

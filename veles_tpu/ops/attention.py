@@ -16,12 +16,8 @@ import jax.numpy as jnp
 
 
 def _pvary(x, axis_name):
-    """Mark a fresh (axis-invariant) value as varying over axis_name —
-    pcast on new JAX, pvary fallback on older releases."""
-    try:
-        return jax.lax.pcast(x, axis_name, to="varying")
-    except (AttributeError, TypeError):
-        return jax.lax.pvary(x, (axis_name,))
+    """Mark a fresh (axis-invariant) value as varying over axis_name."""
+    return jax.lax.pcast(x, axis_name, to="varying")
 
 
 def attention(q, k, v, causal=False, scale=None):
@@ -192,11 +188,8 @@ def ring_attention_sharded(mesh, q, k, v, axis="sp", causal=False):
     """Convenience wrapper: shard q/k/v's sequence dim over ``axis`` and
     run :func:`ring_attention` under shard_map.  q/k/v: [seq, heads,
     dim] global arrays."""
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.5 keeps it in experimental
-        from jax.experimental.shard_map import shard_map
 
     spec = P(axis, None, None)
     fn = shard_map(

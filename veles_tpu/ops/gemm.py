@@ -119,10 +119,7 @@ def _pallas_matmul_body(a, b, col_scale=None, block_m=256,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        # jax >= 0.5 renamed TPUCompilerParams -> CompilerParams
-        compiler_params=getattr(
-            pltpu, "CompilerParams",
-            getattr(pltpu, "TPUCompilerParams", None))(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)

@@ -41,8 +41,8 @@ forward (5.7 vs 6.7 ms) but its backward is VPU-pointwise-bound at
 the same ~10 ms the XLA backward already costs: Mosaic DMA streams
 cap at ~330 GB/s aggregate on this chip (measured; XLA fusions reach
 ~660), and the EUP is f32-only, so the kernel cannot beat the fused
-XLA loops on a streaming-plus-transcendental op.  Full experiment
-log: ROUND5_NOTES.md.  The band formulation therefore REMAINS the
+XLA loops on a streaming-plus-transcendental op (round 5, on the
+chip).  The band formulation therefore REMAINS the
 production TPU path; ``lrn_pallas`` ships tested as the in-repo
 native-kernel counterpart (SURVEY §2.2) and the decision record.
 """

@@ -38,10 +38,10 @@ from jax.experimental.pallas import tpu as pltpu
 #: rounds (the m/l/acc rescale runs on lane-replicated [bq, 128]
 #: scratch, so its cost rivals the matmuls at small batch×heads) —
 #: measured faster than 512 at every length, and past the jax-shipped
-#: kernel at 32k (32.3 vs 38.3 ms; ROUND5_NOTES.md §5)
+#: kernel at 32k (32.3 vs 38.3 ms, round 5)
 DEFAULT_BLOCK = 1024
 #: larger Q blocks amortize the K/V streaming (21% on the jax kernel
-#: at head_dim 128 — ROUND4_NOTES.md)
+#: at head_dim 128, round 4)
 DEFAULT_BLOCK_Q = 1024
 #: finite stand-in for -inf: exp(x - max) underflows to 0 for masked
 #: entries without generating nan through (-inf) - (-inf)
@@ -86,8 +86,8 @@ def _clamp_maps(block_q, block_k, causal):
     causal case the K index CLAMPS to the diagonal block: grid steps
     past the diagonal re-request the same block, and pallas skips the
     DMA for a repeated index — causally dead K/V blocks are never
-    fetched (the r4 gap vs the jax kernel at long context:
-    ROUND4_NOTES.md §1b named this as the next step)."""
+    fetched (this closed the r4 gap vs the jax kernel at long
+    context)."""
     if not causal:
         return lambda b, i, j: (b, j, 0)
 

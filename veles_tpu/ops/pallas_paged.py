@@ -23,8 +23,10 @@ block-for-block (parity is allclose: the online softmax reorders the
 reduction).
 
 Runs under ``interpret=True`` off-TPU (``ops.common.use_interpret``,
-the flash/lrn convention) — tier-1 proves parity on CPU; the Mosaic
-lowering targets real chips.
+the flash/lrn convention) — tier-1 proves parity on CPU
+(tests/test_kv_quant.py) and that the Mosaic lowering compiles at the
+serving shapes (tests/test_tpu_compile.py); chip_smoke.py holds it
+against the jnp paths on a real chip.
 
 Layouts: q/qpos per batch row, pools block-major
 ([num_blocks, block_size, d] with the per-row scales
@@ -47,10 +49,11 @@ _NEG_INF = -1e30
 _LANES = 128
 
 
-def _attend_kernel(tables_ref, q_ref, qp_ref, k_ref, v_ref, *rest,
+def _attend_kernel(tables_ref, qp_ref, q_ref, k_ref, v_ref, *rest,
                    heads, head_dim, block_size, k1, quant, scale):
     """One (b, t) grid step: fold physical block ``tables[b, t]``
-    into row b's online-softmax state.  ``rest`` is
+    into row b's online-softmax state.  ``tables_ref`` and ``qp_ref``
+    ride scalar prefetch (SMEM); ``rest`` is
     ``[sk_ref, sv_ref,] o_ref, acc_ref, m_ref, l_ref``."""
     if quant:
         sk_ref, sv_ref, o_ref, acc_ref, m_ref, l_ref = rest
@@ -70,12 +73,17 @@ def _attend_kernel(tables_ref, q_ref, qp_ref, k_ref, v_ref, *rest,
     k = k_ref[0].astype(jnp.float32)              # [bs, d]
     v = v_ref[0].astype(jnp.float32)
     if quant:                                     # dequant in VMEM
-        k = k * sk_ref[0][:, None]
-        v = v * sv_ref[0][:, None]
-    qp = qp_ref[0]                                # [k1] positions
+        k = k * sk_ref[0, 0][:, None]
+        v = v * sv_ref[0, 0][:, None]
+    # the run's positions are SMEM scalars: spread them down the rows
+    bi = pl.program_id(0)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (k1, bs), 0)
+    qp = jnp.zeros((k1, bs), jnp.int32)
+    for j in range(k1):
+        qp = jnp.where(rows == j, qp_ref[bi, j], qp)
     cols = t * bs + jax.lax.broadcasted_iota(
         jnp.int32, (k1, bs), 1)
-    keep = cols <= qp[:, None]                    # causal + trash tail
+    keep = cols <= qp                             # causal + trash tail
     for head in range(h):
         lo = head * hd
         qh = q_ref[0][:, lo:lo + hd].astype(jnp.float32)  # [k1, hd]
@@ -133,29 +141,30 @@ def pallas_paged_attend(q, pool_k, pool_v, tables, qpos, heads,
         _attend_kernel, heads=heads, head_dim=hd, block_size=bs,
         k1=k1, quant=quant, scale=1.0 / (hd ** 0.5))
 
-    def blk_map(bi, t, tbl):
+    def row_map(bi, t, tbl, qp):
+        return (bi, 0, 0)
+
+    def blk_map(bi, t, tbl, qp):
         return (tbl[bi, t], 0, 0)
 
-    def scl_map(bi, t, tbl):
-        return (tbl[bi, t], 0)
-
     in_specs = [
-        pl.BlockSpec((1, k1, d), lambda bi, t, tbl: (bi, 0, 0)),
-        pl.BlockSpec((1, k1), lambda bi, t, tbl: (bi, 0)),
+        pl.BlockSpec((1, k1, d), row_map),
         pl.BlockSpec((1, bs, d), blk_map),
         pl.BlockSpec((1, bs, d), blk_map),
     ]
-    ops = [q, jnp.asarray(qpos, jnp.int32), pool_k, pool_v]
+    ops = [q, pool_k, pool_v]
     if quant:
-        in_specs += [pl.BlockSpec((1, bs), scl_map),
-                     pl.BlockSpec((1, bs), scl_map)]
-        ops += [scale_k, scale_v]
+        # Mosaic tiles the last two block dims: a (1, bs) slice of
+        # [nb, bs] is refused, a (1, 1, bs) slice of [nb, 1, bs] has
+        # both equal to the array's own
+        in_specs += [pl.BlockSpec((1, 1, bs), blk_map),
+                     pl.BlockSpec((1, 1, bs), blk_map)]
+        ops += [scale_k[:, None, :], scale_v[:, None, :]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, nt),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, k1, d),
-                               lambda bi, t, tbl: (bi, 0, 0)),
+        out_specs=pl.BlockSpec((1, k1, d), row_map),
         scratch_shapes=[
             pltpu.VMEM((k1, d), jnp.float32),
             pltpu.VMEM((heads * k1, _LANES), jnp.float32),
@@ -166,4 +175,5 @@ def pallas_paged_attend(q, pool_k, pool_v, tables, qpos, heads,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, k1, d), jnp.float32),
         interpret=interpret,
-    )(jnp.asarray(tables, jnp.int32), *ops)
+    )(jnp.asarray(tables, jnp.int32), jnp.asarray(qpos, jnp.int32),
+      *ops)

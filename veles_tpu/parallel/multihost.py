@@ -18,27 +18,6 @@ Cloud TPU VMs.
 import os
 
 
-def _is_initialized(jax):
-    """``jax.distributed.is_initialized`` where it exists (jax >=
-    0.4.35-ish); on older jax fall back to probing the distributed
-    client's global state.  NOTE: enabling this path used to trip
-    nondeterministic glibc heap corruption in the XLA:CPU span step
-    (same-process CLI training after other jax work) — root-caused to
-    donated buffers aliasing host numpy memory and fixed in
-    memory.py's donatable_devmem(); see ROUND6_NOTES.md."""
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        try:
-            return bool(probe())
-        except Exception:  # pragma: no cover - defensive
-            return False
-    try:
-        from jax._src import distributed as _dist
-        return getattr(_dist.global_state, "client", None) is not None
-    except Exception:  # pragma: no cover - very old jax
-        return False
-
-
 def initialize(coordinator_address=None, num_processes=None,
                process_id=None, local_device_ids=None, auto=False):
     """Join the jax.distributed coordination service.
@@ -55,7 +34,7 @@ def initialize(coordinator_address=None, num_processes=None,
     """
     import jax
 
-    if _is_initialized(jax):
+    if jax.distributed.is_initialized():
         # idempotent: report the live gang's coordinates
         return jax.process_index(), jax.process_count()
 

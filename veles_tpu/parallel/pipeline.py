@@ -15,17 +15,8 @@ import jax.numpy as jnp
 
 
 def _pvary(x, axis_name):
-    """Mark a fresh (axis-invariant) value as varying over axis_name —
-    pcast on new JAX, pvary on older releases, identity on jax
-    versions that predate replication tracking (nothing to mark)."""
-    try:
-        return jax.lax.pcast(x, axis_name, to="varying")
-    except (AttributeError, TypeError):
-        pass
-    try:
-        return jax.lax.pvary(x, (axis_name,))
-    except AttributeError:
-        return x
+    """Mark a fresh (axis-invariant) value as varying over axis_name."""
+    return jax.lax.pcast(x, axis_name, to="varying")
 
 
 def split_stages(n_layers, n_stages):
@@ -117,11 +108,8 @@ def gpipe_train(mesh, stage_fn, stacked_params, x, n_micro,
 
     Returns [batch, ...] outputs of the last stage, replicated over
     ``axis``."""
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.5 keeps it in experimental
-        from jax.experimental.shard_map import shard_map
 
     if x.shape[0] % n_micro:
         raise ValueError("batch %d not divisible into %d microbatches"
@@ -151,11 +139,8 @@ def pipeline_forward(mesh, stage_fn, per_stage_params, x, n_micro,
     microbatch's sample dim shards over those mesh axes (e.g.
     ``("dp",)`` on a pp×dp mesh — every dp slice runs its own bubble
     schedule on its batch shard, stages still hop over ``pp``)."""
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.5 keeps it in experimental
-        from jax.experimental.shard_map import shard_map
 
     if len(per_stage_params) != mesh.shape[axis]:
         raise ValueError(

@@ -213,7 +213,7 @@ def _make_paged_step_tp(forwards, ctx, pools, want_hidden=False):
     psum: two-operand float addition is order-free), wider meshes
     all-gather and sum in fixed shard order (deterministic, same
     value on every shard)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     size = ctx.size
     cacheable = frozenset(i for i, u in enumerate(forwards)
@@ -262,7 +262,7 @@ def _make_paged_step_tp(forwards, ctx, pools, want_hidden=False):
     in_specs = (pspecs, rep, rep, rep, rep, rep, rep, rep, lspecs)
     out_specs = (rep, rep, lspecs) if want_hidden else (rep, lspecs)
     return shard_map(body, mesh=ctx.mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+                     out_specs=out_specs, check_vma=False)
 
 
 @functools.lru_cache(maxsize=32)

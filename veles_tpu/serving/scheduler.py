@@ -592,13 +592,14 @@ class InferenceScheduler(Logger):
         if tp:
             from veles_tpu.serving.tp import ServingTP, tp_supported
             import jax
+            if len(jax.devices()) < tp:
+                # not a degrade to take quietly: the caller sized the
+                # model and its pools for 1/tp of them per chip
+                raise ValueError("tp=%d needs %d devices, found %d"
+                                 % (tp, tp, len(jax.devices())))
             if self.kv != "paged":
                 self.info("tp needs the paged cache; serving "
                           "unsharded")
-                tp = 0
-            elif len(jax.devices()) < tp:
-                self.info("tp=%d needs %d devices, found %d; serving "
-                          "unsharded", tp, tp, len(jax.devices()))
                 tp = 0
             elif not tp_supported(forwards, tp):
                 self.info("chain does not divide over tp=%d (heads/"
