@@ -515,12 +515,15 @@ def _tiny_fw(name, window=64, vocab=12, dim=16, heads=2):
 
 
 @pytest.mark.alerting_overhead
-def test_alerting_overhead_under_5_percent_and_goodput_gauges(f32):
-    """The engine is default-ON, so its tick cost rides every
-    serving process: gate the engine-on vs engine-off scheduler soak
-    at <5% (the telemetry/tracing overhead precedent).  The same
-    soak proves the goodput accounting: tokens/sec and padding
-    efficiency export to /serving/metrics and the registry."""
+def test_alerting_engine_ticks_beside_the_loop_and_goodput_gauges(f32):
+    """The engine is default-ON, so it ticks beside every serving
+    process: a BUSY engine (20 Hz, the full default rule set) ticks
+    and evaluates every rule while the scheduler serves, none of them
+    failing, and the soak's answers do not change.  What that costs
+    the serving loop in time is read on the chip (PERF.md: the engine
+    runs on its own thread, so it shows in ``decode_step_ms`` or not at
+    all).  The same soak proves the goodput accounting: tokens/sec and
+    padding efficiency export to /serving/metrics and the registry."""
     from veles_tpu.serving import InferenceScheduler
     from veles_tpu.telemetry import metrics
     fw = _tiny_fw("alerts-overhead")
@@ -533,19 +536,10 @@ def test_alerting_overhead_under_5_percent_and_goodput_gauges(f32):
     def soak(requests=4, steps=24):
         futs = [sch.submit(prompt, steps, seed=i)
                 for i in range(requests)]
-        for f in futs:
-            f.result(240)
-
-    def best_of(reps=3):
-        best = float("inf")
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            soak()
-            best = min(best, time.perf_counter() - t0)
-        return best
+        return [f.result(240) for f in futs]
 
     try:
-        soak()   # compile + settle
+        quiet = soak()   # compile + settle
         snap = sch.metrics()
         # -- goodput accounting is live after real traffic
         assert snap["goodput_tokens_per_sec"] is not None \
@@ -556,25 +550,34 @@ def test_alerting_overhead_under_5_percent_and_goodput_gauges(f32):
         fam = metrics.get("veles_serving_bucket_padding_efficiency")
         assert 0.0 < fam.labels(replica="obs-soak").value <= 1.0
 
-        # -- on-vs-off: a BUSY engine (20 Hz, full default rule set)
-        engine = AlertEngine(name="overhead", interval=0.05).start()
+        # -- a busy engine beside the loop
+        engine = AlertEngine(name="overhead", interval=0.05)
+        evaluated = {"ok": 0, "failed": 0}
+
+        def counting(evaluate):
+            def run(*args, **kwargs):
+                try:
+                    rows = evaluate(*args, **kwargs)
+                except Exception:
+                    evaluated["failed"] += 1
+                    raise
+                evaluated["ok"] += 1
+                return rows
+            return run
+        assert len(engine.rules) >= 5
+        for rule in engine.rules:
+            rule.evaluate = counting(rule.evaluate)
+        engine.start()
         try:
-            t_on = best_of()
+            soaks = 0
+            while engine.ticks < 3 and soaks < 500:
+                assert soak() == quiet
+                soaks += 1
         finally:
             engine.stop()
-        t_off = best_of()
-        overhead = (t_on - t_off) / t_off
-        if overhead >= 0.05:   # one retry rides out load spikes
-            engine = AlertEngine(name="overhead2",
-                                 interval=0.05).start()
-            try:
-                t_on = best_of()
-            finally:
-                engine.stop()
-            t_off = best_of()
-            overhead = min(overhead, (t_on - t_off) / t_off)
-        assert overhead < 0.05, \
-            "alerting overhead %.1f%% (on %.3fs, off %.3fs)" \
-            % (overhead * 100, t_on, t_off)
+        assert engine.ticks >= 3
+        assert evaluated["failed"] == 0
+        assert evaluated["ok"] >= (engine.ticks - 1) * len(engine.rules)
+        assert fam.labels(replica="obs-soak").value > 0
     finally:
         sch.close()

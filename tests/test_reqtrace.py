@@ -5,7 +5,7 @@ admit → prefill → step → retire, with preempt→resume parented by one
 trace id), ``/debug/requests`` consistency with ``check_kv()``, the
 ``trace_export --request`` multi-log merge with clock-skew
 detection, SLO good/bad + burn-rate accounting, the flight-recorder
-in-flight table, and the <5% tracing-overhead gate."""
+in-flight table, and tracing's cost to the loop as event counts."""
 
 import json
 import time
@@ -422,18 +422,30 @@ def test_trace_export_legacy_two_arg_mode_unchanged(tmp_path):
     assert len(json.loads(out.read_text())["traceEvents"]) == 2
 
 
-# -- the overhead gate --------------------------------------------------------
+# -- what tracing costs the loop, as counts ----------------------------------
 
 @pytest.mark.tracing_overhead
-def test_tracing_overhead_under_5_percent(f32):
-    """Tracing is default-ON, so its cost rides every decode
-    boundary: one ring append per step plus the per-request phase
-    spans.  Gate the tracing-on vs tracing-off scheduler soak at <5%
-    (the PR 2 telemetry-overhead precedent)."""
+def test_tracing_overhead_is_one_event_a_decode_boundary(f32,
+                                                         monkeypatch):
+    """Tracing is default-ON, so its cost rides every decode boundary.
+    What a CPU run can show of that cost is a COUNT: one ``req.step``
+    event a boundary however many slots ride it, a fixed handful of
+    phase events a request, and none at all with tracing off.  What
+    the events cost the loop thread in time is read on the chip
+    (``sched_observe_ms_per_step``, PERF.md)."""
     from veles_tpu.serving import InferenceScheduler
+    from veles_tpu.telemetry import metrics
     fw = _tiny_fw("reqtrace-overhead")
     prompt = [3, 1, 4, 3, 1, 4]
     saved = root.common.reqtrace.get("enabled", True)
+    seen = []
+    real = events.record
+
+    def recording(name, kind, **attrs):
+        if name.startswith("req."):
+            seen.append(name)
+        return real(name, kind, **attrs)
+    monkeypatch.setattr(events, "record", recording)
 
     def build(enabled):
         root.common.reqtrace.enabled = enabled
@@ -442,41 +454,42 @@ def test_tracing_overhead_under_5_percent(f32):
                                   prefill_chunk=4,
                                   warm_buckets=False).start()
 
-    def soak(sch, requests=4, steps=24):
-        futs = [sch.submit(prompt, steps, seed=i)
-                for i in range(requests)]
-        for f in futs:
-            f.result(240)
-
-    def best_of(sch, reps=3):
-        best = float("inf")
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            soak(sch)
-            best = min(best, time.perf_counter() - t0)
-        return best
+    def soak(sch, together, lead, requests=4, steps=24):
+        """(req.step events, other req.* events, decode boundaries)
+        of ``requests`` requests, ``together`` at a time; each prompt
+        starts with a token of its own (from ``lead``), so none is
+        warm in the prefix cache."""
+        boundaries = metrics.get("veles_serving_steps_total")
+        del seen[:]
+        before = boundaries.value
+        for first in range(0, requests, together):
+            futs = [sch.submit([lead + i] + prompt[1:], steps, seed=i)
+                    for i in range(first, first + together)]
+            for f in futs:
+                f.result(240)
+        while sch._working:          # the last pass's own events
+            time.sleep(0.01)
+        step_events = seen.count("req.step")
+        return (step_events, len(seen) - step_events,
+                boundaries.value - before)
 
     try:
         on = build(True)
         off = build(False)
         assert on._tron and not off._tron
         try:
-            soak(on)    # compile + settle (executables shared)
-            soak(off)
-
-            def measure():
-                t_on, t_off = best_of(on), best_of(off)
-                return (t_on - t_off) / t_off, t_on, t_off
-
-            overhead, t_on, t_off = measure()
-            if overhead >= 0.05:  # one retry rides out load spikes
-                overhead, t_on, t_off = min(
-                    (overhead, t_on, t_off), measure())
+            alone = soak(on, together=1, lead=0)
+            paired = soak(on, together=2, lead=4)
+            silent = soak(off, together=2, lead=8)
         finally:
             on.close()
             off.close()
     finally:
         root.common.reqtrace.enabled = saved
-    assert overhead < 0.05, \
-        "tracing overhead %.1f%% >= 5%% (on %.4fs off %.4fs)" \
-        % (overhead * 100, t_on, t_off)
+    # one event a boundary, at occupancy 1 and at occupancy 2
+    assert alone[0] == alone[2] > 0 and paired[0] == paired[2] > 0
+    assert paired[2] < alone[2]      # two slots did share boundaries
+    # queue, admit, two prefill chunks, first token, retire: the same
+    # six a request whoever it shares the batch with
+    assert alone[1] == paired[1] == 4 * 6
+    assert silent[:2] == (0, 0) and silent[2] > 0

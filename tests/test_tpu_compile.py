@@ -118,13 +118,25 @@ CASES = {
 }
 
 
+#: ``name=`` of the Pallas calls (ops/pallas_attention.py)
+KERNEL_NAMES = {
+    "attention_fwd": ("veles_attn_fwd",),
+    "attention_grad": ("veles_attn_fwd", "veles_attn_bwd_dq",
+                       "veles_attn_bwd_dkv"),
+}
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_v5e(case, one_chip, no_compile_cache):
     fn, args = CASES[case]()
     shapes = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
               for s, dt in args]
-    compiled = jax.jit(fn).lower(*shapes).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert "tpu_custom_call" in text
+    # the attention kernels keep their names through the compiler:
+    # what a device trace shows them by
+    for name in KERNEL_NAMES.get(case.rsplit("_", 1)[0], ()):
+        assert name in text, name
 
 
 def test_attention_kernel_partitions_over_a_dp_mesh(topo,

@@ -265,8 +265,9 @@ root.common.update({
     # per-request distributed tracing (telemetry/reqtrace.py): trace
     # ids minted at the edge (or accepted via X-Veles-Trace),
     # propagated router -> replica -> scheduler, phase spans appended
-    # to the JSONL event sink.  ON by default; overhead is gated in
-    # tier-1 (<5%, the tracing_overhead marker).  Disabling stops the
+    # to the JSONL event sink.  ON by default; tier-1 counts its events
+    # (one a decode boundary; the tracing_overhead marker) and the
+    # loop's observe phase times them on the chip.  Disabling stops the
     # span emission only — ids still mint and echo, so client-side
     # correlation keeps working
     "reqtrace": {"enabled": True},

@@ -35,6 +35,7 @@ from veles_tpu.models.evaluator import EvaluatorMSE
 from veles_tpu.models.lr_adjust import get_schedule
 from veles_tpu.models.solvers import get_solver
 from veles_tpu import prng as prng_mod
+from veles_tpu.telemetry.spans import annotation
 
 
 class GradientDescent(AcceleratedUnit):
@@ -703,46 +704,47 @@ class GradientDescent(AcceleratedUnit):
         self._maybe_invalidate_steps()
         if self._train_step_ is None:
             self._train_step_ = self._build_train_step()
-        params, opt_state = self._gather_state()
-        # under the asynchronous input pipeline these devmem reads are
-        # already-on-device batch handles installed at pop time
-        # (loader/prefetch.py) — no synchronous host→HBM upload here
-        x = l.minibatch_data.devmem
-        labels = l.minibatch_labels.devmem
-        targets = getattr(l, "minibatch_targets", None)
-        is_mse = isinstance(self.evaluator, EvaluatorMSE)
-        target = targets.devmem if is_mse else labels
-        if self._shardings_ is not None:
-            from veles_tpu.parallel import sharding as shlib
-            _, _, x_sh, tgt_sh, _ = self._shardings_
-            pf = getattr(l, "prefetch_", None)
-            if pf not in (None, False) \
-                    and not shlib.is_cross_process(x_sh):
-                # teach the uploader thread the step's input shardings
-                # so the put below becomes a no-op re-place
-                pf.set_placement(
-                    x_sh,
-                    labels_sharding=None if is_mse else tgt_sh,
-                    targets_sharding=tgt_sh if is_mse else None)
-            if shlib.is_cross_process(x_sh):
-                # feed the host mirror directly: putting the local device
-                # buffer would download it again just to re-assemble
-                x = l.minibatch_data.map_read().mem
-                target = (l.minibatch_targets if isinstance(
-                    self.evaluator, EvaluatorMSE)
-                    else l.minibatch_labels).map_read().mem
-            x = shlib.put(x, x_sh)
-            target = shlib.put(target, tgt_sh)
-            params, opt_state = self._mesh_prepare(params, opt_state)
-        key = self.prng.peek_key(self.global_step)
-        new_params, new_opt, acc, loss, n_err, health = \
-            self._train_step_(
-                params, opt_state, self.epoch_acc.donatable_devmem(),
-                x, target,
-                jnp.int32(l.minibatch_size),
-                jnp.int32(l.minibatch_class),
-                jnp.float32(self.global_step),
-                jnp.float32(self.lr_multiplier), key)
+        with annotation("veles.gd.dispatch"):
+            params, opt_state = self._gather_state()
+            # under the asynchronous input pipeline these devmem reads are
+            # already-on-device batch handles installed at pop time
+            # (loader/prefetch.py) — no synchronous host→HBM upload here
+            x = l.minibatch_data.devmem
+            labels = l.minibatch_labels.devmem
+            targets = getattr(l, "minibatch_targets", None)
+            is_mse = isinstance(self.evaluator, EvaluatorMSE)
+            target = targets.devmem if is_mse else labels
+            if self._shardings_ is not None:
+                from veles_tpu.parallel import sharding as shlib
+                _, _, x_sh, tgt_sh, _ = self._shardings_
+                pf = getattr(l, "prefetch_", None)
+                if pf not in (None, False) \
+                        and not shlib.is_cross_process(x_sh):
+                    # teach the uploader thread the step's input shardings
+                    # so the put below becomes a no-op re-place
+                    pf.set_placement(
+                        x_sh,
+                        labels_sharding=None if is_mse else tgt_sh,
+                        targets_sharding=tgt_sh if is_mse else None)
+                if shlib.is_cross_process(x_sh):
+                    # feed the host mirror directly: putting the local device
+                    # buffer would download it again just to re-assemble
+                    x = l.minibatch_data.map_read().mem
+                    target = (l.minibatch_targets if isinstance(
+                        self.evaluator, EvaluatorMSE)
+                        else l.minibatch_labels).map_read().mem
+                x = shlib.put(x, x_sh)
+                target = shlib.put(target, tgt_sh)
+                params, opt_state = self._mesh_prepare(params, opt_state)
+            key = self.prng.peek_key(self.global_step)
+            new_params, new_opt, acc, loss, n_err, health = \
+                self._train_step_(
+                    params, opt_state, self.epoch_acc.donatable_devmem(),
+                    x, target,
+                    jnp.int32(l.minibatch_size),
+                    jnp.int32(l.minibatch_class),
+                    jnp.float32(self.global_step),
+                    jnp.float32(self.lr_multiplier), key)
         self.epoch_acc.devmem = acc
         self._adopt_state(new_params, new_opt)
         self.loss.devmem = loss
@@ -759,34 +761,35 @@ class GradientDescent(AcceleratedUnit):
         self._maybe_invalidate_steps()
         if self._span_step_ is None:
             self._span_step_ = self._build_span_step()
-        params, opt_state = self._gather_state()
-        is_mse = isinstance(self.evaluator, EvaluatorMSE)
-        ds = l.dataset_dev
-        tgt = l.targets_dev if is_mse else l.labels_dev
-        if self._shardings_ is not None or self.mesh is not None:
-            _, _, _, _, rep = self._ensure_shardings()
-            if ds.sharding != rep:
-                # re-home the loader's dataset onto the mesh (replicated,
-                # like each reference slave holding a full copy) — the
-                # single-device original is released, not duplicated
-                l.rehome_dataset(rep)
-                ds = l.dataset_dev
-                tgt = l.targets_dev if is_mse else l.labels_dev
-            params, opt_state = self._mesh_prepare(params, opt_state)
-        idx = l.span_indices_
-        if getattr(self, "_idx_sharding_", None) is not None:
-            # multi-process meshes reject numpy args with non-trivial
-            # shardings — assemble the global index array explicitly
-            from veles_tpu.parallel import sharding as shlib
-            idx = shlib.put(idx, self._idx_sharding_)
-        key = self.prng.peek_key(self.global_step)
-        new_params, new_opt, acc, loss, n_err, health = \
-            self._span_step_(
-                params, opt_state, self.epoch_acc.donatable_devmem(),
-                ds, tgt,
-                idx, l.span_sizes_,
-                jnp.int32(l.span_class_), jnp.float32(self.global_step),
-                jnp.float32(self.lr_multiplier), key)
+        with annotation("veles.gd.dispatch"):
+            params, opt_state = self._gather_state()
+            is_mse = isinstance(self.evaluator, EvaluatorMSE)
+            ds = l.dataset_dev
+            tgt = l.targets_dev if is_mse else l.labels_dev
+            if self._shardings_ is not None or self.mesh is not None:
+                _, _, _, _, rep = self._ensure_shardings()
+                if ds.sharding != rep:
+                    # re-home the loader's dataset onto the mesh (replicated,
+                    # like each reference slave holding a full copy) — the
+                    # single-device original is released, not duplicated
+                    l.rehome_dataset(rep)
+                    ds = l.dataset_dev
+                    tgt = l.targets_dev if is_mse else l.labels_dev
+                params, opt_state = self._mesh_prepare(params, opt_state)
+            idx = l.span_indices_
+            if getattr(self, "_idx_sharding_", None) is not None:
+                # multi-process meshes reject numpy args with non-trivial
+                # shardings — assemble the global index array explicitly
+                from veles_tpu.parallel import sharding as shlib
+                idx = shlib.put(idx, self._idx_sharding_)
+            key = self.prng.peek_key(self.global_step)
+            new_params, new_opt, acc, loss, n_err, health = \
+                self._span_step_(
+                    params, opt_state, self.epoch_acc.donatable_devmem(),
+                    ds, tgt,
+                    idx, l.span_sizes_,
+                    jnp.int32(l.span_class_), jnp.float32(self.global_step),
+                    jnp.float32(self.lr_multiplier), key)
         self.epoch_acc.devmem = acc
         self._adopt_state(new_params, new_opt)
         self.loss.devmem = loss
@@ -810,7 +813,8 @@ class GradientDescent(AcceleratedUnit):
         every = max(int(cfg["sync_every"]), 1)
         if not force and self._health_ticks_ % every:
             return
-        vals = numpy.asarray(health)
+        with annotation("veles.gd.health_sync"):
+            vals = numpy.asarray(health)
         action = health_lib.monitor.on_train_step(
             grad_norm=float(vals[0]), weight_norm=float(vals[1]),
             update_ratio=float(vals[2]), nonfinite=float(vals[3]),

@@ -160,22 +160,27 @@ def paged_decode_attention(q, k_new, v_new, pool_k, pool_v, tables,
     off = pos % bs
     pk = pool_k.at[blk, off].set(k_new[:, 0].astype(pool_k.dtype))
     pv = pool_v.at[blk, off].set(v_new[:, 0].astype(pool_v.dtype))
-    # gather ONLY the table's blocks — [B, T, bs, d] -> [B, T·bs, d];
-    # the window never materializes
-    kg = pk[tables]
-    vg = pv[tables]
-    length = kg.shape[1] * bs
-    qh = q.reshape(b, 1, h, hd)
-    kh = kg.astype(cd).reshape(b, length, h, hd)
-    vh = vg.astype(cd).reshape(b, length, h, hd)
-    logits = jnp.einsum("bqhd,bkhd->bhqk", qh, kh) \
-        * (1.0 / jnp.sqrt(hd))
-    mask = (jnp.arange(length)[None, :]
-            <= pos[:, None])[:, None, None, :]
-    logits = jnp.where(mask, logits, -jnp.inf)
-    probs = jax.nn.softmax(logits, axis=-1)
-    return pk, pv, jnp.einsum("bhqk,bkhd->bqhd", probs,
-                              vh).reshape(b, 1, d)
+    # the scope names the gather + GEMM in each op's metadata (XLA
+    # names the fusions themselves, so a trace's event NAMES need not
+    # carry it: PERF.md, Open questions)
+    with jax.named_scope("veles_paged_decode_attention"):
+        # gather ONLY the table's blocks — [B, T, bs, d] ->
+        # [B, T·bs, d]; the window never materializes
+        kg = pk[tables]
+        vg = pv[tables]
+        length = kg.shape[1] * bs
+        qh = q.reshape(b, 1, h, hd)
+        kh = kg.astype(cd).reshape(b, length, h, hd)
+        vh = vg.astype(cd).reshape(b, length, h, hd)
+        logits = jnp.einsum("bqhd,bkhd->bhqk", qh, kh) \
+            * (1.0 / jnp.sqrt(hd))
+        mask = (jnp.arange(length)[None, :]
+                <= pos[:, None])[:, None, None, :]
+        logits = jnp.where(mask, logits, -jnp.inf)
+        probs = jax.nn.softmax(logits, axis=-1)
+        ctx = jnp.einsum("bhqk,bkhd->bqhd", probs,
+                         vh).reshape(b, 1, d)
+    return pk, pv, ctx
 
 
 # -- int8 quantized pools ---------------------------------------------------

@@ -180,6 +180,7 @@ def _run_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
                           kv_len=kv_len),
+        name="veles_attn_fwd",
         grid=(bh, sq // block_q, sk // block_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -331,6 +332,7 @@ def _mha_bwd(scale, causal, block_q, block_k, interpret, kv_len, res,
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
                           kv_len=kv_len),
+        name="veles_attn_bwd_dq",
         grid=(bh, sq // block_q, sk // block_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -353,6 +355,7 @@ def _mha_bwd(scale, causal, block_q, block_k, interpret, kv_len, res,
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
                           kv_len=kv_len),
+        name="veles_attn_bwd_dkv",
         grid=(bh, sk // block_k, sq // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), q_map),

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 
 from veles_tpu.models.generate import (
     _StepClosure, _arch_sig, _device_params)
-from veles_tpu.telemetry import track_jit
+from veles_tpu.telemetry import trace_named, track_jit
 
 
 def sample_slots(logits, temps, topks, keys):
@@ -70,8 +70,8 @@ def sample_first(logits, temps, topks, seeds, counts):
     return sample_slots(logits, temps, topks, keys)
 
 
-_sample_first_jit = track_jit("serving.sample_first",
-                              jax.jit(sample_first))
+_sample_first_jit = track_jit("serving.sample_first", jax.jit(
+    trace_named("serving.sample_first", sample_first)))
 
 
 def _make_step(forwards):
@@ -97,7 +97,8 @@ def _make_step(forwards):
 
 @functools.lru_cache(maxsize=16)
 def _step_cached(cache_key, closure):
-    return track_jit("serving.slot_step", jax.jit(closure.fn))
+    return track_jit("serving.slot_step", jax.jit(
+        trace_named("serving.slot_step", closure.fn)))
 
 
 def clear_step_cache():
@@ -182,7 +183,8 @@ def _make_paged_step(forwards, want_hidden=False):
 
 @functools.lru_cache(maxsize=64)
 def _paged_step_cached(cache_key, closure):
-    return track_jit("serving.paged_step", jax.jit(closure.fn))
+    return track_jit("serving.paged_step", jax.jit(
+        trace_named("serving.paged_step", closure.fn)))
 
 
 def overlap_supported(forwards):
@@ -267,7 +269,8 @@ def _make_paged_step_tp(forwards, ctx, pools, want_hidden=False):
 
 @functools.lru_cache(maxsize=32)
 def _paged_step_tp_cached(cache_key, closure):
-    return track_jit("serving.paged_step_tp", jax.jit(closure.fn))
+    return track_jit("serving.paged_step_tp", jax.jit(
+        trace_named("serving.paged_step_tp", closure.fn)))
 
 
 def paged_decode_step(forwards, cache, toks, pos, tables, temps,
@@ -393,7 +396,8 @@ def _verify_step_cached(cache_key, closure, donate=False):
     # arrays are never read again).  The legacy two-pass executable
     # keeps the PR 9 no-donation behavior byte-for-byte.
     return track_jit("serving.verify_step", jax.jit(
-        closure.fn, donate_argnums=(9,) if donate else ()))
+        trace_named("serving.verify_step", closure.fn),
+        donate_argnums=(9,) if donate else ()))
 
 
 def verify_step_paged(forwards, cache, toks, pos, lens, tables,

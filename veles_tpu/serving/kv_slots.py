@@ -37,7 +37,7 @@ import numpy
 import jax
 import jax.numpy as jnp
 
-from veles_tpu.telemetry import track_jit
+from veles_tpu.telemetry import trace_named, track_jit
 
 
 def _row_pair(dst_k, dst_v, src_k, src_v, slot):
@@ -75,8 +75,8 @@ def _block_pair(pool_k, pool_v, src_k, src_v, ids, start):
             pool_v.at[ids].set(sv.astype(pool_v.dtype)))
 
 
-_insert_blocks = track_jit("serving.kv_insert_blocks",
-                           jax.jit(_block_pair))
+_insert_blocks = track_jit("serving.kv_insert_blocks", jax.jit(
+    trace_named("serving.kv_insert_blocks", _block_pair)))
 
 
 @functools.lru_cache(maxsize=1)

@@ -187,6 +187,84 @@ class SLOTracker:
         return out
 
 
+def _loop_series():
+    """The scheduler loop's phase account (``scheduler._LoopPhases``:
+    seconds of the loop thread by phase, self time, flushed once a
+    pass) and the request boundaries beside it.  Each a family of its
+    own, unlabelled, ``veles_serving_*_total``, so a scraper that sums
+    label sets away and keeps ``_total`` names reads every one."""
+    return {
+        "parked": metrics.counter(
+            "veles_serving_loop_parked_seconds_total",
+            "loop seconds waiting for work (nothing queued, active "
+            "or owed)"),
+        "admit": metrics.counter(
+            "veles_serving_loop_admit_seconds_total",
+            "loop seconds of its own bookkeeping: queue expiry, "
+            "admission sizing and block claims, the reap, owed "
+            "preemptions"),
+        "prefill": metrics.counter(
+            "veles_serving_loop_prefill_seconds_total",
+            "loop seconds of prompt processing: the input build, the "
+            "prefill or prefill-chunk dispatch, the KV insert, the "
+            "first token's readback"),
+        "aux": metrics.counter(
+            "veles_serving_loop_aux_seconds_total",
+            "loop seconds in embed/score and prefix export/import "
+            "jobs"),
+        "draft": metrics.counter(
+            "veles_serving_loop_draft_seconds_total",
+            "loop seconds of speculative drafting (0 with "
+            "speculation off)"),
+        "pack": metrics.counter(
+            "veles_serving_loop_pack_seconds_total",
+            "loop seconds building a decode/verify step's inputs: "
+            "buckets, the numpy rows, the block tables"),
+        "step": metrics.counter(
+            "veles_serving_loop_step_seconds_total",
+            "loop seconds from a decode/verify launch through the "
+            "readback of its tokens: the one phase that waits on "
+            "the device"),
+        "emit": metrics.counter(
+            "veles_serving_loop_emit_seconds_total",
+            "loop seconds accepting tokens: stream sinks, "
+            "retirement, slot and block release, futures"),
+        "observe": metrics.counter(
+            "veles_serving_loop_observe_seconds_total",
+            "loop seconds spent on being observed: step statistics, "
+            "tenant metering, request tracing, the KV gauges, this "
+            "account's flush"),
+        "loop": metrics.counter(
+            "veles_serving_loop_seconds_total",
+            "loop seconds in every phase but parked"),
+        "passes": metrics.counter(
+            "veles_serving_loop_passes_total",
+            "passes of the scheduler loop that found work"),
+        "steps": metrics.counter(
+            "veles_serving_steps_total",
+            "decode/verify steps launched (step phases)"),
+        "steps_after_prefill": metrics.counter(
+            "veles_serving_steps_after_prefill_total",
+            "steps launched in a pass that ran a prefill phase "
+            "before them (the prompt's device time rides in the "
+            "step's readback)"),
+        "step_after_prefill": metrics.counter(
+            "veles_serving_loop_step_after_prefill_seconds_total",
+            "step-phase seconds of those steps"),
+        "queue_wait": metrics.counter(
+            "veles_serving_queue_wait_seconds_total",
+            "submit-to-admission seconds, summed over first tokens"),
+        "prefill_wait": metrics.counter(
+            "veles_serving_prefill_wait_seconds_total",
+            "admission-to-first-token seconds, summed over first "
+            "tokens (the prompt's chunks and the decode steps "
+            "interleaved with them)"),
+        "first_tokens": metrics.counter(
+            "veles_serving_first_tokens_total",
+            "requests that reached their first token"),
+    }
+
+
 def _registry_series():
     return {
         "submitted": metrics.counter(
@@ -811,6 +889,7 @@ class ServingMetrics:
         self._classes = {}
         self._t0 = time.monotonic()
         self._global = _registry_series()
+        self._loop = _loop_series()
         #: replica-side SLO accounting (TTFT + e2e vs the per-class
         #: objectives under root.common.slo.*)
         self.slo = SLOTracker("serving")
@@ -1092,6 +1171,9 @@ class ServingMetrics:
         self._global["class_ttft_ms"].labels(cls=cls).observe(ttft_ms)
         self._global["ttft_p95"].labels(replica=self.replica).set(
             round(self._ttft.percentile(0.95), 3))
+        self._loop["queue_wait"].inc(queued_ms / 1e3)
+        self._loop["prefill_wait"].inc((ttft_ms - queued_ms) / 1e3)
+        self._loop["first_tokens"].inc()
         self.slo.record(cls, "ttft", ttft_ms)
 
     def record_prefill_chunk(self, tokens, chunk_ms):
@@ -1125,16 +1207,34 @@ class ServingMetrics:
         self._global["kv_bytes_per_token"].labels(
             replica=self.replica).set(int(bytes_per_token))
 
-    def record_step(self, active, slots, tokens=None,
-                    duration_s=None):
+    def record_loop_pass(self, seconds, steps, steps_after_prefill,
+                         step_after_prefill_seconds, passes=1):
+        """The scheduler loop's phase account since its last flush
+        (``scheduler._LoopPhases.drain()``): ``seconds`` by phase.
+        The loop calls this once a pass, so the account costs the
+        registry one visit a pass however many phases ran."""
+        for phase, took in seconds.items():
+            if took > 0:
+                self._loop[phase].inc(took)
+        self._loop["loop"].inc(
+            sum(seconds.values()) - seconds["parked"])
+        self._loop["passes"].inc(passes)
+        if steps:
+            self._loop["steps"].inc(steps)
+        if steps_after_prefill:
+            self._loop["steps_after_prefill"].inc(steps_after_prefill)
+            self._loop["step_after_prefill"].inc(
+                step_after_prefill_seconds)
+
+    def record_step(self, active, slots, tokens=None):
         """One batched decode/verify boundary: ``active`` real rows
         rode a padded ``slots``-row bucket; ``tokens`` is what the
         step actually emitted (spec verify can emit up to k+1 per
         slot, a fully-rejected slot emits 0) and feeds the goodput
-        gauge; ``duration_s`` is accepted for symmetry with the
-        tracing hook (the goodput window uses wall-clock arrival
-        times, so a stalled loop DROPS the gauge instead of freezing
-        it at the last healthy rate)."""
+        gauge (whose window uses wall-clock arrival times, so a
+        stalled loop DROPS the gauge instead of freezing it at the
+        last healthy rate).  The step's seconds are the loop's
+        ``step`` phase (:meth:`record_loop_pass`)."""
         now = time.monotonic()
         with self._lock:
             self.slot_busy_steps += int(active)

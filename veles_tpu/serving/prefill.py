@@ -25,7 +25,7 @@ import numpy
 from veles_tpu.models.generate import (
     _StepClosure, _arch_sig, _check_positions, _device_params,
     kv_cache_eligible)
-from veles_tpu.telemetry import track_jit
+from veles_tpu.telemetry import trace_named, track_jit
 
 
 def serving_supported(forwards):
@@ -106,7 +106,8 @@ def _make_chunk_fn(forwards, key_width):
 
 @functools.lru_cache(maxsize=64)
 def _chunk_cached(cache_key, closure):
-    return track_jit("serving.prefill_chunk", jax.jit(closure.fn))
+    return track_jit("serving.prefill_chunk", jax.jit(
+        trace_named("serving.prefill_chunk", closure.fn)))
 
 
 def clear_chunk_cache():
@@ -204,7 +205,8 @@ def _make_prefill_fn(forwards, window):
 
 @functools.lru_cache(maxsize=32)
 def _prefill_cached(cache_key, closure):
-    return track_jit("serving.prefill", jax.jit(closure.fn))
+    return track_jit("serving.prefill", jax.jit(
+        trace_named("serving.prefill", closure.fn)))
 
 
 def clear_prefill_cache():

@@ -168,6 +168,23 @@ counts), preempt/resume, first token, retire — through
 :meth:`debug_requests` is the live in-flight table behind ``GET
 /debug/requests``; per-class SLO good/bad counts and multi-window
 burn rates (``root.common.slo.*``) ride ``stats.slo``.
+
+Phase account (always on): every instant of the loop thread is charged
+to exactly one of ``PHASES`` — ``parked`` (waiting for work), ``admit``
+(the loop's own bookkeeping; the phase wherever no block names
+another), ``prefill``, ``aux``, ``draft``, ``pack`` (a step's inputs),
+``step`` (launch through token readback: the one phase that waits on
+the device), ``emit`` and ``observe`` (statistics, metering, request
+tracing, gauges, the account's own flush).  ``with self._phases(name)``
+marks a stretch; :class:`_LoopPhases` keeps plain floats on the loop
+thread and one ``ServingMetrics.record_loop_pass`` a pass moves them
+into ``veles_serving_loop_<phase>_seconds_total`` (self times: a
+nested block stops the outer clock).  Each stretch is also a
+``veles.sched.<phase>`` ``jax.profiler.TraceAnnotation`` with the same
+boundaries: under a profiler session the phases lie on the host plane
+beside the device's operations, and without one the annotation does
+nothing, so no switch guards it.  docs/observability.md, "Profiling
+the serving loop".
 """
 
 import collections
@@ -182,6 +199,7 @@ import numpy
 from veles_tpu import faults
 from veles_tpu.logger import Logger
 from veles_tpu.telemetry import reqtrace
+from veles_tpu.telemetry.spans import annotation
 from veles_tpu.serving.engine import (
     first_tokens, paged_decode_step, slot_decode_step,
     verify_step_paged, verify_supported)
@@ -313,6 +331,98 @@ def _metering_enabled():
     compute-seconds at step boundaries)."""
     from veles_tpu.config import root
     return bool(root.common.tsdb.get("metering", True))
+
+
+#: the phases of a loop pass (module docstring, "Phase account"); one
+#: ``veles_serving_loop_<phase>_seconds_total`` each (serving/metrics.py)
+PHASES = ("parked", "admit", "prefill", "aux", "draft", "pack", "step",
+          "emit", "observe")
+
+
+class _Phase(object):
+    """``with phases("step") as ph``: one stretch of one phase;
+    ``ph.seconds`` is its last uninterrupted stretch, read after the
+    block (the whole of it where nothing nests, as in ``step``)."""
+
+    __slots__ = ("account", "name", "outer", "seconds")
+
+    def __init__(self, account, name):
+        self.account, self.name = account, name
+
+    def __enter__(self):
+        self.outer = self.account.switch(self.name)
+        return self
+
+    def __exit__(self, *exc):
+        self.seconds = self.account.elapsed()
+        self.account.switch(self.outer)
+        return False
+
+
+class _LoopPhases(object):
+    """The loop thread's flat phase account.  Exactly one phase is
+    current at any instant (``admit``, the loop's own bookkeeping,
+    where no block says otherwise); switching charges the seconds
+    since the last switch to the phase that was current, in a plain
+    float, and moves the ``veles.sched.<phase>`` annotation with it,
+    so the spans in a profiler trace and the counters share their
+    boundaries.  An inner block stops the outer phase's clock and
+    leaving it resumes it: the numbers are self times.  Loop thread
+    only: no lock, no registry; :meth:`drain` hands a pass's totals
+    to ``ServingMetrics.record_loop_pass``, once a pass."""
+
+    def __init__(self):
+        self._reset()
+        self.current = "admit"
+        self._since = time.perf_counter()
+        self._span = annotation("veles.sched.admit")
+        self._span.__enter__()
+
+    def _reset(self):
+        self.seconds = dict.fromkeys(PHASES, 0.0)
+        self.steps = self.steps_after_prefill = 0
+        self.step_after_prefill_seconds = 0.0
+        self.prefilled = False     # a prefill phase ran this pass
+
+    def __call__(self, name):
+        return _Phase(self, name)
+
+    def elapsed(self):
+        """Seconds since the current phase was entered or resumed."""
+        return time.perf_counter() - self._since
+
+    def switch(self, name):
+        """Make ``name`` the current phase; returns the one it was."""
+        was = self.current
+        if name == was:
+            return was
+        now = time.perf_counter()
+        took = now - self._since
+        self.seconds[was] += took
+        if was == "step":
+            self.steps += 1
+            if self.prefilled:
+                self.steps_after_prefill += 1
+                self.step_after_prefill_seconds += took
+        if name == "prefill":
+            self.prefilled = True
+        self.current, self._since = name, now
+        self._span.__exit__(None, None, None)
+        self._span = annotation("veles.sched." + name)
+        self._span.__enter__()
+        return was
+
+    def drain(self):
+        """The totals since the last drain, as the arguments of
+        ``ServingMetrics.record_loop_pass``; the pass ends here."""
+        out = (self.seconds, self.steps, self.steps_after_prefill,
+               self.step_after_prefill_seconds)
+        self._reset()
+        return out
+
+    def close(self):
+        self.switch("admit")
+        self._span.__exit__(None, None, None)
 
 
 class _Request(object):
@@ -637,7 +747,7 @@ class InferenceScheduler(Logger):
         self._tron = reqtrace.enabled()
         #: per-tenant metering gate (root.common.tsdb.metering), read
         #: ONCE for the same reason — the step boundary is the hot
-        #: path the overhead soak holds to <5%
+        #: path (its cost to the loop: the ``observe`` phase)
         self._metering = _metering_enabled()
         self._queue = collections.deque()
         self._active = {}            # slot -> _Request (decoding)
@@ -664,6 +774,7 @@ class InferenceScheduler(Logger):
         self._watchdog_thread = None
         self._ready = threading.Event()
         self.cache_ = None           # set by the loop thread
+        self._phases = None          # _LoopPhases, the loop thread's
         self.prefix_ = None          # radix cache (loop thread too)
         #: host KV tier — constructed HERE (no device dependencies)
         #: so the reference is immutable across threads; only the
@@ -1669,74 +1780,100 @@ class InferenceScheduler(Logger):
                 req.future.set_exception(SchedulerError(repr(e)))
             raise
         self._ready.set()
-        while True:
-            with self._wake:
-                self._working = False
-                while not self._closed and not self._queue \
-                        and not self._active and not self._prefilling \
-                        and not self._preempts_owed \
-                        and not self._aux and not self._prefix_jobs:
-                    if self._draining:
-                        self._drained.set()
-                    # parked KV exports keep a 1 s housekeeping tick
-                    # alive so their TTL is enforced even on an idle
-                    # prefill replica (no decode work ever wakes it)
-                    self._wake.wait(1.0 if self._exports else None)
-                    if self._exports:
-                        self._sweep_exports_locked()
-                if self._closed:
-                    return
-                # the watchdog measures from here: one iteration =
-                # one reap + admit + chunk + decode step
-                self._working = True
-                self._beat = time.monotonic()
-                self._expire_locked()
-                if self._exports:
-                    self._sweep_exports_locked()
-                admits = []
-                while self._queue and self._can_admit(
-                        cache, self._queue[0]):
-                    req = self._queue.popleft()
-                    self._queued_blocks -= self._blocks_for(req)
-                    if not self._admit_claim(cache, req):
-                        # a racing claim in this same batch consumed
-                        # the headroom the peek counted — requeue at
-                        # the front and retry next boundary
-                        self._queue.appendleft(req)
-                        self._queued_blocks += self._blocks_for(req)
-                        break
-                    admits.append(req)
-                    self._admitting.append(req)
-                # priority preemption: the head of the class-ordered
-                # queue outranks an active lower-class request but
-                # could not admit — owe ONE eviction at this boundary
-                # (one per iteration bounds thrash; the victim's
-                # freed blocks seat the head at the next boundary)
-                if self._queue and not self._preempts_owed:
-                    head = self._queue[0]
-                    if head.priority > 0 \
-                            and not self._can_admit(cache, head) \
-                            and any(r.priority < head.priority
-                                    for r in self._active.values()):
-                        self._preempts_owed.append(head.priority)
-            # jax work OUTSIDE the lock: submit() must never block on
-            # a device step
-            faults.fire("serving.scheduler.loop")
-            self._reap(cache)
-            self._do_preempts(cache)
+        phases = self._phases = _LoopPhases()
+        try:
+            while self._pass(cache):
+                with phases("observe"):
+                    self.stats.record_loop_pass(*phases.drain())
+        finally:
+            # what the last pass and the wait before close() took
+            phases.close()
+            self.stats.record_loop_pass(*phases.drain(), passes=0)
+
+    def _idle_locked(self):
+        return not (self._closed or self._queue or self._active
+                    or self._prefilling or self._preempts_owed
+                    or self._aux or self._prefix_jobs)
+
+    def _pass(self, cache):
+        """One pass of the loop: park until there is work, then one
+        reap + admit + chunk + decode step.  False once closed.
+        Every instant is charged to one of ``PHASES``: ``admit``
+        where no block names another."""
+        phases = self._phases
+        with self._wake:
+            self._working = False
+            if self._idle_locked():
+                with phases("parked"):
+                    while self._idle_locked():
+                        if self._draining:
+                            self._drained.set()
+                        # parked KV exports keep a 1 s
+                        # housekeeping tick alive so their TTL is
+                        # enforced even on an idle prefill replica
+                        # (no decode work ever wakes it)
+                        self._wake.wait(
+                            1.0 if self._exports else None)
+                        if self._exports:
+                            self._sweep_exports_locked()
+            if self._closed:
+                return False
+            # the watchdog measures from here: one iteration =
+            # one reap + admit + chunk + decode step
+            self._working = True
+            self._beat = time.monotonic()
+            self._expire_locked()
+            if self._exports:
+                self._sweep_exports_locked()
+            admits = []
+            while self._queue and self._can_admit(
+                    cache, self._queue[0]):
+                req = self._queue.popleft()
+                self._queued_blocks -= self._blocks_for(req)
+                if not self._admit_claim(cache, req):
+                    # a racing claim in this same batch consumed
+                    # the headroom the peek counted — requeue at
+                    # the front and retry next boundary
+                    self._queue.appendleft(req)
+                    self._queued_blocks += self._blocks_for(req)
+                    break
+                admits.append(req)
+                self._admitting.append(req)
+            # priority preemption: the head of the class-ordered
+            # queue outranks an active lower-class request but
+            # could not admit — owe ONE eviction at this boundary
+            # (one per iteration bounds thrash; the victim's
+            # freed blocks seat the head at the next boundary)
+            if self._queue and not self._preempts_owed:
+                head = self._queue[0]
+                if head.priority > 0 \
+                        and not self._can_admit(cache, head) \
+                        and any(r.priority < head.priority
+                                for r in self._active.values()):
+                    self._preempts_owed.append(head.priority)
+        # jax work OUTSIDE the lock: submit() must never block on
+        # a device step
+        faults.fire("serving.scheduler.loop")
+        self._reap(cache)
+        self._do_preempts(cache)
+        with phases("observe"):
             self._sync_kv_gauges(cache)
-            for req in admits:
-                self._begin_admit(req, cache)
-                with self._lock:
-                    self._admitting.remove(req)
-            if self._aux:
+        for req in admits:
+            self._begin_admit(req, cache)
+            with self._lock:
+                self._admitting.remove(req)
+        if self._aux:
+            with phases("aux"):
                 self._aux_tick()
-            if self._prefix_jobs:
+        if self._prefix_jobs:
+            with phases("aux"):
                 self._prefix_tick(cache)
-            if self._prefilling:
+        if self._prefilling:
+            with phases("prefill"):
                 self._prefill_tick(cache)
-            if self._active:
-                self._step(cache)
+        if self._active:
+            self._step(cache)
+        return True
 
     def _can_admit(self, cache, req):
         """Admission sizing for the head-of-queue request.  A warm
@@ -1971,7 +2108,8 @@ class InferenceScheduler(Logger):
             self._active.pop(req.slot, None)
         self._release_slot(req, cache)
         req.pf_seq = req.pf_caches = None
-        self._sync_kv_gauges(cache)
+        with self._phases("observe"):
+            self._sync_kv_gauges(cache)
 
     def _do_preempts(self, cache):
         """Evict owed preemptions at this decode boundary: lowest
@@ -2000,10 +2138,11 @@ class InferenceScheduler(Logger):
                 self._active.pop(req.slot, None)
             self._release_slot(req, cache)
             req.preempts += 1
-            self.stats.record_preempt(len(req.generated),
-                                      cls=CLASS_NAMES[req.priority],
-                                      trace=req.trace)
-            self._sync_kv_gauges(cache)
+            with self._phases("observe"):
+                self.stats.record_preempt(
+                    len(req.generated),
+                    cls=CLASS_NAMES[req.priority], trace=req.trace)
+                self._sync_kv_gauges(cache)
             with self._lock:
                 self._enqueue_locked(req, front=True)
                 self._queued_blocks += self._blocks_for(req)
@@ -2091,7 +2230,8 @@ class InferenceScheduler(Logger):
             # A preempt-resume of an imported request falls through
             # to the normal re-prefill below instead (its blocks
             # were freed; the chain recomputes the identical K/V)
-            self._admit_import(req, cache)
+            with self._phases("prefill"):
+                self._admit_import(req, cache)
             return
         seq = list(req.prompt) + list(req.generated)
         if req.preempts and req.generated:
@@ -2103,23 +2243,26 @@ class InferenceScheduler(Logger):
             # decision: cold vs prefix-warm and the blocks claimed —
             # the first two entries of a request's phase timeline
             need = self._blocks_for(req)
-            reqtrace.record(
-                req.trace, "queue",
-                duration=req.t_admit - req.t_submit,
-                cls=CLASS_NAMES[req.priority],
-                tenant=req.tenant,
-                resume=bool(req.preempts))
-            reqtrace.record(
-                req.trace, "admit", slot=req.slot, tokens=p_len,
-                warm_blocks=req.pf_matched,
-                blocks_claimed=max(0, need - req.pf_matched),
-                resume=bool(req.preempts))
+            with self._phases("observe"):
+                reqtrace.record(
+                    req.trace, "queue",
+                    duration=req.t_admit - req.t_submit,
+                    cls=CLASS_NAMES[req.priority],
+                    tenant=req.tenant,
+                    resume=bool(req.preempts))
+                reqtrace.record(
+                    req.trace, "admit", slot=req.slot, tokens=p_len,
+                    warm_blocks=req.pf_matched,
+                    blocks_claimed=max(0, need - req.pf_matched),
+                    resume=bool(req.preempts))
         if req.pf_matched:
-            self._admit_warm(req, cache)
+            with self._phases("prefill"):
+                self._admit_warm(req, cache)
             return
         chunk = self.prefill_chunk
         if not chunk or p_len <= chunk:
-            self._admit_oneshot(req, cache)
+            with self._phases("prefill"):
+                self._admit_oneshot(req, cache)
             return
         from veles_tpu import dtypes
         req.pf_chunk = chunk
@@ -2175,7 +2318,6 @@ class InferenceScheduler(Logger):
         p_w = min(width, max(self.window, p_len))
         padded = numpy.zeros((1, p_w), numpy.int32)
         padded[0, :p_len] = req.pf_seq
-        t0 = time.perf_counter()
         try:
             faults.fire("serving.scheduler.prefill")
             row_caches, last = prefill(
@@ -2185,9 +2327,11 @@ class InferenceScheduler(Logger):
             self._retire(req, cache, error=e)
             return
         if self._tron:
-            reqtrace.record(req.trace, "prefill",
-                            duration=time.perf_counter() - t0,
-                            tokens=p_len)
+            # the prefill phase so far: the input build and the call
+            dt = self._phases.elapsed()
+            with self._phases("observe"):
+                reqtrace.record(req.trace, "prefill", duration=dt,
+                                tokens=p_len)
         self._finish_admit(req, cache, row_caches, last)
 
     def _prefill_tick(self, cache):
@@ -2206,7 +2350,6 @@ class InferenceScheduler(Logger):
         padded = numpy.zeros((1, c), numpy.int32)
         padded[0, :clen] = req.pf_seq[off:end]
         kw = _bucket(off + c, c, req.pf_width)
-        t0 = time.perf_counter()
         try:
             faults.fire("serving.scheduler.prefill")
             req.pf_caches, last = prefill_chunk(
@@ -2218,12 +2361,15 @@ class InferenceScheduler(Logger):
                     self._prefilling.remove(req)
             self._retire(req, cache, error=e)
             return
-        self.stats.record_prefill_chunk(
-            clen, (time.perf_counter() - t0) * 1e3)
-        if self._tron:
-            reqtrace.record(req.trace, "prefill_chunk",
-                            duration=time.perf_counter() - t0,
-                            off=off, tokens=clen)
+        # the prefill phase so far: the input build and the DISPATCH
+        # of the chunk (no readback here: its device time rides in the
+        # next step phase, veles_serving_steps_after_prefill_total)
+        dt = self._phases.elapsed()
+        with self._phases("observe"):
+            self.stats.record_prefill_chunk(clen, dt * 1e3)
+            if self._tron:
+                reqtrace.record(req.trace, "prefill_chunk",
+                                duration=dt, off=off, tokens=clen)
         req.pf_off = end
         if end >= p_len:
             with self._lock:
@@ -2266,18 +2412,20 @@ class InferenceScheduler(Logger):
         tok = int(numpy.asarray(first_tokens(
             last, [req.temperature], [req.top_k], [req.seed],
             counts=[len(req.generated)]))[0])
-        self._emit(req, tok)
+        with self._phases("emit"):
+            self._emit(req, tok)
         if req.t_first is None:  # TTFT is the FIRST first-token only
             req.t_first = time.monotonic()
-            self.stats.record_first_token(
-                (req.t_first - req.t_submit) * 1e3,
-                (req.t_admit - req.t_submit) * 1e3,
-                cls=CLASS_NAMES[req.priority])
-            if self._tron:
-                reqtrace.record(
-                    req.trace, "first_token",
-                    ttft_ms=round(
-                        (req.t_first - req.t_submit) * 1e3, 3))
+            with self._phases("observe"):
+                self.stats.record_first_token(
+                    (req.t_first - req.t_submit) * 1e3,
+                    (req.t_admit - req.t_submit) * 1e3,
+                    cls=CLASS_NAMES[req.priority])
+                if self._tron:
+                    reqtrace.record(
+                        req.trace, "first_token",
+                        ttft_ms=round(
+                            (req.t_first - req.t_submit) * 1e3, 3))
         with self._lock:
             self._active[req.slot] = req
         self._maybe_finish(req, cache)
@@ -2300,14 +2448,15 @@ class InferenceScheduler(Logger):
             self._retire(req, cache, error=e)
             return
         if self._tron:
-            reqtrace.record(
-                req.trace, "queue",
-                duration=req.t_admit - req.t_submit,
-                cls=CLASS_NAMES[req.priority],
-                tenant=req.tenant, resume=False)
-            reqtrace.record(
-                req.trace, "kv_import", slot=req.slot,
-                tokens=int(imp["length"]), blocks=len(ids))
+            with self._phases("observe"):
+                reqtrace.record(
+                    req.trace, "queue",
+                    duration=req.t_admit - req.t_submit,
+                    cls=CLASS_NAMES[req.priority],
+                    tenant=req.tenant, resume=False)
+                reqtrace.record(
+                    req.trace, "kv_import", slot=req.slot,
+                    tokens=int(imp["length"]), blocks=len(ids))
         last = numpy.asarray(imp["logits"],
                              numpy.float32).reshape(1, -1)
         self._activate(req, cache, last)
@@ -2340,45 +2489,48 @@ class InferenceScheduler(Logger):
         except Exception as e:
             self._retire(req, cache, error=e)
             return
-        req.pf_caches = None
-        req.pf_seq = None
-        with self._lock:
-            self._active.pop(req.slot, None)
-        self._release_slot(req, cache, finished=True)
-        self._sync_kv_gauges(cache)
-        now = time.monotonic()
-        from veles_tpu.serving.disagg import record_nbytes
-        record["bytes"] = record_nbytes(record)
-        with self._lock:
-            self._sweep_exports_locked(now)
-            capped = 0
-            while self._exports and self._exports_bytes \
-                    + record["bytes"] > self.kv_export_bytes:
-                # oldest unclaimed record pays for the byte budget
-                oldest = min(self._exports,
-                             key=lambda h: self._exports[h]["t"])
-                self._exports_bytes -= \
-                    self._exports[oldest].get("bytes", 0)
-                del self._exports[oldest]
-                capped += 1
-            if capped:
-                # a cap eviction is an unfetched loss like an
-                # expiry, just paid early — same alertable series
-                self.stats.record_kv_export_expired(capped)
-            self._exports[handle] = record
-            self._exports_bytes += record["bytes"]
-            self.stats.set_kv_exports_pending(len(self._exports))
-        if self._tron:
-            reqtrace.record(
-                req.trace, "kv_export", tokens=p_len, blocks=n,
-                total_s=round(now - req.t_submit, 6))
-        if not req.future.done():
-            try:
-                req.future.set_result({
-                    "handle": handle, "prompt_tokens": p_len,
-                    "blocks": n})
-            except concurrent.futures.InvalidStateError:
-                pass
+        with self._phases("emit"):
+            req.pf_caches = None
+            req.pf_seq = None
+            with self._lock:
+                self._active.pop(req.slot, None)
+            self._release_slot(req, cache, finished=True)
+            with self._phases("observe"):
+                self._sync_kv_gauges(cache)
+            now = time.monotonic()
+            from veles_tpu.serving.disagg import record_nbytes
+            record["bytes"] = record_nbytes(record)
+            with self._lock:
+                self._sweep_exports_locked(now)
+                capped = 0
+                while self._exports and self._exports_bytes \
+                        + record["bytes"] > self.kv_export_bytes:
+                    # oldest unclaimed record pays for the byte budget
+                    oldest = min(self._exports,
+                                 key=lambda h: self._exports[h]["t"])
+                    self._exports_bytes -= \
+                        self._exports[oldest].get("bytes", 0)
+                    del self._exports[oldest]
+                    capped += 1
+                if capped:
+                    # a cap eviction is an unfetched loss like an
+                    # expiry, just paid early — same alertable series
+                    self.stats.record_kv_export_expired(capped)
+                self._exports[handle] = record
+                self._exports_bytes += record["bytes"]
+                self.stats.set_kv_exports_pending(len(self._exports))
+            if self._tron:
+                with self._phases("observe"):
+                    reqtrace.record(
+                        req.trace, "kv_export", tokens=p_len, blocks=n,
+                        total_s=round(now - req.t_submit, 6))
+            if not req.future.done():
+                try:
+                    req.future.set_result({
+                        "handle": handle, "prompt_tokens": p_len,
+                        "blocks": n})
+                except concurrent.futures.InvalidStateError:
+                    pass
 
     def _step(self, cache):
         """Advance every active request one token through the shared
@@ -2518,58 +2670,63 @@ class InferenceScheduler(Logger):
         to a power-of-two occupancy bucket; the attended range is the
         power-of-two block bucket of the deepest request."""
         if self.spec:
-            drafts, sources = self._draft(active)
+            with self._phases("draft"):
+                drafts, sources = self._draft(active)
             if drafts:
                 self._step_verify(cache, active, drafts, sources)
                 return
-        slots = sorted(active)
-        n = len(slots)
-        b = _bucket(n, 1, self.max_slots)
-        bs = cache.block_size
-        deepest = max(len(active[s].prompt) + len(active[s].generated)
-                      for s in slots)
-        t = _bucket(-(-deepest // bs), 1, cache.blocks_per_slot)
-        toks = numpy.zeros((b, 1), numpy.int32)
-        pos = numpy.zeros((b,), numpy.int32)
-        temps = numpy.zeros((b,), numpy.float32)
-        topks = numpy.zeros((b,), numpy.int32)
-        seeds = numpy.zeros((b,), numpy.uint32)
-        counts = numpy.zeros((b,), numpy.int32)
-        tables = numpy.zeros((b, t), numpy.int32)
-        arrays = (toks, pos, temps, topks, seeds, counts)
-        for j, slot in enumerate(slots):
-            self._fill_row(arrays, j, active[slot])
-        tables[:n] = cache.table_rows(slots, t)
-        want_h = self._draft_head is not None
-        t0 = time.perf_counter()
-        got = paged_decode_step(
-            self.forwards, cache, toks, pos, tables, temps, topks,
-            seeds, counts, want_hidden=want_h)
-        if want_h:
-            nxt, hid = got
-            hid = numpy.asarray(hid)
-        else:
-            nxt = got
-        nxt = numpy.asarray(nxt)
-        dt = time.perf_counter() - t0
-        # plain decode: every active slot emits exactly one token
-        self.stats.record_step(n, b, tokens=n, duration_s=dt)
-        self._meter_step(active, cache, dt)
-        for j, slot in enumerate(slots):
-            req = active[slot]
+        with self._phases("pack"):
+            slots = sorted(active)
+            n = len(slots)
+            b = _bucket(n, 1, self.max_slots)
+            bs = cache.block_size
+            deepest = max(len(active[s].prompt)
+                          + len(active[s].generated) for s in slots)
+            t = _bucket(-(-deepest // bs), 1, cache.blocks_per_slot)
+            toks = numpy.zeros((b, 1), numpy.int32)
+            pos = numpy.zeros((b,), numpy.int32)
+            temps = numpy.zeros((b,), numpy.float32)
+            topks = numpy.zeros((b,), numpy.int32)
+            seeds = numpy.zeros((b,), numpy.uint32)
+            counts = numpy.zeros((b,), numpy.int32)
+            tables = numpy.zeros((b, t), numpy.int32)
+            arrays = (toks, pos, temps, topks, seeds, counts)
+            for j, slot in enumerate(slots):
+                self._fill_row(arrays, j, active[slot])
+            tables[:n] = cache.table_rows(slots, t)
+            want_h = self._draft_head is not None
+        with self._phases("step") as launch:
+            got = paged_decode_step(
+                self.forwards, cache, toks, pos, tables, temps, topks,
+                seeds, counts, want_hidden=want_h)
             if want_h:
-                # hidden of the position just decoded — what the
-                # Medusa heads condition on next iteration
-                req.hid = hid[j]
-            self._emit(req, int(nxt[j]))
-            self._maybe_finish(req, cache)
+                nxt, hid = got
+                hid = numpy.asarray(hid)
+            else:
+                nxt = got
+            nxt = numpy.asarray(nxt)
+        dt = launch.seconds
+        with self._phases("observe"):
+            # plain decode: every active slot emits exactly one token
+            self.stats.record_step(n, b, tokens=n)
+            self._meter_step(active, cache, dt)
+        with self._phases("emit"):
+            for j, slot in enumerate(slots):
+                req = active[slot]
+                if want_h:
+                    # hidden of the position just decoded — what the
+                    # Medusa heads condition on next iteration
+                    req.hid = hid[j]
+                self._emit(req, int(nxt[j]))
+                self._maybe_finish(req, cache)
         if self._tron:
-            emitted = {}
-            for s in slots:  # batch rows may SHARE a client trace id
-                tr = active[s].trace
-                emitted[tr] = emitted.get(tr, 0) + 1
-            reqtrace.record_step(emitted, duration=dt,
-                                 mode="decode", slots=n, bucket=b)
+            with self._phases("observe"):
+                emitted = {}
+                for s in slots:  # batch rows may SHARE a client trace id
+                    tr = active[s].trace
+                    emitted[tr] = emitted.get(tr, 0) + 1
+                reqtrace.record_step(emitted, duration=dt,
+                                     mode="decode", slots=n, bucket=b)
 
     def _step_verify(self, cache, active, drafts, sources):
         """Speculative step: every active slot rides ONE batched
@@ -2580,125 +2737,135 @@ class InferenceScheduler(Logger):
         prefix plus the correction sample, so the emitted stream is
         bit-identical to spec-off decoding while one pass can emit
         up to k + 1 tokens."""
-        slots = sorted(active)
-        n = len(slots)
-        b = _bucket(n, 1, self.max_slots)
-        # adaptive draft width — MODEL-DRAFTER schedulers only: the
-        # verify runs at the power-of-two bucket of the widest draft
-        # BUDGET among drafting slots, so when every slot's EMA
-        # controller has shrunk its draft_k the pass stops paying
-        # spec_k-wide sampling for one-token drafts.  Keying on
-        # draft_k (not raw draft lengths) keeps un-shrunk batches on
-        # the spec_k-wide executable; the ladder is bounded at
-        # log2(spec_k) + 1 per (B, T) and only exists where a draft
-        # head is attached — n-gram-only schedulers keep the ONE
-        # fixed-width executable (drafts pad up, ``lens`` masks), so
-        # the flipped-on spec default compiles nothing extra.
-        if self._draft_head is not None:
-            k = _bucket(max(active[s].draft_k for s in drafts),
-                        1, self.spec_k)
-        else:
-            k = _bucket(self.spec_k, 1, self.spec_k)
-        bs = cache.block_size
-        deepest = max(len(active[s].prompt)
-                      + len(active[s].generated) for s in slots) + k
-        t = _bucket(-(-deepest // bs), 1, cache.blocks_per_slot)
-        toks = numpy.zeros((b, k + 1), numpy.int32)
-        pos = numpy.zeros((b,), numpy.int32)
-        lens = numpy.ones((b,), numpy.int32)
-        temps = numpy.zeros((b,), numpy.float32)
-        topks = numpy.zeros((b,), numpy.int32)
-        seeds = numpy.zeros((b,), numpy.uint32)
-        counts = numpy.zeros((b,), numpy.int32)
-        tables = numpy.zeros((b, t), numpy.int32)
-        for j, slot in enumerate(slots):
-            req = active[slot]
-            d = drafts.get(slot, ())[:k]
-            toks[j, 0] = req.generated[-1]
-            if d:
-                toks[j, 1:1 + len(d)] = d
-            pos[j] = len(req.prompt) + len(req.generated) - 1
-            lens[j] = 1 + len(d)
-            temps[j] = req.temperature
-            topks[j] = req.top_k
-            seeds[j] = req.seed
-            counts[j] = len(req.generated)
-        tables[:n] = cache.table_rows(slots, t)
-        want_h = self._draft_head is not None
-        t0 = time.perf_counter()
-        got = verify_step_paged(
-            self.forwards, cache, toks, pos, lens, tables, temps,
-            topks, seeds, counts, want_hidden=want_h)
-        if want_h:
-            nxt, hid = got
-            hid = numpy.asarray(hid)
-        else:
-            nxt = got
-        nxt = numpy.asarray(nxt)
-        dt = time.perf_counter() - t0
-        # metered BEFORE acceptance retires finished slots — the
-        # step's residency belongs to everyone who rode the batch
-        self._meter_step(active, cache, dt)
+        with self._phases("pack"):
+            slots = sorted(active)
+            n = len(slots)
+            b = _bucket(n, 1, self.max_slots)
+            # adaptive draft width — MODEL-DRAFTER schedulers only: the
+            # verify runs at the power-of-two bucket of the widest draft
+            # BUDGET among drafting slots, so when every slot's EMA
+            # controller has shrunk its draft_k the pass stops paying
+            # spec_k-wide sampling for one-token drafts.  Keying on
+            # draft_k (not raw draft lengths) keeps un-shrunk batches on
+            # the spec_k-wide executable; the ladder is bounded at
+            # log2(spec_k) + 1 per (B, T) and only exists where a draft
+            # head is attached — n-gram-only schedulers keep the ONE
+            # fixed-width executable (drafts pad up, ``lens`` masks), so
+            # the flipped-on spec default compiles nothing extra.
+            if self._draft_head is not None:
+                k = _bucket(max(active[s].draft_k for s in drafts),
+                            1, self.spec_k)
+            else:
+                k = _bucket(self.spec_k, 1, self.spec_k)
+            bs = cache.block_size
+            deepest = max(len(active[s].prompt)
+                          + len(active[s].generated) for s in slots) + k
+            t = _bucket(-(-deepest // bs), 1, cache.blocks_per_slot)
+            toks = numpy.zeros((b, k + 1), numpy.int32)
+            pos = numpy.zeros((b,), numpy.int32)
+            lens = numpy.ones((b,), numpy.int32)
+            temps = numpy.zeros((b,), numpy.float32)
+            topks = numpy.zeros((b,), numpy.int32)
+            seeds = numpy.zeros((b,), numpy.uint32)
+            counts = numpy.zeros((b,), numpy.int32)
+            tables = numpy.zeros((b, t), numpy.int32)
+            for j, slot in enumerate(slots):
+                req = active[slot]
+                d = drafts.get(slot, ())[:k]
+                toks[j, 0] = req.generated[-1]
+                if d:
+                    toks[j, 1:1 + len(d)] = d
+                pos[j] = len(req.prompt) + len(req.generated) - 1
+                lens[j] = 1 + len(d)
+                temps[j] = req.temperature
+                topks[j] = req.top_k
+                seeds[j] = req.seed
+                counts[j] = len(req.generated)
+            tables[:n] = cache.table_rows(slots, t)
+            want_h = self._draft_head is not None
+        with self._phases("step") as launch:
+            got = verify_step_paged(
+                self.forwards, cache, toks, pos, lens, tables, temps,
+                topks, seeds, counts, want_hidden=want_h)
+            if want_h:
+                nxt, hid = got
+                hid = numpy.asarray(hid)
+            else:
+                nxt = got
+            nxt = numpy.asarray(nxt)
+        dt = launch.seconds
+        with self._phases("observe"):
+            # metered BEFORE acceptance retires finished slots — the
+            # step's residency belongs to everyone who rode the batch
+            self._meter_step(active, cache, dt)
         emitted = {}
-        for j, slot in enumerate(slots):
-            req = active[slot]
-            d = list(drafts.get(slot, ()))[:k]
-            out = accept_drafts(d, nxt[j, :len(d) + 1])
-            before = len(req.generated)
-            for tok in out:
-                self._emit(req, int(tok))
-                if len(req.generated) >= req.steps \
-                        or (req.stop_token is not None
-                            and int(tok) == req.stop_token):
-                    break
-            done = len(req.generated) - before
-            if want_h and done > 0:
-                # hidden of the LAST position this verify scored and
-                # kept — row [j, done-1] conditioned the token now
-                # pending, so the Medusa heads read it next iteration
-                req.hid = hid[j, done - 1]
-            if d:
-                self._adapt_draft_k(req, len(d), len(out) - 1,
-                                    sources.get(slot, "ngram"))
-            emitted[req.trace] = emitted.get(req.trace, 0) + done
-            self._maybe_finish(req, cache)
-        # recorded AFTER acceptance so goodput counts what the verify
-        # actually emitted (a fully-rejected batch is 0 good tokens)
-        self.stats.record_step(n, b, tokens=sum(emitted.values()),
-                               duration_s=dt)
-        if self._tron:
-            reqtrace.record_step(emitted, duration=dt, mode="verify",
-                                 slots=n, bucket=b, k=k)
+        with self._phases("emit"):
+            for j, slot in enumerate(slots):
+                req = active[slot]
+                d = list(drafts.get(slot, ()))[:k]
+                out = accept_drafts(d, nxt[j, :len(d) + 1])
+                before = len(req.generated)
+                for tok in out:
+                    self._emit(req, int(tok))
+                    if len(req.generated) >= req.steps \
+                            or (req.stop_token is not None
+                                and int(tok) == req.stop_token):
+                        break
+                done = len(req.generated) - before
+                if want_h and done > 0:
+                    # hidden of the LAST position this verify scored
+                    # and kept — row [j, done-1] conditioned the token
+                    # now pending, so the Medusa heads read it next
+                    # iteration
+                    req.hid = hid[j, done - 1]
+                if d:
+                    self._adapt_draft_k(req, len(d), len(out) - 1,
+                                        sources.get(slot, "ngram"))
+                emitted[req.trace] = emitted.get(req.trace, 0) + done
+                self._maybe_finish(req, cache)
+        with self._phases("observe"):
+            # recorded AFTER acceptance so goodput counts what the
+            # verify actually emitted (a fully-rejected batch is 0
+            # good tokens)
+            self.stats.record_step(n, b, tokens=sum(emitted.values()))
+            if self._tron:
+                reqtrace.record_step(emitted, duration=dt,
+                                     mode="verify", slots=n, bucket=b,
+                                     k=k)
 
     def _step_dense(self, cache, active):
         """Legacy full-batch step: free slots decode garbage rows."""
         s = self.max_slots
-        toks = numpy.zeros((s, 1), numpy.int32)
-        pos = numpy.zeros((s,), numpy.int32)
-        temps = numpy.zeros((s,), numpy.float32)
-        topks = numpy.zeros((s,), numpy.int32)
-        seeds = numpy.zeros((s,), numpy.uint32)
-        counts = numpy.zeros((s,), numpy.int32)
-        arrays = (toks, pos, temps, topks, seeds, counts)
-        for slot, req in active.items():
-            self._fill_row(arrays, slot, req)
-        t0 = time.perf_counter()
-        nxt = numpy.asarray(slot_decode_step(
-            self.forwards, cache, toks, pos, temps, topks, seeds,
-            counts))
-        dt = time.perf_counter() - t0
-        self.stats.record_step(len(active), s, tokens=len(active),
-                               duration_s=dt)
-        self._meter_step(active, cache, dt)
-        for slot, req in active.items():
-            self._emit(req, int(nxt[slot]))
-            self._maybe_finish(req, cache)
+        with self._phases("pack"):
+            toks = numpy.zeros((s, 1), numpy.int32)
+            pos = numpy.zeros((s,), numpy.int32)
+            temps = numpy.zeros((s,), numpy.float32)
+            topks = numpy.zeros((s,), numpy.int32)
+            seeds = numpy.zeros((s,), numpy.uint32)
+            counts = numpy.zeros((s,), numpy.int32)
+            arrays = (toks, pos, temps, topks, seeds, counts)
+            for slot, req in active.items():
+                self._fill_row(arrays, slot, req)
+        with self._phases("step") as launch:
+            nxt = numpy.asarray(slot_decode_step(
+                self.forwards, cache, toks, pos, temps, topks, seeds,
+                counts))
+        dt = launch.seconds
+        with self._phases("observe"):
+            self.stats.record_step(len(active), s, tokens=len(active))
+            self._meter_step(active, cache, dt)
+        with self._phases("emit"):
+            for slot, req in active.items():
+                self._emit(req, int(nxt[slot]))
+                self._maybe_finish(req, cache)
         if self._tron:
-            emitted = {}
-            for r in active.values():
-                emitted[r.trace] = emitted.get(r.trace, 0) + 1
-            reqtrace.record_step(emitted, duration=dt, mode="decode",
-                                 slots=len(active), bucket=s)
+            with self._phases("observe"):
+                emitted = {}
+                for r in active.values():
+                    emitted[r.trace] = emitted.get(r.trace, 0) + 1
+                reqtrace.record_step(emitted, duration=dt,
+                                     mode="decode", slots=len(active),
+                                     bucket=s)
 
     def _maybe_finish(self, req, cache, error=None):
         done = error is not None \
@@ -2709,42 +2876,48 @@ class InferenceScheduler(Logger):
             self._retire(req, cache, error=error)
 
     def _retire(self, req, cache, error=None):
-        with self._lock:
-            self._active.pop(req.slot, None)
-        self._release_slot(req, cache, finished=error is None)
-        self._sync_kv_gauges(cache)
-        if self._metering:
-            # token attribution happens for ERRORS too — the prefill
-            # and decode compute was spent either way, and a bill
-            # that forgets failures undercharges the tenant causing
-            # them
-            self.stats.record_tenant_tokens(
-                req.tenant, prompt=len(req.prompt),
-                generated=len(req.generated))
-        if self._tron:
-            # an INSTANT at the retire boundary ("duration" would
-            # backdate it into a request-spanning bar): total_s is
-            # the whole submit->retire wall time as an attribute
-            reqtrace.record(
-                req.trace, "retire", tokens=len(req.generated),
-                total_s=round(time.monotonic() - req.t_submit, 6),
-                preempts=req.preempts,
-                outcome="ok" if error is None
-                else type(error).__name__)
-        if error is not None:
-            req.fail(error if isinstance(error, SchedulerError)
-                     else SchedulerError(repr(error)))
-            return
-        if req.future.done():
-            # watchdog/cancel failed it first — the tokens are moot
-            return
-        now = time.monotonic()
-        self.stats.record_complete(
-            len(req.generated), now - req.t_submit,
-            (req.t_first - req.t_submit) * 1e3,
-            (req.t_admit - req.t_submit) * 1e3,
-            cls=CLASS_NAMES[req.priority], trace=req.trace)
-        try:
-            req.future.set_result(list(req.prompt) + req.generated)
-        except concurrent.futures.InvalidStateError:
-            pass
+        with self._phases("emit"):
+            with self._lock:
+                self._active.pop(req.slot, None)
+            self._release_slot(req, cache, finished=error is None)
+            with self._phases("observe"):
+                self._sync_kv_gauges(cache)
+                if self._metering:
+                    # token attribution happens for ERRORS too — the
+                    # prefill and decode compute was spent either way,
+                    # and a bill that forgets failures undercharges
+                    # the tenant causing them
+                    self.stats.record_tenant_tokens(
+                        req.tenant, prompt=len(req.prompt),
+                        generated=len(req.generated))
+                if self._tron:
+                    # an INSTANT at the retire boundary ("duration"
+                    # would backdate it into a request-spanning bar):
+                    # total_s is the whole submit->retire wall time as
+                    # an attribute
+                    reqtrace.record(
+                        req.trace, "retire",
+                        tokens=len(req.generated),
+                        total_s=round(
+                            time.monotonic() - req.t_submit, 6),
+                        preempts=req.preempts,
+                        outcome="ok" if error is None
+                        else type(error).__name__)
+            if error is not None:
+                req.fail(error if isinstance(error, SchedulerError)
+                         else SchedulerError(repr(error)))
+                return
+            if req.future.done():
+                # watchdog/cancel failed it first — the tokens are moot
+                return
+            now = time.monotonic()
+            with self._phases("observe"):
+                self.stats.record_complete(
+                    len(req.generated), now - req.t_submit,
+                    (req.t_first - req.t_submit) * 1e3,
+                    (req.t_admit - req.t_submit) * 1e3,
+                    cls=CLASS_NAMES[req.priority], trace=req.trace)
+            try:
+                req.future.set_result(list(req.prompt) + req.generated)
+            except concurrent.futures.InvalidStateError:
+                pass

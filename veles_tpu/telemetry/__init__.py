@@ -16,7 +16,8 @@ See ``docs/observability.md`` for the metric names and span schema.
 from veles_tpu.telemetry.alerts import (  # noqa: F401
     AlertEngine, AlertRule, default_rules, firing_table)
 from veles_tpu.telemetry.compile_tracker import (  # noqa: F401
-    compile_summary, cost_summary, maybe_profiler_trace, track_jit)
+    compile_summary, cost_summary, maybe_profiler_trace, trace_named,
+    track_jit)
 from veles_tpu.telemetry.federation import (  # noqa: F401
     fleet_families, merge_scrapes, parse_prometheus)
 from veles_tpu.telemetry.flight_recorder import (  # noqa: F401
@@ -29,7 +30,7 @@ from veles_tpu.telemetry.registry import (  # noqa: F401
 from veles_tpu.telemetry.reqtrace import (  # noqa: F401
     TRACE_HEADER, clean_trace_id, ensure_trace_id, new_trace_id)
 from veles_tpu.telemetry.spans import (  # noqa: F401
-    iter_spans, next_span_id, span)
+    annotation, iter_spans, next_span_id, span)
 from veles_tpu.telemetry.tsdb import (  # noqa: F401
     DEFAULT_TIERS, TimeSeriesStore, bundle_history, history_query)
 

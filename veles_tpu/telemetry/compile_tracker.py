@@ -23,6 +23,7 @@ import contextlib
 import functools
 import threading
 import time
+import types
 
 from veles_tpu.logger import events
 from veles_tpu.telemetry.registry import metrics
@@ -279,6 +280,23 @@ def track_jit(name, fn):
     the wrapped callable's, so dropping the jit handle still frees
     the compiled executables and everything their closures pin."""
     return _TrackedJit(name, fn)
+
+
+def trace_named(name, fn):
+    """``fn`` under the name a device trace should show it by: jax
+    calls a jitted function's compiled module ``jit_<fn.__name__>``,
+    and the serving closures are all ``step`` / ``fn`` / ``pair``.
+    ``track_jit("serving.paged_step", jax.jit(trace_named(
+    "serving.paged_step", closure.fn)))`` compiles to module
+    ``jit_serving_paged_step``, so the profiler's ``XLA Modules`` line
+    splits device time by entry point.  A renamed copy: ``fn`` itself
+    keeps its name."""
+    out = types.FunctionType(fn.__code__, fn.__globals__,
+                             name.replace(".", "_"), fn.__defaults__,
+                             fn.__closure__)
+    out.__kwdefaults__ = fn.__kwdefaults__
+    out.__qualname__ = out.__name__
+    return out
 
 
 def compile_summary():

@@ -13,6 +13,11 @@ top:
 
 The per-unit spans the scheduler emits (``unit:<name>`` in
 :meth:`veles_tpu.units.Unit._run_wrapped`) follow the same schema.
+
+:func:`annotation` is the other clock: a host span in the PROFILER's
+trace (``jax.profiler``), beside the device's operations, for the
+boundaries a device trace has to be read against (the serving loop's
+``veles.sched.*`` phases, the trainer's ``veles.gd.*``).
 """
 
 import itertools
@@ -23,6 +28,16 @@ import time
 from veles_tpu.logger import events as default_sink
 
 _span_ids = itertools.count(1)
+
+
+def annotation(name):
+    """A ``jax.profiler.TraceAnnotation`` named ``name``: a context
+    manager that puts a host span on the profiler's clock while a
+    profiler session is active and does nothing otherwise (well under
+    a microsecond an enter/exit pair), so no switch guards it.  Names
+    of the program's own spans start ``veles.``."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name)
 
 
 def next_span_id():
