@@ -1462,13 +1462,7 @@ def bench_tp(dev):
             "tp=%d fell back (devices? divisibility?) — the bench " \
             "numbers would silently measure the unsharded path" % tp
         try:
-            if sch.tp_ is not None:
-                params = sch.tp_.device_params(fw)
-            else:
-                params = {i: {n: a.devmem
-                              for n, a in u.param_arrays().items()}
-                          for i, u in enumerate(fw)}
-            return per_chip_bytes({"params": params,
+            return per_chip_bytes({"params": sch.weights_.params,
                                    "pools": sch.cache_.pools}), \
                 fw, sch
         except BaseException:

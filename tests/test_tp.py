@@ -231,11 +231,7 @@ def test_tp_serves_wider_model_at_fixed_chip_budget(f32):
                                  **kw).start()
         try:
             assert sch.tp == tp
-            params = sch.tp_.device_params(fw) if sch.tp_ \
-                else {i: {n: a.devmem
-                          for n, a in u.param_arrays().items()}
-                      for i, u in enumerate(fw)}
-            total = per_chip_bytes({"params": params,
+            total = per_chip_bytes({"params": sch.weights_.params,
                                     "pools": sch.cache_.pools})
             out = sch.submit([3, 1, 4, 1], 6, seed=0).result(240)
             sch.check_kv()

@@ -164,3 +164,114 @@ def test_attention_kernel_partitions_over_a_dp_mesh(topo,
     compiled = jax.jit(jax.grad(loss)).lower(params, x).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-reduce" in text
+
+
+# -- lowered, not compiled: the serving steps at the benchmark's widths -------
+
+def _opt67_chain():
+    """The serve cell's chain (benchmark/configs/opt-6.7b-8l.json) with
+    no weights made: every parameter a lazily-zero host array that
+    nothing touches, so the units can say which leaves they declare."""
+    import json
+    import os
+
+    import numpy
+
+    from veles_tpu.accelerated_units import AcceleratedWorkflow
+    from veles_tpu.memory import Array
+    from veles_tpu.models.standard import make_forwards
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs",
+                           "opt-6.7b-8l.json")) as f:
+        s = json.load(f)["shapes"]
+    d, h, v = s["dim"], s["ffn"], s["vocab"]
+    spec = [{"type": "embedding", "vocab": v, "dim": d}]
+    spec += [{"type": "transformer_block", "heads": s["heads"],
+              "hidden": h, "causal": True}] * s["layers"]
+    spec += [{"type": "token_logits", "vocab": v}]
+    fw = make_forwards(
+        AcceleratedWorkflow(None, name="opt67-lowering"),
+        Array(numpy.zeros((1, s["positions"]), numpy.int32)), spec)
+    block = {"ln1_scale": (d,), "ln1_bias": (d,), "wq": (d, d),
+             "wk": (d, d), "wv": (d, d), "wo": (d, d),
+             "ln2_scale": (d,), "ln2_bias": (d,), "ffn_w1": (d, h),
+             "ffn_b1": (h,), "ffn_w2": (h, d), "ffn_b2": (d,)}
+    layout = [{"weights": (v, d), "positions": (s["positions"], d)}] \
+        + [block] * s["layers"] + [{"weights": (d, v), "bias": (v,)}]
+    for unit, leaves in zip(fw, layout):
+        for name, shape in leaves.items():
+            getattr(unit, name).reset(numpy.zeros(shape, numpy.float32))
+    return fw, s
+
+
+def _abstract_params(fw, cast):
+    return {i: {name: jax.ShapeDtypeStruct(
+                    arr.shape, jnp.bfloat16
+                    if cast and name in u.compute_dtype_params()
+                    else jnp.float32)
+                for name, arr in u.param_arrays().items()}
+            for i, u in enumerate(fw)}
+
+
+def _lower_paged_step(fw, s, params):
+    from veles_tpu.serving.engine import _make_paged_step
+    b, t, block, blocks = 8, 64, 16, 8 * 128 + 1
+    pool = jax.ShapeDtypeStruct((blocks, block, s["dim"]), jnp.bfloat16)
+    pools = {i: {"k": pool, "v": pool} for i, u in enumerate(fw)
+             if hasattr(u, "init_cache")}
+
+    def vec(dtype, *shape):
+        return jax.ShapeDtypeStruct((b,) + shape, dtype)
+    return b, jax.jit(_make_paged_step(fw)).lower(
+        params, vec(jnp.int32, 1), vec(jnp.int32), vec(jnp.int32, t),
+        vec(jnp.float32), vec(jnp.int32), vec(jnp.uint32),
+        vec(jnp.int32), pools)
+
+
+def _lower_prefill_chunk(fw, s, params):
+    from veles_tpu.serving.prefill import _make_chunk_fn
+    c, width = 64, 1024
+    stage = jax.ShapeDtypeStruct((1, width, s["dim"]), jnp.bfloat16)
+    caches = {i: {"k": stage, "v": stage} for i, u in enumerate(fw)
+              if hasattr(u, "init_cache")}
+    return c, jax.jit(_make_chunk_fn(fw, width)).lower(
+        params, jax.ShapeDtypeStruct((1, c), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32),
+        jax.ShapeDtypeStruct((1,), jnp.int32), caches)
+
+
+def _weight_casts(text, rows, dim):
+    """The converts of the lowered module that take a float32 function
+    argument larger than [rows, dim] to bfloat16: a weight cast inside
+    the step."""
+    import math
+    import re
+    found = []
+    for operand, shape in re.findall(
+            r"stablehlo\.convert (%\w+) : \(tensor<([0-9x]+)xf32>\)"
+            r" -> tensor<[0-9x]+xbf16>", text):
+        size = math.prod(int(n) for n in shape.split("x"))
+        if operand.startswith("%arg") and size > rows * dim:
+            found.append(shape)
+    return found
+
+
+@pytest.mark.parametrize("lower", [_lower_paged_step,
+                                   _lower_prefill_chunk])
+def test_serving_step_casts_no_weight(lower):
+    """With the leaves the units declare already in the compute dtype
+    (what ``serving/weights.ServingWeights`` hands a running server)
+    the lowered decode step and prefill chunk hold no float32 → bf16
+    convert of a parameter; from float32 leaves they hold one for
+    every matmul weight and table, so this would catch the mechanism
+    falling silent."""
+    from veles_tpu import dtypes
+    assert dtypes.compute_dtype() == jnp.bfloat16
+    fw, s = _opt67_chain()
+    declared = sum(len(u.compute_dtype_params()) for u in fw)
+    assert declared == 2 + 6 * s["layers"] + 1
+    rows, lowered = lower(fw, s, _abstract_params(fw, True))
+    assert _weight_casts(lowered.as_text(), rows, s["dim"]) == []
+    rows, lowered = lower(fw, s, _abstract_params(fw, False))
+    assert len(_weight_casts(lowered.as_text(), rows, s["dim"])) \
+        == declared

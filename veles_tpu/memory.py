@@ -218,6 +218,19 @@ class Array(Pickleable):
             Watcher.free(self._watch_key(), self._devmem_.nbytes)
             self._devmem_ = None
 
+    def release_devmem(self):
+        """Give the device buffer back WITHOUT losing the value: the
+        host mirror is made current first (one device→host read where
+        the device held the only current copy, free otherwise), and
+        the next :attr:`devmem` read uploads it again.  For a holder
+        of a frozen copy in another dtype or placement (the serving
+        weights, serving/weights.py) that must not keep both."""
+        self.map_read()
+        if self._mem is None:   # no host mirror to come back from
+            return
+        self._release_devmem()
+        self._state = HOST_DIRTY
+
     def _upload(self):
         if self._mem is None:
             return

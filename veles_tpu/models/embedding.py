@@ -24,6 +24,7 @@ class Embedding(ForwardBase):
     SEQ_DIM1_INPUT = True
 
     PARAMS = ("weights", "positions")
+    MATMUL_PARAMS = PARAMS
 
     def __init__(self, workflow, vocab=None, dim=None,
                  learned_positions=True, **kwargs):

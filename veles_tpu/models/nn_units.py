@@ -98,6 +98,27 @@ class ForwardBase(AcceleratedUnit):
         return {name: getattr(self, name) for name in self.PARAMS
                 if bool(getattr(self, name))}
 
+    #: parameters whose EVERY use in this unit's traced code is
+    #: ``params[name].astype(dtypes.compute_dtype())``
+    MATMUL_PARAMS = ()
+
+    def compute_dtype_params(self):
+        """The parameters a FROZEN copy may hold in the compute dtype
+        already (serving/weights.py): of ``MATMUL_PARAMS``, those
+        stored as floats of another dtype.  ``.astype`` of an array
+        that has the dtype is no operation, so the traced code is the
+        same and its operands are bit-identical: the rounding is done
+        once instead of on every call.  An int8 checkpoint weight
+        keeps its int8, and under float32 compute there is nothing to
+        name."""
+        from veles_tpu import dtypes
+        cd = numpy.dtype(dtypes.compute_dtype())
+        arrays = self.param_arrays()
+        return tuple(
+            name for name in self.MATMUL_PARAMS
+            if name in arrays and arrays[name].dtype != cd
+            and numpy.issubdtype(arrays[name].dtype, numpy.floating))
+
     def _export_activation(self):
         """Activation name for export_config — callables can't ride a
         JSON manifest."""

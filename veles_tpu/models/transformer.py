@@ -56,6 +56,7 @@ class TransformerBlock(ForwardBase):
 
     BASE_PARAMS = ("ln1_scale", "ln1_bias", "wq", "wk", "wv", "wo",
                    "ln2_scale", "ln2_bias")
+    MATMUL_PARAMS = ("wq", "wk", "wv", "wo", "ffn_w1", "ffn_w2")
 
     def __init__(self, workflow, heads=4, hidden=None, causal=True,
                  n_experts=0, top_k=2, attn_block_size=None,
@@ -140,6 +141,14 @@ class TransformerBlock(ForwardBase):
             self._fill(self.ffn_w2.mem, self.weights_filling,
                        self.weights_stddev, h, d)
             self.ffn_b2.reset(numpy.zeros((d,), numpy.float32))
+
+    def compute_dtype_params(self):
+        """None under ``int8_decode`` (the decode step quantizes from
+        the float32 weight inside the trace) and none for an MoE
+        block (its expert weights shard over ``ep``)."""
+        if self.int8_decode or self.n_experts:
+            return ()
+        return super(TransformerBlock, self).compute_dtype_params()
 
     # -- tensor-parallel serving layout (serving/tp.py) -----------------
 
@@ -712,6 +721,7 @@ class TokenProjection(ForwardBase):
     standard convention (parallel/sharding.py)."""
 
     PARAMS = ("weights", "bias")
+    MATMUL_PARAMS = ("weights",)
     SEQ_DIM1_INPUT = True
     #: position-wise: safe to apply to a [batch, 1, d] decode step
     #: unchanged (models/generate.py kv_cache chain dispatch)

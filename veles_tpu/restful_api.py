@@ -284,14 +284,6 @@ class RESTfulAPI(Unit):
 
     def initialize(self, **kwargs):
         super(RESTfulAPI, self).initialize(**kwargs)
-        if self.forwards is not None:
-            # warm the device params NOW, single-threaded: Array.devmem
-            # lazily uploads on first touch and is not thread-safe
-            # against the concurrent HTTP handler threads /generate
-            # runs on (the upload nulls the buffer before replacing it)
-            for u in self.forwards:
-                for arr in u.param_arrays().values():
-                    arr.devmem
         if self.forwards is not None and self.serving \
                 and self.scheduler_ is None:
             from veles_tpu.serving import (
@@ -328,6 +320,16 @@ class RESTfulAPI(Unit):
             else:
                 self.info("chain not slot-servable; /generate stays "
                           "on the serialized decode path")
+        if self.forwards is not None and self.scheduler_ is None:
+            # no scheduler (its start() builds the serving weights
+            # leaf by leaf): warm the device params NOW,
+            # single-threaded — Array.devmem lazily uploads on first
+            # touch and is not thread-safe against the concurrent HTTP
+            # handler threads /generate runs on (the upload nulls the
+            # buffer before replacing it)
+            for u in self.forwards:
+                for arr in u.param_arrays().values():
+                    arr.devmem
         if self._server_ is not None:
             return
         api = self

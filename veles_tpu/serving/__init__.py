@@ -106,6 +106,7 @@ from veles_tpu.serving.scheduler import (  # noqa: F401
     resolve_priority)
 from veles_tpu.serving.tp import (  # noqa: F401
     ServingTP, per_chip_bytes, tp_allreduce, tp_supported)
+from veles_tpu.serving.weights import ServingWeights  # noqa: F401
 from veles_tpu.serving.disagg import (  # noqa: F401
     decode_export, encode_export)
 from veles_tpu.serving.streams import (  # noqa: F401

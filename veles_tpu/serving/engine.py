@@ -112,7 +112,7 @@ def clear_step_cache():
 
 
 def slot_decode_step(forwards, cache, toks, pos, temps, topks, seeds,
-                     counts):
+                     counts, params=None):
     """Run ONE decode step over every slot of ``cache``
     (:class:`serving.kv_slots.SlotKVCache`, updated in place).
 
@@ -120,9 +120,14 @@ def slot_decode_step(forwards, cache, toks, pos, temps, topks, seeds,
     sequence index (length - 1); ``temps``/``topks`` [S] — per-slot
     sampler settings; ``seeds``/``counts`` [S] — per-request PRNG
     stream (seed and draw counter for THIS step's token).  Returns the
-    [S] next tokens (device array — callers ``numpy.asarray`` it)."""
+    [S] next tokens (device array — callers ``numpy.asarray`` it).
+
+    ``params`` — the chain's device parameters; a server passes its
+    frozen :class:`serving.weights.ServingWeights` pytree, an offline
+    caller none (the units' own float32 buffers)."""
     from veles_tpu import dtypes
-    params = _device_params(forwards)
+    if params is None:
+        params = _device_params(forwards)
     cache_key = (_arch_sig(forwards), cache.max_slots, cache.window,
                  str(dtypes.compute_dtype()),
                  str(dtypes.matmul_precision()))
@@ -274,7 +279,8 @@ def _paged_step_tp_cached(cache_key, closure):
 
 
 def paged_decode_step(forwards, cache, toks, pos, tables, temps,
-                      topks, seeds, counts, want_hidden=False):
+                      topks, seeds, counts, want_hidden=False,
+                      params=None):
     """Run ONE decode step over a PACKED batch of active slots
     against ``cache`` (:class:`serving.kv_slots.PagedKVCache`,
     updated in place).
@@ -291,9 +297,12 @@ def paged_decode_step(forwards, cache, toks, pos, tables, temps,
     (serving/draft.py); the flag keys the executable cache, so
     hidden-on and hidden-off never share a trace.
 
+    ``params`` as in :func:`slot_decode_step`.
+
     A cache built with a tensor-parallel context (``cache.tp_`` —
-    serving/tp.py) runs the step SPMD over the tp mesh: params ride
-    pre-sharded Megatron-style, the pools head-wise, and the
+    serving/tp.py) runs the step SPMD over the tp mesh: ``params`` ride
+    pre-sharded Megatron-style (the server's ``ServingWeights`` placed
+    them; required then), the pools head-wise, and the
     executable cache keys on the mesh size so tp on/off never share
     a trace.  With ``root.common.serving.tp_overlap`` set (and every
     cacheable block speaking the shard_map step — see
@@ -306,8 +315,8 @@ def paged_decode_step(forwards, cache, toks, pos, tables, temps,
     from veles_tpu import dtypes
     from veles_tpu.config import root
     ctx = getattr(cache, "tp_", None)
-    params = ctx.device_params(forwards) if ctx is not None \
-        else _device_params(forwards)
+    if params is None:
+        params = _device_params(forwards)
     tables = jnp.asarray(tables, jnp.int32)
     b, t = tables.shape
     # fp32 pools only: the int8 pool's per-row amax must reduce over
@@ -402,7 +411,7 @@ def _verify_step_cached(cache_key, closure, donate=False):
 
 def verify_step_paged(forwards, cache, toks, pos, lens, tables,
                       temps, topks, seeds, counts,
-                      want_hidden=False):
+                      want_hidden=False, params=None):
     """Score a PACKED batch of speculative token runs in ONE model
     pass against ``cache`` (:class:`serving.kv_slots.PagedKVCache`,
     updated in place) — the batched verify step of speculative
@@ -412,9 +421,10 @@ def verify_step_paged(forwards, cache, toks, pos, lens, tables,
     tokens (padded past ``lens[n]``); ``pos`` [B] — the sequence
     index of each row's pending token; ``lens`` [B] — real positions
     per row (1 = no drafts, i.e. a plain decode step riding the
-    verify batch); ``tables``/``temps``/``topks``/``seeds`` as in
-    :func:`paged_decode_step`; ``counts`` [B] — the draw counter of
-    the FIRST sampled token (position j draws ``counts + j``).
+    verify batch); ``tables``/``temps``/``topks``/``seeds``/
+    ``params`` as in :func:`paged_decode_step`; ``counts`` [B] — the
+    draw counter of the FIRST sampled token (position j draws
+    ``counts + j``).
 
     Returns [B, K1] next tokens: entry (n, j) is the token a
     sequential decode would emit after row n's context extended by
@@ -429,8 +439,8 @@ def verify_step_paged(forwards, cache, toks, pos, lens, tables,
     from veles_tpu import dtypes
     from veles_tpu.config import root
     ctx = getattr(cache, "tp_", None)
-    params = ctx.device_params(forwards) if ctx is not None \
-        else _device_params(forwards)
+    if params is None:
+        params = _device_params(forwards)
     tables = jnp.asarray(tables, jnp.int32)
     toks = jnp.asarray(toks, jnp.int32)
     b, t = tables.shape

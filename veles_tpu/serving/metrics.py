@@ -316,6 +316,18 @@ def _registry_series():
             "divide by the mesh factor) — the streams-per-HBM-"
             "dollar denominator, labeled per replica",
             labelnames=("replica",)),
+        "weight_bytes": metrics.gauge(
+            "veles_serving_weight_bytes",
+            "bytes of the frozen serving weights resident on the "
+            "devices, by stored dtype (serving/weights.py: matmul "
+            "weights held in the compute dtype, cast once at start); "
+            "0 once the server has closed",
+            labelnames=("dtype", "replica")),
+        "weight_leaves_cast": metrics.counter(
+            "veles_serving_weight_leaves_cast_total",
+            "parameter leaves a server holds in the compute dtype "
+            "instead of the unit's, counted when it starts (0: no "
+            "unit named any, or compute is float32)"),
         "prefill_chunks": metrics.counter(
             "veles_serving_prefill_chunk_total",
             "prompt chunks prefilled (chunked-prefill path)"),
@@ -1206,6 +1218,14 @@ class ServingMetrics:
                 1 if d == kv_dtype else 0)
         self._global["kv_bytes_per_token"].labels(
             replica=self.replica).set(int(bytes_per_token))
+
+    def set_weights(self, bytes_by_dtype, leaves_cast):
+        """Advertise the serving weights (at start; the same dtypes at
+        0 bytes, and 0 leaves, at close)."""
+        for d, nbytes in bytes_by_dtype.items():
+            self._global["weight_bytes"].labels(
+                dtype=d, replica=self.replica).set(int(nbytes))
+        self._global["weight_leaves_cast"].inc(int(leaves_cast))
 
     def record_loop_pass(self, seconds, steps, steps_after_prefill,
                          step_after_prefill_seconds, passes=1):

@@ -118,16 +118,18 @@ def clear_embed_cache():
     _embed_cached.cache_clear()
 
 
-def embed_pool(forwards, prompt, prompt_lens):
+def embed_pool(forwards, prompt, prompt_lens, params=None):
     """Pooled embeddings for ``prompt`` [b, P] int32 (front-aligned
     rows, ``prompt_lens`` [b] real lengths): ONE jitted pass through
     the chain's hidden layers (head skipped), masked mean-pool,
     L2-normalized [b, d] f32.  Callers bucket b and P — each (b, P)
-    pair is one compiled executable."""
+    pair is one compiled executable.  ``params``: the server's frozen
+    ``ServingWeights`` pytree (default: the units' own buffers)."""
     if not embed_supported(forwards):
         raise ValueError("chain cannot serve embeddings (needs a "
                          "prefill-capable chain with a head unit)")
-    params = _device_params(forwards)
+    if params is None:
+        params = _device_params(forwards)
     prompt = jnp.asarray(prompt, jnp.int32)
     b, p = prompt.shape
     _check_positions(forwards, p)
@@ -159,22 +161,24 @@ def _pad_rows(rows, width_cap):
     return padded, lens_arr
 
 
-def pooled_embeddings(forwards, rows, window):
+def pooled_embeddings(forwards, rows, window, params=None):
     """Batched ``/v1/embeddings`` execution: bucket + pad the rows,
     one :func:`embed_pool` pass, unpadded [n, d] float lists back."""
     padded, lens = _pad_rows(rows, window)
-    out = numpy.asarray(embed_pool(forwards, padded, lens))
+    out = numpy.asarray(embed_pool(forwards, padded, lens,
+                                   params=params))
     return [out[i].tolist() for i in range(len(rows))]
 
 
-def score_rows(forwards, rows, window):
+def score_rows(forwards, rows, window, tp=None, params=None):
     """Batched ``/v1/classify`` execution: the last-position logits
     of each row through the FULL chain (the prefill TTFT edge),
     log-softmaxed to per-class log-probabilities [n, classes]."""
     from veles_tpu.serving.prefill import prefill
     padded, lens = _pad_rows(rows, window)
     _, last = prefill(forwards, padded,
-                      prompt_lens=lens, window=padded.shape[1])
+                      prompt_lens=lens, window=padded.shape[1],
+                      tp=tp, params=params)
     logits = numpy.asarray(last, numpy.float64)[:len(rows)]
     z = logits - logits.max(axis=-1, keepdims=True)
     logp = z - numpy.log(numpy.exp(z).sum(axis=-1, keepdims=True))

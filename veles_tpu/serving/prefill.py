@@ -117,7 +117,7 @@ def clear_chunk_cache():
 
 
 def prefill_chunk(forwards, chunk, offset, chunk_lens, caches,
-                  key_width=None, tp=None):
+                  key_width=None, tp=None, params=None):
     """Prefill ONE chunk — ``chunk`` [batch, C] int32 tokens at
     sequence positions [offset, offset+C) — into existing staging
     ``caches`` (``{chain index: {"k", "v"} [batch, W, d]}``; W a
@@ -137,17 +137,22 @@ def prefill_chunk(forwards, chunk, offset, chunk_lens, caches,
     final chunk lands.  Running the chunks in order reproduces the
     one-shot :func:`prefill` cache rows and logits (tested).
 
+    ``params`` — the chain's device parameters: a server passes its
+    frozen :class:`serving.weights.ServingWeights` pytree, an offline
+    caller none (the units' own float32 buffers).
+
     ``tp`` (a :class:`serving.tp.ServingTP`, default None) runs the
-    chunk SPMD over the tensor-parallel mesh with Megatron-sharded
-    params — the staging caches ride uncommitted and land wherever
+    chunk SPMD over the tensor-parallel mesh with the Megatron-sharded
+    ``params`` the server placed there — the staging caches ride
+    uncommitted and land wherever
     GSPMD places them; the later block insert re-places them against
     the head-sharded pools."""
     from veles_tpu import dtypes
     if not chunked_supported(forwards):
         raise ValueError("chain cannot prefill in chunks (see "
                          "chunked_supported)")
-    params = tp.device_params(forwards) if tp is not None \
-        else _device_params(forwards)
+    if params is None:
+        params = _device_params(forwards)
     chunk = jnp.asarray(chunk, jnp.int32)
     b, c = chunk.shape
     widths = {tuple(a.shape[1] for a in layer.values())
@@ -216,7 +221,7 @@ def clear_prefill_cache():
 
 
 def prefill(forwards, prompt, prompt_lens=None, window=None,
-            tp=None):
+            tp=None, params=None):
     """Prefill ``prompt`` [batch, P] (int32, front-aligned rows) in
     ONE compiled pass.
 
@@ -231,7 +236,8 @@ def prefill(forwards, prompt, prompt_lens=None, window=None,
     traced argument.  ``window`` (default P) sizes the returned cache
     buffers — a request decoding into a slot cache prefills straight
     at the slot width.  ``tp`` (serving/tp.py context) runs the pass
-    SPMD over the tensor-parallel mesh."""
+    SPMD over the tensor-parallel mesh; ``params`` as in
+    :func:`prefill_chunk`."""
     from veles_tpu import dtypes
     for u in forwards:
         if hasattr(u, "init_cache") \
@@ -239,8 +245,8 @@ def prefill(forwards, prompt, prompt_lens=None, window=None,
             raise ValueError(
                 "batched prefill: %s has no apply_prefill"
                 % type(u).__name__)
-    params = tp.device_params(forwards) if tp is not None \
-        else _device_params(forwards)
+    if params is None:
+        params = _device_params(forwards)
     prompt = jnp.asarray(prompt, jnp.int32)
     b, p = prompt.shape
     window = int(window or p)

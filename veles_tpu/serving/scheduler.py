@@ -215,6 +215,7 @@ from veles_tpu.serving.draft import draft_supported
 from veles_tpu.serving.spec import (
     NgramIndex, NgramProposer, accept_drafts)
 from veles_tpu.serving.streams import TokenStream
+from veles_tpu.serving.weights import ServingWeights
 
 #: priority classes, lowest to highest; ints in [0, 2] also accepted
 PRIORITIES = {"low": 0, "normal": 1, "high": 2}
@@ -774,6 +775,10 @@ class InferenceScheduler(Logger):
         self._watchdog_thread = None
         self._ready = threading.Event()
         self.cache_ = None           # set by the loop thread
+        #: the frozen device parameters every jitted entry point of
+        #: this server takes (serving/weights.py): built by start(),
+        #: dropped by close()
+        self.weights_ = None
         self._phases = None          # _LoopPhases, the loop thread's
         self.prefix_ = None          # radix cache (loop thread too)
         #: host KV tier — constructed HERE (no device dependencies)
@@ -786,11 +791,11 @@ class InferenceScheduler(Logger):
     # -- client side ----------------------------------------------------
 
     def start(self):
-        """Warm the device params (single-threaded — Array.devmem's
-        lazy upload is not re-entrant), start the decode loop and
-        block until it is READY — cache built and the paged-step
-        bucket ladder compiled — so traffic never eats warmup
-        compiles as decode stalls."""
+        """Build the serving weights (single-threaded — Array.devmem's
+        lazy upload is not re-entrant; serving/weights.py), start the
+        decode loop and block until it is READY — cache built and the
+        paged-step bucket ladder compiled — so traffic never eats
+        warmup compiles as decode stalls."""
         with self._lock:  # two racing start()s must not spawn two loops
             if self._thread is not None:
                 started = True
@@ -803,11 +808,17 @@ class InferenceScheduler(Logger):
             self._ready.wait(600)
             return self
         try:
-            for u in self.forwards:
-                for arr in u.param_arrays().values():
-                    arr.devmem
+            t0 = time.monotonic()
+            self.weights_ = ServingWeights(self.forwards, tp=self.tp_)
+            self.stats.set_weights(self.weights_.bytes_by_dtype,
+                                   self.weights_.leaves_cast)
+            self.info("serving weights: %d leaves cast, resident %s, "
+                      "built in %.2fs", self.weights_.leaves_cast,
+                      self.weights_.bytes_by_dtype,
+                      time.monotonic() - t0)
             self._thread.start()
         except BaseException:
+            self._drop_weights()
             with self._lock:  # release the claim so start() can retry
                 self._thread = None
             raise
@@ -1358,10 +1369,13 @@ class InferenceScheduler(Logger):
         try:
             faults.fire("serving.scheduler.aux")
             if kind == "embed":
-                out = pooled_embeddings(self.forwards, rows,
-                                        self.window)
+                out = pooled_embeddings(
+                    self.forwards, rows, self.window,
+                    params=self.weights_.params)
             else:
-                out = score_rows(self.forwards, rows, self.window)
+                out = score_rows(
+                    self.forwards, rows, self.window, tp=self.tp_,
+                    params=self.weights_.params)
         except Exception as e:
             fut.set_exception(
                 e if isinstance(e, SchedulerError)
@@ -1520,6 +1534,9 @@ class InferenceScheduler(Logger):
                "role": self.role,
                "replica": self.replica_id,
                "kv_exports_pending": len(self._exports)}
+        weights = self.weights_
+        out["weights_dtype"] = \
+            weights.dtype if weights is not None else None
         cache = self.cache_
         if self.kv == "paged":
             out["kv_dtype"] = self.kv_dtype
@@ -1685,11 +1702,20 @@ class InferenceScheduler(Logger):
             req.fail(err)
         if cache is not None:
             self._sync_kv_gauges(cache)
+        if loop_dead:   # a live loop still steps on them
+            self._drop_weights()
         self._drained.set()
         with self._lock:  # claim the watchdog before joining it
             wd, self._watchdog_thread = self._watchdog_thread, None
         if wd is not None:
             wd.join(5)
+
+    def _drop_weights(self):
+        weights, self.weights_ = self.weights_, None
+        if weights is not None:
+            weights.close()
+            self.stats.set_weights(
+                dict.fromkeys(weights.bytes_by_dtype, 0), 0)
 
     # -- decode loop ----------------------------------------------------
 
@@ -1740,7 +1766,8 @@ class InferenceScheduler(Logger):
                     numpy.zeros((b,), numpy.int32),
                     numpy.zeros((b,), numpy.uint32),
                     numpy.zeros((b,), numpy.int32),
-                    want_hidden=want_h)
+                    want_hidden=want_h,
+                    params=self.weights_.params)
                 for kk in ks:
                     # the verify ladder rides the same dummy trash-
                     # block convention, one executable per (B, T, k)
@@ -1754,7 +1781,8 @@ class InferenceScheduler(Logger):
                         numpy.zeros((b,), numpy.int32),
                         numpy.zeros((b,), numpy.uint32),
                         numpy.zeros((b,), numpy.int32),
-                        want_hidden=want_h)
+                        want_hidden=want_h,
+                        params=self.weights_.params)
         self.info("paged-step warmup: %d occupancy x %d depth x "
                   "%d spec buckets in %.2fs", len(buckets),
                   len(depths), len(ks) + 1, time.monotonic() - t0)
@@ -1775,6 +1803,7 @@ class InferenceScheduler(Logger):
                 self._closed = True
                 pending = list(self._queue)
                 self._queue.clear()
+            self._drop_weights()   # close() returns early from here on
             self._ready.set()
             for req in pending:
                 req.future.set_exception(SchedulerError(repr(e)))
@@ -2322,7 +2351,8 @@ class InferenceScheduler(Logger):
             faults.fire("serving.scheduler.prefill")
             row_caches, last = prefill(
                 self.forwards, padded, prompt_lens=[p_len],
-                window=width, tp=self.tp_)
+                window=width, tp=self.tp_,
+                params=self.weights_.params)
         except Exception as e:
             self._retire(req, cache, error=e)
             return
@@ -2354,7 +2384,8 @@ class InferenceScheduler(Logger):
             faults.fire("serving.scheduler.prefill")
             req.pf_caches, last = prefill_chunk(
                 self.forwards, padded, off, [clen], req.pf_caches,
-                key_width=kw, tp=self.tp_)
+                key_width=kw, tp=self.tp_,
+                params=self.weights_.params)
         except Exception as e:
             with self._lock:
                 if req in self._prefilling:
@@ -2698,7 +2729,8 @@ class InferenceScheduler(Logger):
         with self._phases("step") as launch:
             got = paged_decode_step(
                 self.forwards, cache, toks, pos, tables, temps, topks,
-                seeds, counts, want_hidden=want_h)
+                seeds, counts, want_hidden=want_h,
+                params=self.weights_.params)
             if want_h:
                 nxt, hid = got
                 hid = numpy.asarray(hid)
@@ -2786,7 +2818,8 @@ class InferenceScheduler(Logger):
         with self._phases("step") as launch:
             got = verify_step_paged(
                 self.forwards, cache, toks, pos, lens, tables, temps,
-                topks, seeds, counts, want_hidden=want_h)
+                topks, seeds, counts, want_hidden=want_h,
+                params=self.weights_.params)
             if want_h:
                 nxt, hid = got
                 hid = numpy.asarray(hid)
@@ -2849,7 +2882,7 @@ class InferenceScheduler(Logger):
         with self._phases("step") as launch:
             nxt = numpy.asarray(slot_decode_step(
                 self.forwards, cache, toks, pos, temps, topks, seeds,
-                counts))
+                counts, params=self.weights_.params))
         dt = launch.seconds
         with self._phases("observe"):
             self.stats.record_step(len(active), s, tokens=len(active))

@@ -76,10 +76,10 @@ class ServingTP:
 
     ``size`` chips off the front of ``devices`` (default
     ``jax.devices()``) form a ``{"tp": size}`` mesh
-    (``parallel/mesh.py`` axis conventions).  ``device_params``
-    shards the chain's frozen weights by each unit's declared spec
-    ONCE and caches the placement (serving weights never change, so
-    repeated decode steps must not re-ship them);
+    (``parallel/mesh.py`` axis conventions).  The server's
+    :class:`~veles_tpu.serving.weights.ServingWeights` places the
+    chain's frozen weights on it ONCE, each leaf by its unit's
+    declared ``tp_param_spec`` (replicated where it declares none);
     ``shard_pools`` places a paged layer's K/V pools head-wise and
     its scale arrays replicated."""
 
@@ -94,39 +94,9 @@ class ServingTP:
                 "tp=%d needs %d devices, found %d"
                 % (self.size, self.size, len(devs)))
         self.mesh = build_mesh({"tp": self.size}, devs[:self.size])
-        self._params = None
-        self._params_for = None
 
     def sharding(self, spec):
         return NamedSharding(self.mesh, spec)
-
-    def device_params(self, forwards):
-        """The chain's parameters placed on the mesh: sharded where
-        the unit declares a ``tp_param_spec``, replicated elsewhere.
-        Computed once per chain (the ctx belongs to one scheduler,
-        whose weights are frozen) — the sharded counterpart of
-        ``models/generate._device_params``."""
-        key = id(forwards)
-        if self._params is not None and self._params_for == key:
-            return self._params
-        out = {}
-        for i, u in enumerate(forwards):
-            spec_fn = getattr(u, "tp_param_spec", None)
-            layer = {}
-            for name, arr in u.param_arrays().items():
-                spec = spec_fn(name, self.size) \
-                    if spec_fn is not None else None
-                # reshard the CURRENT device value (devmem) — the
-                # host .mem buffer can be stale after training until
-                # a map_read, and serving must see what the solver
-                # actually wrote
-                layer[name] = jax.device_put(
-                    arr.devmem,
-                    self.sharding(spec if spec is not None else P()))
-            out[i] = layer
-        self._params = out
-        self._params_for = key
-        return out
 
     def shard_pools(self, pools):
         """Place one cache's per-layer pool dicts on the mesh: K/V
