@@ -68,9 +68,21 @@ class StreamLoader(Loader):
             self._lab[idx]
 
 
+#: threads alive before the test: another test file of this xdist
+#: worker may have driven ``loader.run()`` by hand and never stopped its
+#: loader, and its pipeline is not this file's to judge
+_OTHERS = set()
+
+
+@pytest.fixture(autouse=True)
+def _threads_of_other_tests():
+    _OTHERS.clear()
+    _OTHERS.update(threading.enumerate())
+
+
 def _prefetch_threads():
     return [t.name for t in threading.enumerate()
-            if t.name.startswith("prefetch-")]
+            if t.name.startswith("prefetch-") and t not in _OTHERS]
 
 
 def _reseed():

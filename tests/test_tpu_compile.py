@@ -100,6 +100,22 @@ def _flash():
             [((4, 2048, 16, 128), jnp.bfloat16)] * 3)
 
 
+def _routed_ffn():
+    """One routed FFN at LFM2-24B-A2B's widths, a decode step's 8 rows:
+    the three grouped products (``jax.lax.ragged_dot``) compile to the
+    chip's own grouped-matmul calls."""
+    from veles_tpu.models.lfm2 import routed_ffn
+    e, d, h = 64, 2048, 1536
+
+    def fn(u, router, bias, w1, w3, w2):
+        return routed_ffn({"router": router, "expert_bias": bias,
+                           "expert_w1": w1, "expert_w3": w3,
+                           "expert_w2": w2}, u, 4, True, 1.0)
+    return fn, [((8, d), jnp.float32), ((d, e), jnp.float32),
+                ((e,), jnp.float32), ((e, d, h), jnp.bfloat16),
+                ((e, d, h), jnp.bfloat16), ((e, h, d), jnp.bfloat16)]
+
+
 CASES = {
     "attention_fwd_4x2048x16x128":
         functools.partial(_attention, False, (4, 2048, 16, 128)),
@@ -115,6 +131,7 @@ CASES = {
     "paged_int8_k5": functools.partial(_paged, True, 5),
     "int8_matmul_1x1024x32768": _int8_matmul,
     "flash_4x2048x16x128": _flash,
+    "lfm2_routed_ffn_8x2048_64x1536": _routed_ffn,
 }
 
 
@@ -225,7 +242,7 @@ def _lower_paged_step(fw, s, params):
     return b, jax.jit(_make_paged_step(fw)).lower(
         params, vec(jnp.int32, 1), vec(jnp.int32), vec(jnp.int32, t),
         vec(jnp.float32), vec(jnp.int32), vec(jnp.uint32),
-        vec(jnp.int32), pools)
+        vec(jnp.int32), vec(jnp.int32), pools)
 
 
 def _lower_prefill_chunk(fw, s, params):

@@ -57,7 +57,7 @@ def _device_params(forwards):
 def _check_positions(forwards, total):
     for u in forwards:
         pos_table = getattr(u, "positions", None)
-        if pos_table is not None and hasattr(pos_table, "shape") \
+        if getattr(pos_table, "shape", None) is not None \
                 and len(pos_table.shape) == 2 \
                 and total > pos_table.shape[0]:
             raise ValueError(
@@ -75,7 +75,7 @@ def _arch_sig(forwards):
     return tuple(
         (type(u).__name__,
          repr(sorted(u.export_config().items(), key=str)),
-         tuple(sorted((n, tuple(a.mem.shape))
+         tuple(sorted((n, tuple(a.shape))
                       for n, a in u.param_arrays().items())))
         for u in forwards)
 
@@ -121,12 +121,12 @@ def _make_prefill(forwards):
 
 def kv_cache_eligible(forwards):
     """True when :func:`generate` can decode this chain with
-    ``kv_cache=True``: every cacheable block is causal and every other
-    unit either has a single-token step or is position-wise (the same
-    predicate the kv path validates with)."""
+    ``kv_cache=True``: every cacheable block is causal and has the
+    single-token step, and every other unit either has one or is
+    position-wise (the same predicate the kv path validates with)."""
     for u in forwards:
         if hasattr(u, "init_cache"):
-            if not u.causal:
+            if not u.causal or not hasattr(u, "apply_step"):
                 return False
         elif not hasattr(u, "apply_step") \
                 and not getattr(u, "DECODE_POINTWISE", False):

@@ -19,6 +19,7 @@ overrides (extras item 13) — both znicz conventions.
 from veles_tpu.accelerated_units import AcceleratedWorkflow
 from veles_tpu.models.attention import MultiHeadAttention
 from veles_tpu.models.embedding import Embedding
+from veles_tpu.models.lfm2 import Lfm2Block, NormedTokenLogits
 from veles_tpu.models.moe import MoE
 from veles_tpu.models.transformer import MeanPoolSeq, TransformerBlock, TokenProjection
 from veles_tpu.models.all2all import (
@@ -60,6 +61,8 @@ LAYER_TYPES = {
     "lstm": LSTM,
     "last_timestep": LastTimestep,
     "token_logits": TokenProjection,
+    "lfm2_block": Lfm2Block,
+    "rms_token_logits": NormedTokenLogits,
 }
 
 

@@ -137,8 +137,35 @@ def paged_verify_attention(q, k_new, v_new, pool_k, pool_v, tables,
                               vh).reshape(b, k1, d)
 
 
+def grouped_attend(q, keys, values, qpos, kv_heads):
+    """Causal attention of ``q`` [b, s, heads, hd] at positions ``qpos``
+    [b, s] over ``keys``/``values`` [b, L, kv_heads * hd], key row i at
+    position i: KV head j serves query heads j*g ... j*g + g - 1
+    (``g = heads // kv_heads``).  Scores and softmax in float32, the
+    two products on compute-dtype operands.  Rows past a query's
+    position are masked, so what they hold (zeros of a staging row,
+    the trash block's garbage) never counts.  -> [b, s, heads * hd]
+    float32."""
+    from veles_tpu import dtypes
+    cd = dtypes.compute_dtype()
+    b, s, heads, hd = q.shape
+    length = keys.shape[1]
+    qg = q.astype(cd).reshape(b, s, kv_heads, heads // kv_heads, hd)
+    kh = keys.astype(cd).reshape(b, length, kv_heads, hd)
+    vh = values.astype(cd).reshape(b, length, kv_heads, hd)
+    scores = jnp.einsum("bqjgd,bkjd->bjgqk", qg, kh,
+                        preferred_element_type=jnp.float32) \
+        * (1.0 / jnp.sqrt(jnp.float32(hd)))
+    mask = jnp.arange(length)[None, None, :] <= qpos[:, :, None]
+    scores = jnp.where(mask[:, None, None], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1).astype(cd)
+    return jnp.einsum("bjgqk,bkjd->bqjgd", probs, vh,
+                      preferred_element_type=jnp.float32).reshape(
+                          b, s, heads * hd)
+
+
 def paged_decode_attention(q, k_new, v_new, pool_k, pool_v, tables,
-                           pos, heads):
+                           pos, heads, kv_heads=None):
     """One decode position per row against a paged KV pool.
 
     ``q``/``k_new``/``v_new`` [B, 1, d] — the new token's projections
@@ -149,7 +176,12 @@ def paged_decode_attention(q, k_new, v_new, pool_k, pool_v, tables,
 
     Returns ``(pool_k', pool_v', context)`` — the pools with the new
     K/V scattered in, and the attention context [B, 1, d] (same dtype
-    conventions as the dense slot step)."""
+    conventions as the dense slot step).
+
+    ``kv_heads`` (default None: the path above): grouped-query attention — the
+    pools hold ``kv_heads`` heads a row (``[blocks, bs, kv_heads·hd]``)
+    under ``heads`` query heads, attended by :func:`grouped_attend`
+    (float32 scores; context float32)."""
     from veles_tpu import dtypes
     cd = dtypes.compute_dtype()
     b, _, d = q.shape
@@ -160,6 +192,11 @@ def paged_decode_attention(q, k_new, v_new, pool_k, pool_v, tables,
     off = pos % bs
     pk = pool_k.at[blk, off].set(k_new[:, 0].astype(pool_k.dtype))
     pv = pool_v.at[blk, off].set(v_new[:, 0].astype(pool_v.dtype))
+    if kv_heads is not None:
+        rows = tables.shape[1] * bs
+        return pk, pv, grouped_attend(
+            q.reshape(b, 1, h, hd), pk[tables].reshape(b, rows, -1),
+            pv[tables].reshape(b, rows, -1), pos[:, None], kv_heads)
     # the scope names the gather + GEMM in each op's metadata (XLA
     # names the fusions themselves, so a trace's event NAMES need not
     # carry it: PERF.md, Open questions)

@@ -13,6 +13,12 @@ RESTfulAPI unit the same way; veles/restful_api.py:78).
          -d '{"prompt": [3, 1, 4], "steps": 32}'  # LM snapshots only
     curl -X POST http://localhost:8080/shutdown   # clean stop
 
+Without a snapshot, ``root.serve.layers`` (a ``layers`` spec as
+``models/standard.make_forwards`` takes it, e.g. an embedding, some
+``lfm2_block`` and a ``rms_token_logits``) builds the chain with the
+units' own seeded filling, and ``root.serve.window`` bounds a request
+where no positional table does (rotary positions).
+
 Graph: repeater → restful_loader → [forwards from the snapshot] → api,
 looping until /shutdown (or the feed closes).
 """
@@ -46,22 +52,29 @@ class ServeWorkflow(AcceleratedWorkflow):
         super(ServeWorkflow, self).__init__(workflow, name="Serve",
                                             **kwargs)
         cfg = root.serve
-        snapshot = cfg.get("snapshot")
-        if not snapshot:
+        snapshot, layers = cfg.get("snapshot"), cfg.get("layers")
+        window = cfg.get("window")
+        if snapshot:
+            from veles_tpu.snapshotter import SnapshotterToFile
+            # a CLI-trained snapshot pickles classes under the workflow
+            # FILE's module name ('lm', 'mnist', …) — that module must
+            # be importable here before unpickling (the reference
+            # resumed through the same re-import,
+            # veles/__main__.py:539-589)
+            wf_file = cfg.get("workflow")
+            if wf_file:
+                from veles_tpu.import_file import import_file_as_module
+                import_file_as_module(wf_file)
+            trained = SnapshotterToFile.import_file(snapshot)
+            self.forwards = trained.forwards  # adopted trained chain
+            sample_shape = tuple(
+                trained.loader.minibatch_data.shape[1:])
+        elif layers:
+            sample_shape = (int(window or 1024),)
+        else:
             raise ValueError(
-                "set root.serve.snapshot to a trained workflow snapshot")
-        from veles_tpu.snapshotter import SnapshotterToFile
-        # a CLI-trained snapshot pickles classes under the workflow
-        # FILE's module name ('lm', 'mnist', …) — that module must be
-        # importable here before unpickling (the reference resumed
-        # through the same re-import, veles/__main__.py:539-589)
-        wf_file = cfg.get("workflow")
-        if wf_file:
-            from veles_tpu.import_file import import_file_as_module
-            import_file_as_module(wf_file)
-        trained = SnapshotterToFile.import_file(snapshot)
-        self.forwards = trained.forwards  # adopted trained chain
-        sample_shape = tuple(trained.loader.minibatch_data.shape[1:])
+                "set root.serve.snapshot to a trained workflow "
+                "snapshot, or root.serve.layers to a layer spec")
 
         self.repeater = Repeater(self)
         self.repeater.link_from(self.start_point)
@@ -70,6 +83,11 @@ class ServeWorkflow(AcceleratedWorkflow):
             minibatch_size=int(cfg.get("minibatch_size", 16)),
             max_wait=float(cfg.get("max_wait", 1.0)))
         self.loader.link_from(self.repeater)
+        if not snapshot:
+            from veles_tpu.models.standard import make_forwards
+            self.forwards = make_forwards(
+                self, self.loader.minibatch_data,
+                [dict(spec) for spec in layers])
 
         prev = self.loader.minibatch_data
         for u in self.forwards:
@@ -82,6 +100,7 @@ class ServeWorkflow(AcceleratedWorkflow):
         for a, b in zip(self.forwards, self.forwards[1:]):
             b.link_from(a)
 
+        from veles_tpu.models.lfm2 import NormedTokenLogits
         from veles_tpu.models.transformer import TokenProjection
         self.api = RESTfulAPI(
             self, loader=self.loader,
@@ -92,10 +111,13 @@ class ServeWorkflow(AcceleratedWorkflow):
             serving=bool(cfg.get("serving", True)),
             max_slots=int(cfg.get("max_slots", 4)),
             max_queue=int(cfg.get("max_queue", 32)),
-            # an LM snapshot (per-token logits head) also serves
+            serving_window=int(window) if window else None,
+            # an LM chain (per-token logits head) also serves
             # POST /generate — autoregressive decode off the same chain
             forwards=self.forwards
-            if isinstance(self.forwards[-1], TokenProjection) else None)
+            if isinstance(self.forwards[-1],
+                          (TokenProjection, NormedTokenLogits))
+            else None)
         self.api.output = self.forwards[-1].output
         self.api.gate_skip = self.loader.idle
         self.api.shutdown_callback = self.request_stop

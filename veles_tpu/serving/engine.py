@@ -29,6 +29,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy
 
 from veles_tpu.models.generate import (
     _StepClosure, _arch_sig, _device_params)
@@ -155,24 +156,37 @@ def hidden_supported(forwards):
         and not hasattr(last, "init_cache")
 
 
+#: key of the routed layers' counts in the pools a paged step returns
+#: (an int like the chain indices: a pytree's keys must sort)
+MOE_COUNTS = -1
+
+
 def _make_paged_step(forwards, want_hidden=False):
     cacheable = frozenset(i for i, u in enumerate(forwards)
                           if hasattr(u, "init_cache"))
+    # units that keep per-slot state or count live rows are told which
+    # slot each packed row is (-1: a padding row)
+    by_slot = frozenset(i for i in cacheable
+                        if hasattr(forwards[i], "cache_kind"))
     last = len(forwards) - 1
 
     def step(params, toks, pos, tables, temps, topks, seeds, counts,
-             pools):
+             slots, pools):
         h = toks
         hid = None
         out = dict(pools)
+        moe = []
         for i, u in enumerate(forwards):
             if want_hidden and i == last:
                 # the final unit's INPUT is the target's last hidden
                 # state — what the draft head conditions on
                 hid = h.astype(jnp.float32)
             if i in cacheable:
-                h, out[i] = u.apply_step_paged(params[i], h, pos,
-                                               tables, pools[i])
+                h, out[i] = u.apply_step_paged(
+                    params[i], h, pos, tables, pools[i],
+                    **({"slots": slots} if i in by_slot else {}))
+                if "moe" in out[i]:   # a routed layer's counts
+                    moe.append(out[i].pop("moe"))
             elif hasattr(u, "apply_step_slots"):
                 h = u.apply_step_slots(params[i], h, pos)
             else:
@@ -180,6 +194,8 @@ def _make_paged_step(forwards, want_hidden=False):
         logits = h[:, 0].astype(jnp.float32)
         keys = _fold_keys(seeds, counts)
         nxt = sample_slots(logits, temps, topks, keys)
+        if moe:   # ONE small array a step: [routed layers, 4]
+            out[MOE_COUNTS] = jnp.stack(moe)
         if want_hidden:
             return nxt, hid[:, 0], out
         return nxt, out
@@ -244,7 +260,7 @@ def _make_paged_step_tp(forwards, ctx, pools, want_hidden=False):
             for name, a in layer.items()}
 
     def body(params, toks, pos, tables, temps, topks, seeds, counts,
-             pools_):
+             slots, pools_):
         h = toks
         hid = None
         out = dict(pools_)
@@ -266,7 +282,8 @@ def _make_paged_step_tp(forwards, ctx, pools, want_hidden=False):
         return nxt, out
 
     rep = P()
-    in_specs = (pspecs, rep, rep, rep, rep, rep, rep, rep, lspecs)
+    in_specs = (pspecs, rep, rep, rep, rep, rep, rep, rep, rep,
+                lspecs)
     out_specs = (rep, rep, lspecs) if want_hidden else (rep, lspecs)
     return shard_map(body, mesh=ctx.mesh, in_specs=in_specs,
                      out_specs=out_specs, check_vma=False)
@@ -280,7 +297,7 @@ def _paged_step_tp_cached(cache_key, closure):
 
 def paged_decode_step(forwards, cache, toks, pos, tables, temps,
                       topks, seeds, counts, want_hidden=False,
-                      params=None):
+                      params=None, slots=None):
     """Run ONE decode step over a PACKED batch of active slots
     against ``cache`` (:class:`serving.kv_slots.PagedKVCache`,
     updated in place).
@@ -298,6 +315,12 @@ def paged_decode_step(forwards, cache, toks, pos, tables, temps,
     hidden-on and hidden-off never share a trace.
 
     ``params`` as in :func:`slot_decode_step`.
+
+    ``slots`` [B] — the slot of each packed row, -1 for a padding row
+    (the default for every row): what a unit with per-slot state
+    indexes its state pool by.  A chain with routed layers leaves
+    their counts of this step, one int32 [layers, 4] device array, in
+    ``cache.moe_counts`` (None otherwise).
 
     A cache built with a tensor-parallel context (``cache.tp_`` —
     serving/tp.py) runs the step SPMD over the tp mesh: ``params`` ride
@@ -348,12 +371,15 @@ def paged_decode_step(forwards, cache, toks, pos, tables, temps,
         jnp.asarray(temps, jnp.float32),
         jnp.asarray(topks, jnp.int32),
         jnp.asarray(seeds, jnp.uint32),
-        jnp.asarray(counts, jnp.int32), cache.pools)
-    if want_hidden:
-        nxt, hid, cache.pools = got
-        return nxt, hid
-    nxt, cache.pools = got
-    return nxt
+        jnp.asarray(counts, jnp.int32),
+        # host rows, handed over by the call itself: a chain that reads
+        # none (jit prunes the argument) pays no transfer of its own
+        numpy.full((b,), -1, numpy.int32) if slots is None
+        else numpy.asarray(slots, numpy.int32), cache.pools)
+    pools = got[-1]
+    cache.moe_counts = pools.pop(MOE_COUNTS, None)
+    cache.pools = pools
+    return (got[0], got[1]) if want_hidden else got[0]
 
 
 def _make_verify_step(forwards, want_hidden=False):

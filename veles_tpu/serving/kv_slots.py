@@ -170,6 +170,36 @@ def _import_blocks_jit():
     return track_jit("serving.kv_import_blocks", jax.jit(pair))
 
 
+def slot_state_units(forwards):
+    """{chain index: unit name} of the cacheable units whose cache is
+    ONE fixed state per slot (``cache_kind == "slot"``: a short
+    convolution's last rows) and not rows that grow with the text."""
+    return {i: u.name for i, u in enumerate(forwards)
+            if hasattr(u, "init_cache")
+            and getattr(u, "cache_kind", "paged") == "slot"}
+
+
+def state_refusal(what, units):
+    """The error of asking, for a chain with per-slot state, for what
+    does not carry it (``units``: :func:`slot_state_units`)."""
+    return ValueError(
+        "%s is not carried for a chain with per-slot state (%s): "
+        "blocks alone do not hold its requests"
+        % (what, ", ".join(sorted(units.values()))))
+
+
+def _state_rows(pool, src, slot):
+    # a per-slot state is written WHOLE: batch-1 staging -> row slot
+    return {name: jax.lax.dynamic_update_slice(
+        pool[name], src[name].astype(pool[name].dtype),
+        (slot,) + (jnp.int32(0),) * (pool[name].ndim - 1))
+        for name in pool}
+
+
+_insert_state = track_jit("serving.kv_insert_state", jax.jit(
+    trace_named("serving.kv_insert_state", _state_rows)))
+
+
 def _insert_layer(layer, src, fn, *args):
     """Insert one layer's staging K/V via the paired jitted call,
     falling back per-name for exotic cache pytrees."""
@@ -276,7 +306,20 @@ class PagedKVCache:
     Inserts quantize (``serving.kv_quant_insert_blocks``), the warm
     gather dequantizes (``serving.kv_quant_gather_blocks``), and the
     decode/verify steps quantize-on-scatter / dequant-on-gather in
-    ``ops/paged_attention.py``."""
+    ``ops/paged_attention.py``.
+
+    TWO KINDS OF STATE.  Each cacheable unit is asked what it holds
+    (:func:`slot_state_units`): paged rows as above, or one fixed
+    state per slot.  A state pool is ``init_cache(max_slots + 1, ...)``
+    — row ``max_slots`` is the trash row that a packed step's padding
+    rows read and write — indexed by SLOT, written whole by
+    :meth:`insert` and by every decode step, never zeroed (an
+    admission's staging starts from ``init_cache``'s zeros) and
+    forgotten at release.  It costs no block: ``bytes_per_token`` and
+    ``can_admit`` count the paged layers alone, :meth:`state_bytes`
+    gives both.  A prefix of blocks says nothing about such a state,
+    so block export/import, the warm gather, int8 pools and a tp mesh
+    refuse a chain that has one."""
 
     def __init__(self, forwards, max_slots, window, block_size=16,
                  kv_blocks=None, kv_dtype="fp32", tp=None):
@@ -297,7 +340,11 @@ class PagedKVCache:
         if self.capacity_blocks < 1:
             raise ValueError("need kv_blocks >= 1")
         num = self.capacity_blocks + 1          # + the trash block 0
+        self.state_units = slot_state_units(forwards)
+        if tp is not None:
+            self._blocks_only("tp")
         if kv_dtype == "int8":
+            self._blocks_only("kv_dtype='int8'")
             # int8 needs block-pool-aware units (the scale layout is
             # theirs to consume in apply_step_paged)
             missing = [type(u).__name__ for u in forwards
@@ -315,8 +362,9 @@ class PagedKVCache:
                 if hasattr(u, "init_cache")}
         else:
             self.pools = {
-                i: u.init_cache(num, self.block_size,
-                                dtypes.compute_dtype())
+                i: u.init_cache(
+                    self.max_slots + 1 if i in self.state_units
+                    else num, self.block_size, dtypes.compute_dtype())
                 for i, u in enumerate(forwards)
                 if hasattr(u, "init_cache")}
         if not self.pools:
@@ -341,6 +389,9 @@ class PagedKVCache:
         #: the caller instead of the free list; decode never writes
         #: them because the cold offset starts past the shared range)
         self.n_shared = numpy.zeros((self.max_slots,), numpy.int32)
+        #: the routed layers' counts of the last decode step, a device
+        #: array [layers, 4] (serving/engine.paged_decode_step)
+        self.moe_counts = None
 
     # -- occupancy reads ------------------------------------------------
 
@@ -371,7 +422,9 @@ class PagedKVCache:
         scales still cost every chip their full byte."""
         shards = self.tp_.size if self.tp_ is not None else 1
         total = 0
-        for layer in self.pools.values():
+        for i, layer in self.pools.items():
+            if i in self.state_units:    # costs a slot, not a token
+                continue
             for name, arr in layer.items():
                 if name.endswith("_scale"):   # one scale per row
                     total += arr.dtype.itemsize
@@ -379,6 +432,19 @@ class PagedKVCache:
                     total += arr.shape[-1] * arr.dtype.itemsize \
                         // shards
         return int(total)
+
+    def state_bytes(self):
+        """{"kv": bytes of the paged pools, "conv": bytes of the
+        per-slot state pools} resident on the devices."""
+        out = {"kv": 0, "conv": 0}
+        for i, layer in self.pools.items():
+            out["conv" if i in self.state_units else "kv"] += sum(
+                a.nbytes for a in layer.values())
+        return out
+
+    def _blocks_only(self, what):
+        if self.state_units:
+            raise state_refusal(what, self.state_units)
 
     def blocks_needed(self, total_tokens):
         return -(-max(int(total_tokens), 1) // self.block_size)
@@ -537,6 +603,10 @@ class PagedKVCache:
         start = jnp.int32(f * self.block_size)
         for i, layer in self.pools.items():
             src = row_caches[i]
+            if i in self.state_units:
+                self.pools[i] = _insert_state(layer, src,
+                                              jnp.int32(slot))
+                continue
             wk = next(iter(src.values())).shape[1]
             if wk < need * self.block_size:
                 raise ValueError(
@@ -561,6 +631,7 @@ class PagedKVCache:
         pool's storage dtype (int8 stays int8 — its scales travel in
         the same record, so the importing replica reproduces the
         resident bytes exactly, no dequant→requant noise)."""
+        self._blocks_only("block export")
         ids = jnp.asarray(numpy.asarray(ids, numpy.int32))
         fn = _export_blocks_jit()
         out = {}
@@ -586,6 +657,7 @@ class PagedKVCache:
         the importing table's blocks end up byte-identical to the
         exporter's, scales included — so the decode loop attends over
         exactly the K/V the colocated path would have."""
+        self._blocks_only("block import")
         ids_j = jnp.asarray(numpy.asarray(ids, numpy.int32))
         n = int(len(ids))
         fn = _import_blocks_jit()
@@ -630,6 +702,7 @@ class PagedKVCache:
         attends over these rows exactly as if it had prefilled them
         itself (the resident K/V was produced by the identical
         computation).  Returns the updated staging dict."""
+        self._blocks_only("the warm gather of a prefix")
         if not len(ids):
             return row_caches
         ids = jnp.asarray(numpy.asarray(ids, numpy.int32))

@@ -323,6 +323,28 @@ def _registry_series():
             "weights held in the compute dtype, cast once at start); "
             "0 once the server has closed",
             labelnames=("dtype", "replica")),
+        "state_bytes": metrics.gauge(
+            "veles_serving_state_bytes",
+            "bytes resident for the requests' state, by kind: kv (the "
+            "paged K/V pools) and conv (the fixed per-slot state of "
+            "short-convolution layers, serving/kv_slots.py)",
+            labelnames=("kind", "replica")),
+        "moe_layer_steps": metrics.counter(
+            "veles_serving_moe_layer_steps_total",
+            "routed layers x decode steps"),
+        "moe_pairs": metrics.counter(
+            "veles_serving_moe_pairs_total",
+            "routed (row, expert) pairs of decode steps: live rows x "
+            "experts a token x routed layers (padding rows excluded)"),
+        "moe_experts_touched": metrics.counter(
+            "veles_serving_moe_experts_touched_total",
+            "distinct experts with a live row, summed over routed "
+            "layers and decode steps: the expert weights a step had "
+            "to read"),
+        "moe_hottest_rows": metrics.counter(
+            "veles_serving_moe_hottest_rows_total",
+            "live rows on the most loaded expert, summed over routed "
+            "layers and decode steps"),
         "weight_leaves_cast": metrics.counter(
             "veles_serving_weight_leaves_cast_total",
             "parameter leaves a server holds in the compute dtype "
@@ -1219,6 +1241,11 @@ class ServingMetrics:
         self._global["kv_bytes_per_token"].labels(
             replica=self.replica).set(int(bytes_per_token))
 
+    def set_state_bytes(self, by_kind):
+        for kind, nbytes in by_kind.items():
+            self._global["state_bytes"].labels(
+                kind=kind, replica=self.replica).set(int(nbytes))
+
     def set_weights(self, bytes_by_dtype, leaves_cast):
         """Advertise the serving weights (at start; the same dtypes at
         0 bytes, and 0 leaves, at close)."""
@@ -1246,7 +1273,7 @@ class ServingMetrics:
             self._loop["step_after_prefill"].inc(
                 step_after_prefill_seconds)
 
-    def record_step(self, active, slots, tokens=None):
+    def record_step(self, active, slots, tokens=None, moe=None):
         """One batched decode/verify boundary: ``active`` real rows
         rode a padded ``slots``-row bucket; ``tokens`` is what the
         step actually emitted (spec verify can emit up to k+1 per
@@ -1254,7 +1281,15 @@ class ServingMetrics:
         gauge (whose window uses wall-clock arrival times, so a
         stalled loop DROPS the gauge instead of freezing it at the
         last healthy rate).  The step's seconds are the loop's
-        ``step`` phase (:meth:`record_loop_pass`)."""
+        ``step`` phase (:meth:`record_loop_pass`).  ``moe``: the
+        routed layers' counts of a decode step, int [layers, 4] =
+        (1, pairs, experts touched, rows on the hottest expert)."""
+        if moe is not None:
+            for name, total in zip(
+                    ("moe_layer_steps", "moe_pairs",
+                     "moe_experts_touched", "moe_hottest_rows"),
+                    moe.sum(axis=0).tolist()):
+                self._global[name].inc(total)
         now = time.monotonic()
         with self._lock:
             self.slot_busy_steps += int(active)

@@ -23,29 +23,29 @@ import jax.numpy as jnp
 import numpy
 
 from veles_tpu.models.generate import (
-    _StepClosure, _arch_sig, _check_positions, _device_params,
-    kv_cache_eligible)
+    _StepClosure, _arch_sig, _check_positions, _device_params)
+from veles_tpu.serving.kv_slots import slot_state_units
 from veles_tpu.telemetry import trace_named, track_jit
 
 
 def serving_supported(forwards):
     """True when the chain can serve through the slot scheduler:
-    kv-cache eligible AND every cacheable block speaks the serving
-    step shapes (``apply_prefill`` + ``apply_step_slots``) AND every
-    other sequence-dependent unit has a per-slot step or is
-    position-wise."""
-    if not kv_cache_eligible(forwards):
-        return False
+    every cacheable block is causal and speaks the serving step
+    shapes (``apply_prefill`` + a decode step: ``apply_step_paged``
+    or the dense ``apply_step_slots``) AND every other
+    sequence-dependent unit has a per-slot step or is position-wise."""
     has_cache = False
     for u in forwards:
         if hasattr(u, "init_cache"):
             has_cache = True
-            if not hasattr(u, "apply_prefill") \
-                    or not hasattr(u, "apply_step_slots"):
+            if not u.causal or not hasattr(u, "apply_prefill") \
+                    or not (hasattr(u, "apply_step_slots")
+                            or hasattr(u, "apply_step_paged")):
                 return False
-        elif hasattr(u, "apply_step") \
-                and not getattr(u, "DECODE_POINTWISE", False) \
-                and not hasattr(u, "apply_step_slots"):
+        elif getattr(u, "DECODE_POINTWISE", False):
+            continue
+        elif not hasattr(u, "apply_step") \
+                or not hasattr(u, "apply_step_slots"):
             return False
     return has_cache
 
@@ -58,7 +58,7 @@ def serving_window(forwards):
     best = None
     for u in forwards:
         pos_table = getattr(u, "positions", None)
-        if pos_table is not None and hasattr(pos_table, "shape") \
+        if getattr(pos_table, "shape", None) is not None \
                 and len(pos_table.shape) == 2:
             n = int(pos_table.shape[0])
             best = n if best is None else min(best, n)
@@ -155,8 +155,9 @@ def prefill_chunk(forwards, chunk, offset, chunk_lens, caches,
         params = _device_params(forwards)
     chunk = jnp.asarray(chunk, jnp.int32)
     b, c = chunk.shape
+    state = slot_state_units(forwards)   # a fixed state has no width
     widths = {tuple(a.shape[1] for a in layer.values())
-              for layer in caches.values()}
+              for i, layer in caches.items() if i not in state}
     w = next(iter(widths))[0]
     if any(x != w for tup in widths for x in tup):
         raise ValueError("staging caches disagree on width")

@@ -205,7 +205,8 @@ from veles_tpu.serving.engine import (
     verify_step_paged, verify_supported)
 from veles_tpu.serving.kv_host import HostKVTier
 from veles_tpu.serving.kv_slots import (
-    PagedKVCache, SlotKVCache, paged_supported)
+    PagedKVCache, SlotKVCache, paged_supported, slot_state_units,
+    state_refusal)
 from veles_tpu.serving.metrics import ServingMetrics
 from veles_tpu.serving.prefill import (
     chunked_supported, prefill, prefill_chunk, serving_supported,
@@ -539,7 +540,20 @@ class InferenceScheduler(Logger):
             self.info("chain has no paged decode step; falling back "
                       "to the dense slot cache")
             kv = "dense"
+        paged_only = [u.name for u in forwards
+                      if hasattr(u, "init_cache")
+                      and not hasattr(u, "apply_step_slots")]
+        if kv == "dense" and paged_only:
+            raise ValueError(
+                "kv='dense' is not carried by %s: they have the paged "
+                "decode step alone" % ", ".join(paged_only))
         self.kv = kv
+        #: units that keep ONE fixed state per slot beside the paged
+        #: K/V (serving/kv_slots.py).  What follows does not carry
+        #: such a state and is refused in words when asked for by
+        #: argument; asked for by the configuration's default alone it
+        #: is turned off and said so (`_without_state`)
+        self._state_units = slot_state_units(forwards)
         self.block_size = int(
             block_size or _serving_conf("block_size", 16))
         if self.block_size < 1:
@@ -555,7 +569,11 @@ class InferenceScheduler(Logger):
         #: or "int8" (per-row scales beside the block tables, ~half
         #: the bytes per cached token → ~2x streams per HBM budget;
         #: quality-gated, see serving/kv_quality.py).  Paged only.
+        asked = kv_dtype
         kv_dtype = kv_dtype or _serving_conf("kv_dtype", "fp32")
+        if kv_dtype == "int8" and not self._without_state(
+                "kv_dtype='int8'", True if asked else None, True):
+            kv_dtype = "fp32"
         if kv_dtype not in ("fp32", "int8"):
             raise ValueError("kv_dtype must be 'fp32' or 'int8'")
         if kv_dtype == "int8" and self.kv != "paged":
@@ -593,8 +611,9 @@ class InferenceScheduler(Logger):
         #: ONE batched verify pass — output streams stay bit-
         #: identical (greedy and per-seed sampling), accepted drafts
         #: are pure latency win.  Paged-KV only.
-        spec = bool(_serving_conf("spec", False)
-                    if spec is None else spec)
+        spec = bool(self._without_state(
+            "speculative decoding (spec)", spec,
+            _serving_conf("spec", False)))
         self.spec_k = int(_serving_conf("spec_k", 4)
                           if spec_k is None else spec_k)
         if spec and self.spec_k < 1:
@@ -660,8 +679,10 @@ class InferenceScheduler(Logger):
         #: — needs the paged cache, chunked prefill for the cold
         #: tail, and a power-of-two block size (the staging/chunk
         #: tilings assume it)
-        pfx = bool(_serving_conf("prefix_cache", False)
-                   if prefix_cache is None else prefix_cache)
+        pfx = bool(self._without_state(
+            "the radix prefix cache (prefix_cache): a hit hands over "
+            "K/V blocks and no state,", prefix_cache,
+            _serving_conf("prefix_cache", False)))
         if pfx and (self.kv != "paged" or not self.prefill_chunk
                     or self.block_size & (self.block_size - 1)):
             self.info("prefix cache needs kv='paged', chunked "
@@ -677,8 +698,9 @@ class InferenceScheduler(Logger):
         #: instead of dropping them, and matching admissions promote
         #: them back.  0 disables (the tier-1 baseline); needs the
         #: prefix cache (the tier is keyed by its token paths)
-        hb = int(_serving_conf("kv_host_bytes", 0)
-                 if kv_host_bytes is None else kv_host_bytes or 0)
+        hb = int(self._without_state(
+            "the host KV tier (kv_host_bytes)", kv_host_bytes,
+            _serving_conf("kv_host_bytes", 0)) or 0)
         if hb and not pfx:
             self.info("kv_host_bytes needs the prefix cache; host "
                       "tier disabled")
@@ -696,7 +718,10 @@ class InferenceScheduler(Logger):
         #: head-wise paged pools, per-chip kv_blocks HBM / N
         #: (serving/tp.py; module docstring).  Needs the paged cache,
         #: N devices, and a chain whose blocks declare tp layouts.
-        tp = int(_serving_conf("tp", 0) if tp is None else tp or 0)
+        if tp is not None and int(tp) == 1:
+            tp = 0
+        tp = int(self._without_state("tp", tp,
+                                     _serving_conf("tp", 0)) or 0)
         if tp == 1:
             tp = 0
         self.tp_ = None
@@ -727,6 +752,9 @@ class InferenceScheduler(Logger):
         if role not in ("both", "prefill", "decode"):
             raise ValueError(
                 "role must be 'prefill', 'decode' or 'both'")
+        if role != "both":
+            self._without_state(
+                "role=%r (block export and import)" % role, True, True)
         if role == "prefill" and self.kv != "paged":
             raise ValueError("role='prefill' needs the paged cache "
                              "(block export is block-granular)")
@@ -787,6 +815,22 @@ class InferenceScheduler(Logger):
         self.host_ = HostKVTier(self.kv_host_bytes,
                                 self.block_size) \
             if self.kv_host_bytes > 0 else None
+
+    def _without_state(self, what, asked, default):
+        """The value of an option that per-slot state is not carried
+        through: ``asked`` (the argument; None: not given) or else the
+        configuration's ``default``.  On a chain with such state
+        (``_state_units``) an option that was asked for is refused in
+        words, and one that only the configuration's default turns on
+        is turned off and logged; never run wrongly."""
+        value = default if asked is None else asked
+        if not value or not self._state_units:
+            return value
+        if asked is not None:
+            raise state_refusal(what, self._state_units)
+        self.info("%s off: not carried for per-slot state (%s)", what,
+                  ", ".join(sorted(self._state_units.values())))
+        return type(value)()
 
     # -- client side ----------------------------------------------------
 
@@ -1551,6 +1595,11 @@ class InferenceScheduler(Logger):
             out["kv_blocks_free"] = \
                 cache.free_blocks if cache is not None \
                 else self.kv_blocks
+            # what is resident for it: the paged K/V pools, and the
+            # per-slot state beside them (0 for a chain with none)
+            out["state_bytes"] = \
+                cache.state_bytes() if cache is not None else None
+            out["state_units"] = sorted(self._state_units.values())
         out["spec"] = self.spec
         out["spec_k"] = self.spec_k if self.spec else 0
         out["drafter"] = self.drafter if self.spec else None
@@ -1798,6 +1847,7 @@ class InferenceScheduler(Logger):
             if self.kv == "paged":
                 self.stats.set_kv_dtype(self.kv_dtype,
                                         cache.bytes_per_token())
+                self.stats.set_state_bytes(cache.state_bytes())
         except Exception as e:  # surface init failures to clients
             with self._wake:
                 self._closed = True
@@ -2721,6 +2771,8 @@ class InferenceScheduler(Logger):
             seeds = numpy.zeros((b,), numpy.uint32)
             counts = numpy.zeros((b,), numpy.int32)
             tables = numpy.zeros((b, t), numpy.int32)
+            rows = numpy.full((b,), -1, numpy.int32)   # -1: padding
+            rows[:n] = slots
             arrays = (toks, pos, temps, topks, seeds, counts)
             for j, slot in enumerate(slots):
                 self._fill_row(arrays, j, active[slot])
@@ -2730,17 +2782,21 @@ class InferenceScheduler(Logger):
             got = paged_decode_step(
                 self.forwards, cache, toks, pos, tables, temps, topks,
                 seeds, counts, want_hidden=want_h,
-                params=self.weights_.params)
+                params=self.weights_.params, slots=rows)
             if want_h:
                 nxt, hid = got
                 hid = numpy.asarray(hid)
             else:
                 nxt = got
             nxt = numpy.asarray(nxt)
+            # the routed layers' counts come with the step: computed
+            # by then, one small copy, no further wait on the device
+            moe = None if cache.moe_counts is None \
+                else numpy.asarray(cache.moe_counts)
         dt = launch.seconds
         with self._phases("observe"):
             # plain decode: every active slot emits exactly one token
-            self.stats.record_step(n, b, tokens=n)
+            self.stats.record_step(n, b, tokens=n, moe=moe)
             self._meter_step(active, cache, dt)
         with self._phases("emit"):
             for j, slot in enumerate(slots):

@@ -14,6 +14,11 @@ step: the same round-to-nearest of the same float32.  Under float32
 compute, and for a unit that names nothing, the pytree is the units'
 own device buffers.
 
+A leaf that its unit already holds in the compute dtype (a checkpoint
+handed over as bfloat16 device leaves before the unit initialized: no
+host mirror, no float32 copy anywhere) is named by nobody and taken as
+it is.
+
 A tensor-parallel context (serving/tp.py) places each leaf on its mesh
 after the cast, so there is one notion of frozen serving weights.
 
