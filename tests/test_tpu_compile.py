@@ -185,10 +185,12 @@ def test_attention_kernel_partitions_over_a_dp_mesh(topo,
 
 # -- lowered, not compiled: the serving steps at the benchmark's widths -------
 
-def _opt67_chain():
+def _opt67_chain(**cut):
     """The serve cell's chain (benchmark/configs/opt-6.7b-8l.json) with
     no weights made: every parameter a lazily-zero host array that
-    nothing touches, so the units can say which leaves they declare."""
+    nothing touches, so the units can say which leaves they declare.
+    ``cut``: shapes to replace (fewer ``layers``, a narrower ``vocab``)
+    where a test looks at neither."""
     import json
     import os
 
@@ -200,7 +202,7 @@ def _opt67_chain():
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(here, "benchmark", "configs",
                            "opt-6.7b-8l.json")) as f:
-        s = json.load(f)["shapes"]
+        s = dict(json.load(f)["shapes"], **cut)
     d, h, v = s["dim"], s["ffn"], s["vocab"]
     spec = [{"type": "embedding", "vocab": v, "dim": d}]
     spec += [{"type": "transformer_block", "heads": s["heads"],
@@ -292,3 +294,60 @@ def test_serving_step_casts_no_weight(lower):
     rows, lowered = lower(fw, s, _abstract_params(fw, False))
     assert len(_weight_casts(lowered.as_text(), rows, s["dim"])) \
         == declared
+
+
+# -- compiled: the programs that return the KV pools write them in place ------
+
+def _pool_programs(one_chip):
+    """{name: (jitted entry point, abstract arguments on the described
+    chip, positions of the pool arguments)} at the OPT cell's pool shape
+    and widths, two layers deep under a narrow head (more layers add
+    nothing to see; the sampler's sort over 50272 logits alone takes
+    the compiler 23 s)."""
+    import math
+
+    from veles_tpu.models.generate import _StepClosure
+    from veles_tpu.serving import engine, kv_slots
+    fw, s = _opt67_chain(layers=2, vocab=1024)
+    d, b, t, block, blocks = s["dim"], 8, 64, 16, 8 * 128 + 1
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def arr(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype)
+    pool = arr(jnp.bfloat16, blocks, block, d)
+    pools = {i: {"k": pool, "v": pool} for i, u in enumerate(fw)
+             if hasattr(u, "init_cache")}
+    step = engine._paged_step_cached(
+        "pools-in-place", _StepClosure(engine._make_paged_step(fw)))
+    stage = arr(jnp.bfloat16, 1, 1024, d)
+    return math.prod(pool.shape), {
+        "paged_step": (step, on_chip((
+            _abstract_params(fw, True), arr(jnp.int32, b, 1),
+            arr(jnp.int32, b), arr(jnp.int32, b, t),
+            arr(jnp.float32, b), arr(jnp.int32, b), arr(jnp.uint32, b),
+            arr(jnp.int32, b), arr(jnp.int32, b), pools)), 2 * len(pools)),
+        "kv_insert_blocks": (kv_slots._insert_blocks, on_chip((
+            pool, pool, stage, stage, arr(jnp.int32, 17),
+            arr(jnp.int32))), 2),
+    }
+
+
+@pytest.mark.parametrize("name", ["kv_insert_blocks", "paged_step"])
+def test_pools_are_written_in_place_on_v5e(name, one_chip,
+                                           no_compile_cache):
+    """Compiled for the described chip, the decode step and the block
+    insert alias every donated pool to its output and hold no copy of
+    a whole pool (PERF.md, PR 30: without the donation the step held
+    one ``copy`` a pool, 41 % of its device time)."""
+    import re
+    size, programs = _pool_programs(one_chip)
+    fn, args, n_pools = programs[name]
+    text = fn.lower(*args).compile().as_text()
+    head = text.split("\n", 1)[0]
+    assert head.count("-alias)") == n_pools, head[:400]
+    shape = r"bf16\[1025,16,4096\]"
+    assert size == 1025 * 16 * 4096
+    assert re.findall(r"= %s\S* copy\(" % shape, text) == []

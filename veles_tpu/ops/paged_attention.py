@@ -320,17 +320,22 @@ def paged_verify_attention_q8(q, k_new, v_new, pool_k, pool_v,
 def paged_verify_attention_fused(q, k_new, v_new, pool_k, pool_v,
                                  tables, pos, lens, heads,
                                  backend=None):
-    """Single-pass fp32 verify.  The PR 9 two-pass path scatters the
+    """Single-pass fp32 verify.  The two-pass path scatters the
     run's K/V into the POOL and then gathers it back out before
-    attending — the attention waits on a write to (and under jit
-    without donation, a full copy of) the multi-megabyte pool just to
-    read back the handful of rows it wrote.  Here the gather reads
-    the PRE-scatter pool and the run's rows are scattered into the
-    small GATHERED buffer instead ([B, T·bs, d] — the write is
-    O(batch·k), not O(pool)), which takes the pool update off the
-    attention's critical path entirely: the engine donates the pool
-    buffers to this step, so the scatter lands in place and the
-    per-step pool copy disappears.
+    attending — the attention waits on a write to the
+    multi-megabyte pool just to read back the handful of rows it
+    wrote.  Here the gather reads the PRE-scatter pool and the run's
+    rows are scattered into the small GATHERED buffer instead
+    ([B, T·bs, d] — the write is O(batch·k), not O(pool)), which takes
+    the pool update off the attention's critical path.
+
+    Every verify step takes the pools donated (serving/engine.py).
+    Reading the pre-scatter pool while the same donated buffer is
+    scattered into makes XLA keep the old values by COPYING the pool
+    (compiled for the CPU: two whole-pool copies a pool, where the
+    two-pass path has none), so of the jnp branches the two-pass one
+    is the in-place one; the accelerator branch below reads the
+    POST-scatter pool and has no such read.
 
     The gathered buffer ends up elementwise IDENTICAL to the
     two-pass gather at every causally-visible position, and the

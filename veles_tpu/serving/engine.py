@@ -96,10 +96,18 @@ def _make_step(forwards):
     return step
 
 
+# Every step below takes the cache's device state (its LAST argument)
+# DONATED and returns it anew: the caller swaps the cache's attribute
+# for what came back at once and nothing else holds the old leaves
+# (the invariant is said in serving/kv_slots.py), so the scatter of a
+# step's rows lands in place instead of in a copy of every pool;
+# ``cache.note_swap`` counts a call whose input came back alive.
+
 @functools.lru_cache(maxsize=16)
 def _step_cached(cache_key, closure):
     return track_jit("serving.slot_step", jax.jit(
-        trace_named("serving.slot_step", closure.fn)))
+        trace_named("serving.slot_step", closure.fn),
+        donate_argnums=(7,)))
 
 
 def clear_step_cache():
@@ -133,6 +141,7 @@ def slot_decode_step(forwards, cache, toks, pos, temps, topks, seeds,
                  str(dtypes.compute_dtype()),
                  str(dtypes.matmul_precision()))
     fn = _step_cached(cache_key, _StepClosure(_make_step(forwards)))
+    old = cache.first_leaf()
     nxt, cache.caches = fn(
         params, jnp.asarray(toks, jnp.int32),
         jnp.asarray(pos, jnp.int32),
@@ -140,6 +149,7 @@ def slot_decode_step(forwards, cache, toks, pos, temps, topks, seeds,
         jnp.asarray(topks, jnp.int32),
         jnp.asarray(seeds, jnp.uint32),
         jnp.asarray(counts, jnp.int32), cache.caches)
+    cache.note_swap(old)
     return nxt
 
 
@@ -205,7 +215,8 @@ def _make_paged_step(forwards, want_hidden=False):
 @functools.lru_cache(maxsize=64)
 def _paged_step_cached(cache_key, closure):
     return track_jit("serving.paged_step", jax.jit(
-        trace_named("serving.paged_step", closure.fn)))
+        trace_named("serving.paged_step", closure.fn),
+        donate_argnums=(9,)))
 
 
 def overlap_supported(forwards):
@@ -292,7 +303,8 @@ def _make_paged_step_tp(forwards, ctx, pools, want_hidden=False):
 @functools.lru_cache(maxsize=32)
 def _paged_step_tp_cached(cache_key, closure):
     return track_jit("serving.paged_step_tp", jax.jit(
-        trace_named("serving.paged_step_tp", closure.fn)))
+        trace_named("serving.paged_step_tp", closure.fn),
+        donate_argnums=(9,)))
 
 
 def paged_decode_step(forwards, cache, toks, pos, tables, temps,
@@ -365,6 +377,7 @@ def paged_decode_step(forwards, cache, toks, pos, tables, temps,
         fn = _paged_step_cached(
             cache_key, _StepClosure(_make_paged_step(
                 forwards, want_hidden=want_hidden)))
+    old = cache.first_leaf()
     got = fn(
         params, jnp.asarray(toks, jnp.int32),
         jnp.asarray(pos, jnp.int32), tables,
@@ -379,6 +392,7 @@ def paged_decode_step(forwards, cache, toks, pos, tables, temps,
     pools = got[-1]
     cache.moe_counts = pools.pop(MOE_COUNTS, None)
     cache.pools = pools
+    cache.note_swap(old)
     return (got[0], got[1]) if want_hidden else got[0]
 
 
@@ -422,17 +436,14 @@ def _make_verify_step(forwards, want_hidden=False):
 
 
 @functools.lru_cache(maxsize=64)
-def _verify_step_cached(cache_key, closure, donate=False):
-    # the fused/int8 verify paths take the pool update off the
-    # attention's critical path (ops/paged_attention.py), so the pool
-    # buffers can be DONATED — the scatter lands in place instead of
-    # copying the whole pool every step.  Safe: the caller swaps
-    # cache.pools for the returned pools immediately (the donated
-    # arrays are never read again).  The legacy two-pass executable
-    # keeps the PR 9 no-donation behavior byte-for-byte.
+def _verify_step_cached(cache_key, closure):
+    # the two-pass verify scatters, then gathers the post-scatter
+    # pool, as the decode step does; the fused one gathers the
+    # PRE-scatter pool, which the compiler orders before the in-place
+    # scatter (ops/paged_attention.paged_verify_attention_fused)
     return track_jit("serving.verify_step", jax.jit(
         trace_named("serving.verify_step", closure.fn),
-        donate_argnums=(9,) if donate else ()))
+        donate_argnums=(9,)))
 
 
 def verify_step_paged(forwards, cache, toks, pos, lens, tables,
@@ -487,8 +498,8 @@ def verify_step_paged(forwards, cache, toks, pos, lens, tables,
     fn = _verify_step_cached(
         cache_key,
         _StepClosure(_make_verify_step(forwards,
-                                       want_hidden=want_hidden)),
-        donate=fused or kv_dtype == "int8")
+                                       want_hidden=want_hidden)))
+    old = cache.first_leaf()
     got = fn(
         params, toks, jnp.asarray(pos, jnp.int32),
         jnp.asarray(lens, jnp.int32), tables,
@@ -496,11 +507,9 @@ def verify_step_paged(forwards, cache, toks, pos, lens, tables,
         jnp.asarray(topks, jnp.int32),
         jnp.asarray(seeds, jnp.uint32),
         jnp.asarray(counts, jnp.int32), cache.pools)
-    if want_hidden:
-        nxt, hid, cache.pools = got
-        return nxt, hid
-    nxt, cache.pools = got
-    return nxt
+    cache.pools = got[-1]
+    cache.note_swap(old)
+    return got[:-1] if want_hidden else got[0]
 
 
 def verify_supported(forwards):

@@ -27,7 +27,24 @@ step-limit frees slot + blocks; no zeroing needed — every attended
 row [0, len) was written by the current occupant).
 
 All methods must be called from ONE thread (the scheduler's decode
-loop) — the arrays are plain jax values, swapped functionally.
+loop).
+
+THE DEVICE STATE IS DONATED, ALWAYS.  Every jitted program that takes
+a cache's device state (``pools`` / ``caches``) and returns it anew —
+the decode and verify steps of serving/engine.py, and the inserts and
+the import below — takes it DONATED, so the scatter lands in place
+instead of in a copy of the whole pool.  The invariant that makes it
+safe, said once: whoever calls such a program owns the ONLY reference
+to the leaves it hands over and swaps the cache's attribute for what
+came back at once; nothing else keeps an old leaf (a deleted leaf
+still answers ``nbytes`` / ``shape``, which is all
+:meth:`PagedKVCache.state_bytes` asks; its values raise).  The programs
+that only READ the pools (the warm gather, the export) donate nothing.
+``note_swap`` counts the calls whose input came back alive
+(``pool_copies``: the program copied, expected 0); a call that failed
+AFTER it consumed its input leaves deleted leaves behind, which
+``pools_lost()`` sees and ``reset_pools()`` cures by zeroing (the
+scheduler fails every request that lived in them).
 """
 
 import functools
@@ -53,7 +70,7 @@ def _row_pair(dst_k, dst_v, src_k, src_v, slot):
 
 
 _insert_row_pair = track_jit("serving.kv_insert_row",
-                             jax.jit(_row_pair))
+                             jax.jit(_row_pair, donate_argnums=(0, 1)))
 
 
 def _block_pair(pool_k, pool_v, src_k, src_v, ids, start):
@@ -76,7 +93,8 @@ def _block_pair(pool_k, pool_v, src_k, src_v, ids, start):
 
 
 _insert_blocks = track_jit("serving.kv_insert_blocks", jax.jit(
-    trace_named("serving.kv_insert_blocks", _block_pair)))
+    trace_named("serving.kv_insert_blocks", _block_pair),
+    donate_argnums=(0, 1)))
 
 
 @functools.lru_cache(maxsize=1)
@@ -124,7 +142,8 @@ def _quant_block_pair(pool_k, pool_v, scale_k, scale_v, src_k, src_v,
 def _insert_blocks_q8_jit():
     # lazy like _gather_blocks_jit — no module-level executable ref
     return track_jit("serving.kv_quant_insert_blocks",
-                     jax.jit(_quant_block_pair))
+                     jax.jit(_quant_block_pair,
+                             donate_argnums=(0, 1, 2, 3)))
 
 
 @functools.lru_cache(maxsize=1)
@@ -167,7 +186,8 @@ def _import_blocks_jit():
     def pair(a, b, src_a, src_b, ids):
         return (a.at[ids].set(src_a.astype(a.dtype)),
                 b.at[ids].set(src_b.astype(b.dtype)))
-    return track_jit("serving.kv_import_blocks", jax.jit(pair))
+    return track_jit("serving.kv_import_blocks",
+                     jax.jit(pair, donate_argnums=(0, 1)))
 
 
 def slot_state_units(forwards):
@@ -197,20 +217,61 @@ def _state_rows(pool, src, slot):
 
 
 _insert_state = track_jit("serving.kv_insert_state", jax.jit(
-    trace_named("serving.kv_insert_state", _state_rows)))
+    trace_named("serving.kv_insert_state", _state_rows),
+    donate_argnums=(0,)))
 
 
-def _insert_layer(layer, src, fn, *args):
-    """Insert one layer's staging K/V via the paired jitted call,
-    falling back per-name for exotic cache pytrees."""
-    if set(layer) == {"k", "v"}:
-        k, v = fn(layer["k"], layer["v"], src["k"], src["v"], *args)
-        return {"k": k, "v": v}
-    out = {}
-    for name in layer:
-        out[name], _ = fn(layer[name], layer[name], src[name],
-                          src[name], *args)
-    return out
+def _pairs_only(state, names, what):
+    """Refuse, in words, a layer whose cache is not exactly ``names``:
+    the paired programs hand each array over donated, once."""
+    for i, layer in state.items():
+        if set(layer) != set(names):
+            raise ValueError(
+                "%s holds %s for chain unit %s, not %s: its insert, "
+                "gather, export and import programs move K and V as "
+                "one donated pair" % (what, sorted(layer), i,
+                                      sorted(names)))
+
+
+class _DonatedState:
+    """What both caches share: the account of their state-returning
+    calls and the recovery of a device state lost to a failed one
+    (``STATE`` names the attribute that holds it)."""
+
+    STATE = None
+    #: state-returning calls that came back / that copied
+    pool_swaps = pool_copies = 0
+
+    def first_leaf(self):
+        """The first array of the device state: what a caller hands to
+        :meth:`note_swap` once its call has come back."""
+        return next(iter(next(iter(
+            getattr(self, self.STATE).values())).values()))
+
+    def note_swap(self, old):
+        """Account one state-returning call that came back: ``old`` is
+        the first leaf that went in donated.  Still alive (a host-side
+        flag, no device sync) means the program could not write in
+        place and copied (``veles_serving_pool_copies_total``,
+        expected 0)."""
+        self.pool_swaps += 1
+        if not old.is_deleted():
+            self.pool_copies += 1
+
+    def pools_lost(self):
+        """True when a call that failed after it consumed its donated
+        input left deleted leaves behind."""
+        return any(a.is_deleted() for a in
+                   jax.tree.leaves(getattr(self, self.STATE)))
+
+    def reset_pools(self):
+        """Zero the whole device state: every request and every
+        resident prefix in it is lost (the caller fails and forgets
+        them).  Shape, dtype and sharding still answer on a deleted
+        leaf."""
+        setattr(self, self.STATE, jax.tree.map(
+            lambda a: jnp.zeros(a.shape, a.dtype, device=a.sharding),
+            getattr(self, self.STATE)))
 
 
 def paged_supported(forwards):
@@ -226,10 +287,12 @@ def paged_supported(forwards):
     return has
 
 
-class SlotKVCache:
+class SlotKVCache(_DonatedState):
     """Per-layer dense slot-major K/V buffers + free-slot
     bookkeeping (the legacy layout; parity baseline for the paged
     cache)."""
+
+    STATE = "caches"
 
     def __init__(self, forwards, max_slots, window):
         from veles_tpu import dtypes
@@ -244,6 +307,7 @@ class SlotKVCache:
             if hasattr(u, "init_cache")}
         if not self.caches:
             raise ValueError("chain has no cacheable blocks")
+        _pairs_only(self.caches, ("k", "v"), "the dense slot cache")
         # lowest slot first — keeps occupancy dense and debuggable
         self._free = list(range(self.max_slots - 1, -1, -1))
 
@@ -279,13 +343,16 @@ class SlotKVCache:
         s = jnp.int32(slot)
         w = self.window
         for i, layer in self.caches.items():
+            old = layer["k"]
             src = {n: a[:, :w] if a.shape[1] > w else a
                    for n, a in row_caches[i].items()}
-            self.caches[i] = _insert_layer(layer, src,
-                                           _insert_row_pair, s)
+            k, v = _insert_row_pair(old, layer["v"], src["k"],
+                                    src["v"], s)
+            self.caches[i] = {"k": k, "v": v}
+            self.note_swap(old)
 
 
-class PagedKVCache:
+class PagedKVCache(_DonatedState):
     """Block-paged K/V pools + per-slot block tables.
 
     ``block_size`` tokens per block; ``kv_blocks`` — the pool's
@@ -320,6 +387,8 @@ class PagedKVCache:
     gives both.  A prefix of blocks says nothing about such a state,
     so block export/import, the warm gather, int8 pools and a tp mesh
     refuse a chain that has one."""
+
+    STATE = "pools"
 
     def __init__(self, forwards, max_slots, window, block_size=16,
                  kv_blocks=None, kv_dtype="fp32", tp=None):
@@ -369,6 +438,11 @@ class PagedKVCache:
                 if hasattr(u, "init_cache")}
         if not self.pools:
             raise ValueError("chain has no cacheable blocks")
+        _pairs_only(
+            {i: layer for i, layer in self.pools.items()
+             if i not in self.state_units},
+            ("k", "v", "k_scale", "v_scale") if kv_dtype == "int8"
+            else ("k", "v"), "the paged cache")
         #: tensor-parallel serving context (serving/tp.py) — pools
         #: shard HEAD-WISE over the mesh (each chip stores
         #: [num_blocks, block_size, d/tp]; scales replicate), so the
@@ -435,12 +509,18 @@ class PagedKVCache:
 
     def state_bytes(self):
         """{"kv": bytes of the paged pools, "conv": bytes of the
-        per-slot state pools} resident on the devices."""
+        per-slot state pools} resident on the devices.  Metadata
+        alone (``nbytes``), so it answers from any thread, also on a
+        leaf that a step in flight has consumed."""
         out = {"kv": 0, "conv": 0}
         for i, layer in self.pools.items():
             out["conv" if i in self.state_units else "kv"] += sum(
                 a.nbytes for a in layer.values())
         return out
+
+    def reset_pools(self):
+        super().reset_pools()
+        self.moe_counts = None
 
     def _blocks_only(self, what):
         if self.state_units:
@@ -601,27 +681,35 @@ class PagedKVCache:
                 % (f, need))
         ids = jnp.asarray(self.tables[slot, f:need])
         start = jnp.int32(f * self.block_size)
-        for i, layer in self.pools.items():
+        for i in self.pools:
             src = row_caches[i]
             if i in self.state_units:
-                self.pools[i] = _insert_state(layer, src,
-                                              jnp.int32(slot))
+                self._insert_state(i, src, slot)
                 continue
             wk = next(iter(src.values())).shape[1]
             if wk < need * self.block_size:
                 raise ValueError(
                     "staging width %d < %d blocks x %d" %
                     (wk, need, self.block_size))
+            layer = self.pools[i]
+            old = layer["k"]
             if self.kv_dtype == "int8":
                 k, v, sk, sv = _insert_blocks_q8_jit()(
-                    layer["k"], layer["v"], layer["k_scale"],
+                    old, layer["v"], layer["k_scale"],
                     layer["v_scale"], src["k"], src["v"], ids, start)
                 self.pools[i] = {"k": k, "v": v, "k_scale": sk,
                                  "v_scale": sv}
             else:
-                self.pools[i] = _insert_layer(layer, src,
-                                              _insert_blocks,
-                                              ids, start)
+                k, v = _insert_blocks(old, layer["v"], src["k"],
+                                      src["v"], ids, start)
+                self.pools[i] = {"k": k, "v": v}
+            self.note_swap(old)
+
+    def _insert_state(self, i, src, slot):
+        state = self.pools[i]
+        old = next(iter(state.values()))
+        self.pools[i] = _insert_state(state, src, jnp.int32(slot))
+        self.note_swap(old)
 
     def export_blocks(self, ids):
         """Gather blocks ``ids`` RAW out of every layer's pools for a
@@ -640,13 +728,9 @@ class PagedKVCache:
                 k, v = fn(layer["k"], layer["v"], ids)
                 sk, sv = fn(layer["k_scale"], layer["v_scale"], ids)
                 got = {"k": k, "v": v, "k_scale": sk, "v_scale": sv}
-            elif set(layer) == {"k", "v"}:
+            else:
                 k, v = fn(layer["k"], layer["v"], ids)
                 got = {"k": k, "v": v}
-            else:  # exotic cache pytrees: per-name self-pairing
-                got = {}
-                for name in layer:
-                    got[name], _ = fn(layer[name], layer[name], ids)
             out[i] = {n: numpy.asarray(a) for n, a in got.items()}
         return out
 
@@ -669,31 +753,22 @@ class PagedKVCache:
                     "imported layer %s blocks %s do not fit %d x "
                     "block_size %d" % (i, ref.shape[:2], n,
                                        self.block_size))
+            if self.kv_dtype == "int8" and "k_scale" not in src:
+                raise ValueError(
+                    "int8 import needs k_scale/v_scale riding "
+                    "the exported blocks")
+            old = layer["k"]
+            k, v = fn(old, layer["v"], jnp.asarray(src["k"]),
+                      jnp.asarray(src["v"]), ids_j)
+            # swapped at once: a refused scale pair must not leave
+            # the consumed K and V behind as the cache's
+            layer = self.pools[i] = dict(layer, k=k, v=v)
             if self.kv_dtype == "int8":
-                if "k_scale" not in src:
-                    raise ValueError(
-                        "int8 import needs k_scale/v_scale riding "
-                        "the exported blocks")
-                k, v = fn(layer["k"], layer["v"],
-                          jnp.asarray(src["k"]), jnp.asarray(src["v"]),
-                          ids_j)
                 sk, sv = fn(layer["k_scale"], layer["v_scale"],
                             jnp.asarray(src["k_scale"]),
                             jnp.asarray(src["v_scale"]), ids_j)
-                self.pools[i] = {"k": k, "v": v, "k_scale": sk,
-                                 "v_scale": sv}
-            elif set(layer) == {"k", "v"}:
-                k, v = fn(layer["k"], layer["v"],
-                          jnp.asarray(src["k"]), jnp.asarray(src["v"]),
-                          ids_j)
-                self.pools[i] = {"k": k, "v": v}
-            else:
-                got = {}
-                for name in layer:
-                    got[name], _ = fn(layer[name], layer[name],
-                                      jnp.asarray(src[name]),
-                                      jnp.asarray(src[name]), ids_j)
-                self.pools[i] = got
+                self.pools[i] = dict(layer, k_scale=sk, v_scale=sv)
+            self.note_swap(old)
 
     def load_staging(self, row_caches, ids):
         """Copy resident blocks ``ids`` (a matched prompt prefix)
@@ -719,15 +794,6 @@ class PagedKVCache:
         out = {}
         for i, layer in self.pools.items():
             src = row_caches[i]
-            if set(layer) == {"k", "v"}:
-                k, v = fn(layer["k"], layer["v"], src["k"], src["v"],
-                          ids)
-                out[i] = {"k": k, "v": v}
-            else:  # exotic cache pytrees: per-name, pairing each
-                # tensor with itself (same fallback as _insert_layer)
-                got = {}
-                for name in src:
-                    got[name], _ = fn(layer[name], layer[name],
-                                      src[name], src[name], ids)
-                out[i] = got
+            k, v = fn(layer["k"], layer["v"], src["k"], src["v"], ids)
+            out[i] = {"k": k, "v": v}
         return out

@@ -345,6 +345,13 @@ def _registry_series():
             "veles_serving_moe_hottest_rows_total",
             "live rows on the most loaded expert, summed over routed "
             "layers and decode steps"),
+        "pool_copies": metrics.counter(
+            "veles_serving_pool_copies_total",
+            "calls that return the cache's device state (decode and "
+            "verify steps, inserts, imports) whose donated input came "
+            "back alive: the program copied the pools instead of "
+            "writing in place (note_swap in serving/kv_slots.py; "
+            "expected 0)"),
         "weight_leaves_cast": metrics.counter(
             "veles_serving_weight_leaves_cast_total",
             "parameter leaves a server holds in the compute dtype "
@@ -924,6 +931,7 @@ class ServingMetrics:
         self._t0 = time.monotonic()
         self._global = _registry_series()
         self._loop = _loop_series()
+        self._pool_copies_seen = 0
         #: replica-side SLO accounting (TTFT + e2e vs the per-class
         #: objectives under root.common.slo.*)
         self.slo = SLOTracker("serving")
@@ -1255,11 +1263,19 @@ class ServingMetrics:
         self._global["weight_leaves_cast"].inc(int(leaves_cast))
 
     def record_loop_pass(self, seconds, steps, steps_after_prefill,
-                         step_after_prefill_seconds, passes=1):
+                         step_after_prefill_seconds, passes=1,
+                         pool_copies=None):
         """The scheduler loop's phase account since its last flush
         (``scheduler._LoopPhases.drain()``): ``seconds`` by phase.
         The loop calls this once a pass, so the account costs the
-        registry one visit a pass however many phases ran."""
+        registry one visit a pass however many phases ran.
+        ``pool_copies``: the cache's running count (the warm-up's
+        included); what it grew by since the last pass is counted."""
+        if pool_copies is not None \
+                and pool_copies != self._pool_copies_seen:
+            self._global["pool_copies"].inc(
+                pool_copies - self._pool_copies_seen)
+            self._pool_copies_seen = pool_copies
         for phase, took in seconds.items():
             if took > 0:
                 self._loop[phase].inc(took)

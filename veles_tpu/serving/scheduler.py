@@ -1449,6 +1449,7 @@ class InferenceScheduler(Logger):
             fut.set_exception(
                 e if isinstance(e, SchedulerError)
                 else SchedulerError(repr(e)))
+            self._recover_pools(cache, e)
             return
         try:
             fut.set_result(out)
@@ -1582,6 +1583,11 @@ class InferenceScheduler(Logger):
         out["weights_dtype"] = \
             weights.dtype if weights is not None else None
         cache = self.cache_
+        # did every call that returns the cache's device state write
+        # it in place (``note_swap``, serving/kv_slots.py)?  None before the
+        # first such call (the warm-up's first step, where it runs)
+        out["pools_in_place"] = cache.pool_copies == 0 \
+            if cache is not None and cache.pool_swaps else None
         if self.kv == "paged":
             out["kv_dtype"] = self.kv_dtype
             out["kv_bytes_per_token"] = \
@@ -1863,7 +1869,8 @@ class InferenceScheduler(Logger):
         try:
             while self._pass(cache):
                 with phases("observe"):
-                    self.stats.record_loop_pass(*phases.drain())
+                    self.stats.record_loop_pass(
+                        *phases.drain(), pool_copies=cache.pool_copies)
         finally:
             # what the last pass and the wait before close() took
             phases.close()
@@ -1933,12 +1940,16 @@ class InferenceScheduler(Logger):
         # jax work OUTSIDE the lock: submit() must never block on
         # a device step
         faults.fire("serving.scheduler.loop")
+        # the except paths below recover at once; this catches a call
+        # that failed where none could (a promotion, under the lock)
+        self._recover_pools(cache)
         self._reap(cache)
         self._do_preempts(cache)
         with phases("observe"):
             self._sync_kv_gauges(cache)
         for req in admits:
-            self._begin_admit(req, cache)
+            if req.slot is not None:   # else: failed by a recovery
+                self._begin_admit(req, cache)
             with self._lock:
                 self._admitting.remove(req)
         if self._aux:
@@ -2474,6 +2485,7 @@ class InferenceScheduler(Logger):
                 cache.insert(req.slot, row_caches, len(req.pf_seq))
         except Exception as e:
             self._retire(req, cache, error=e)
+            self._recover_pools(cache, e)
             return
         if req.export_only:
             # prefill-role terminus: the blocks now hold the whole
@@ -2527,6 +2539,7 @@ class InferenceScheduler(Logger):
             cache.import_blocks(ids, imp["layers"])
         except Exception as e:
             self._retire(req, cache, error=e)
+            self._recover_pools(cache, e)
             return
         if self._tron:
             with self._phases("observe"):
@@ -2620,11 +2633,51 @@ class InferenceScheduler(Logger):
             active = dict(self._active)
         if not active:
             return
-        faults.fire("serving.scheduler.step")
-        if self.kv == "paged":
-            self._step_paged(cache, active)
-        else:
-            self._step_dense(cache, active)
+        try:
+            faults.fire("serving.scheduler.step")
+            if self.kv == "paged":
+                self._step_paged(cache, active)
+            else:
+                self._step_dense(cache, active)
+        except Exception as e:
+            # every active request rode the batch, so each is failed
+            # with the error; the loop lives on for the next request
+            self.exception("decode step failed: %r", e)
+            if not self._recover_pools(cache, e):
+                for req in active.values():
+                    if req.slot is not None:   # not retired in the step
+                        self._retire(req, cache, error=e)
+
+    def _recover_pools(self, cache, error=None):
+        """The other half of donating the cache's device state
+        (serving/kv_slots.py): a call that raised BEFORE dispatch has
+        consumed nothing, but one that failed after consuming its
+        input leaves deleted leaves behind, and no later call may
+        carry on with them.  If any leaf is deleted: fail every
+        request that holds a slot (its rows are gone), forget every
+        resident prefix, zero the pools.  True when it did."""
+        if not cache.pools_lost():
+            return False
+        err = SchedulerError(
+            "the KV pools were lost to a call that failed after "
+            "consuming them (%r): request failed, pools zeroed"
+            % (error,))
+        with self._lock:
+            victims = list(self._active.values()) \
+                + self._prefilling + self._admitting
+            self._prefilling = []
+        victims = [r for r in victims if r.slot is not None]
+        # zeroed before anyone is told: a client that sees its request
+        # fail may send the next one at once
+        cache.reset_pools()
+        for req in victims:
+            self._retire(req, cache, error=err)
+        pfx = self.prefix_   # loop-owned, like the cache
+        if pfx is not None:
+            cache.reclaim(pfx.clear())
+            self._sync_prefix_gauges()
+        self.warning("%s; %d request(s) failed", err, len(victims))
+        return True
 
     def _emit(self, req, tok):
         """Accept one token: append to the request's stream AND push
