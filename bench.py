@@ -796,9 +796,10 @@ def bench_serving_sweep(dev):
       time-to-first-token of short probes submitted BEHIND long
       prompts, chunked prefill on vs off (the Sarathi win: the long
       prefill no longer monopolizes the loop);
-    - ``serving_max_streams_paged`` vs ``_dense`` — concurrent
-      streams actually decoding for the SAME KV HBM budget
-      (block-proportional vs window-per-slot admission).
+    - ``serving_max_streams_paged`` — concurrent streams actually
+      decoding in a KV HBM budget that window-sized rows would
+      spend on ``max_slots // 2`` requests (block-proportional
+      admission).
 
     Sized down hard on CPU so driver runs stay fast."""
     from veles_tpu.serving import InferenceScheduler
@@ -822,7 +823,7 @@ def bench_serving_sweep(dev):
     # -- occupancy sweep: decode throughput at 1/25/50/100% ----------
     sch = InferenceScheduler(
         fw, max_slots=max_slots, window=window, max_queue=4 * max_slots,
-        queue_timeout=600.0, kv="paged", block_size=block,
+        queue_timeout=600.0, block_size=block,
         prefill_chunk=0).start()
     try:
         sch.submit(short, steps).result(600)   # prefill-width warmup
@@ -843,7 +844,7 @@ def bench_serving_sweep(dev):
     def ttft_p95(chunk):
         sch = InferenceScheduler(
             fw, max_slots=4, window=window, max_queue=64,
-            queue_timeout=600.0, kv="paged", block_size=block,
+            queue_timeout=600.0, block_size=block,
             prefill_chunk=chunk).start()
         try:
             # warm both prefill shapes out of the timed region
@@ -876,12 +877,10 @@ def bench_serving_sweep(dev):
     out["serving_prefill_chunks"] = chunks
     out["serving_prefill_chunk_tokens"] = chunk
 
-    # -- admission capacity for the SAME KV HBM budget ---------------
-    # dense reserves window tokens per slot: budget = dense_slots x
-    # window tokens.  paged spends the same budget in blocks, so
+    # -- admission capacity of a fixed KV HBM budget -----------------
+    # the budget of max_slots // 2 window-sized rows, spent in blocks:
     # short streams pack block-proportionally.
-    dense_slots = max_slots // 2
-    budget_blocks = dense_slots * (window // block)
+    budget_blocks = (max_slots // 2) * (window // block)
     per_req = -(-(p_short + steps) // block)
     paged_cap = min(4 * max_slots, budget_blocks // per_req)
 
@@ -903,10 +902,8 @@ def bench_serving_sweep(dev):
         finally:
             sch.close()
 
-    out["serving_max_streams_dense"] = peak_streams(
-        kv="dense", max_slots=dense_slots)
     out["serving_max_streams_paged"] = peak_streams(
-        kv="paged", max_slots=paged_cap, block_size=block,
+        max_slots=paged_cap, block_size=block,
         kv_blocks=budget_blocks)
     out["serving_sweep_config"] = {
         "d_model": d_model, "layers": layers, "heads": heads,
@@ -1027,7 +1024,7 @@ def bench_spec(dev):
     def decode_tps(spec, slots):
         sch = InferenceScheduler(
             fw, max_slots=slots, window=window,
-            max_queue=4 * slots, queue_timeout=600.0, kv="paged",
+            max_queue=4 * slots, queue_timeout=600.0,
             block_size=block, prefill_chunk=0, spec=spec,
             spec_k=spec_k).start()
         try:
@@ -1084,7 +1081,7 @@ def bench_spec(dev):
             kw.update(drafter="model", draft_head=head)
         sch = InferenceScheduler(
             hfw, max_slots=1, window=window, max_queue=4,
-            queue_timeout=600.0, kv="paged", block_size=block,
+            queue_timeout=600.0, block_size=block,
             prefill_chunk=0, spec=spec, spec_k=spec_k, **kw).start()
         try:
             sch.submit(hprompt, steps, seed=0).result(600)  # warmup
@@ -1121,7 +1118,7 @@ def bench_spec(dev):
     other = rng.integers(0, vocab, (p_len,)).tolist()
     sch = InferenceScheduler(
         pfw, max_slots=4, window=pwindow, max_queue=64,
-        queue_timeout=600.0, kv="paged", block_size=block,
+        queue_timeout=600.0, block_size=block,
         prefill_chunk=block * 2, prefix_cache=True).start()
     try:
         # pre-warm BOTH paths' executables on an unrelated prompt so
@@ -1159,7 +1156,7 @@ def bench_spec(dev):
         cap = 4 * per_req if prefix else 4
         sch = InferenceScheduler(
             fw, max_slots=min(64, pool), window=window,
-            max_queue=256, queue_timeout=600.0, kv="paged",
+            max_queue=256, queue_timeout=600.0,
             block_size=block, kv_blocks=pool,
             prefill_chunk=block * 2, prefix_cache=prefix,
             shed_block_factor=0,    # the queue IS the experiment
@@ -1248,7 +1245,7 @@ def bench_kv_quant(dev):
         sch = InferenceScheduler(
             fw, max_slots=min(64, max(cap, 1)), window=window,
             max_queue=4 * max(cap, 1), queue_timeout=600.0,
-            kv="paged", block_size=block, kv_blocks=kv_blocks,
+            block_size=block, kv_blocks=kv_blocks,
             kv_dtype=kv_dtype, prefill_chunk=0, spec=False,
             prefix_cache=False, shed_block_factor=0,
             warm_buckets=False).start()
@@ -1289,7 +1286,7 @@ def bench_kv_quant(dev):
     def decode_tps(spec, kv_dtype):
         sch = InferenceScheduler(
             fw, max_slots=4, window=window, max_queue=16,
-            queue_timeout=600.0, kv="paged", block_size=block,
+            queue_timeout=600.0, block_size=block,
             kv_dtype=kv_dtype, prefill_chunk=0, spec=spec,
             spec_k=spec_k, prefix_cache=False,
             warm_buckets=False).start()
@@ -1454,7 +1451,7 @@ def bench_tp(dev):
                 if hasattr(u, "quantize_weights"):
                     u.quantize_weights()
         sch = InferenceScheduler(
-            fw, max_slots=2, window=window, kv="paged",
+            fw, max_slots=2, window=window,
             block_size=block, kv_blocks=kv_blocks, prefill_chunk=0,
             spec=False, prefix_cache=False, warm_buckets=False,
             tp=tp).start()
@@ -1525,7 +1522,7 @@ def bench_tp(dev):
 
     def decode_tps(tp):
         sch = InferenceScheduler(
-            fw, max_slots=slots, window=window, kv="paged",
+            fw, max_slots=slots, window=window,
             block_size=block, prefill_chunk=0, spec=False,
             prefix_cache=False, warm_buckets=False, tp=tp).start()
         assert sch.tp == tp
@@ -1578,7 +1575,7 @@ def bench_tp(dev):
 
     def ttft_colocated():
         sch = InferenceScheduler(
-            fw, max_slots=4, window=window, kv="paged",
+            fw, max_slots=4, window=window,
             block_size=block, prefill_chunk=chunk, spec=False,
             prefix_cache=False, warm_buckets=False).start()
         try:
@@ -1599,7 +1596,7 @@ def bench_tp(dev):
             sch.close()
 
     def ttft_disagg():
-        kw = dict(max_slots=4, window=window, kv="paged",
+        kw = dict(max_slots=4, window=window,
                   block_size=block, prefill_chunk=chunk, spec=False,
                   prefix_cache=False, warm_buckets=False)
         pre = InferenceScheduler(fw, role="prefill", **kw).start()
@@ -1840,7 +1837,7 @@ def bench_streaming(dev):
 
     sch = InferenceScheduler(fw, max_slots=4, window=window,
                              max_queue=64, queue_timeout=600.0,
-                             kv="paged", block_size=block,
+                             block_size=block,
                              warm_buckets=False).start()
     try:
         sch.submit(prompt, steps).result(600)   # compile + settle
@@ -2855,8 +2852,7 @@ def main():
         "decode_tokens_per_sec", "decode_kv_speedup",
         "serving_ttft_ms", "serving_concurrent_tokens_per_sec",
         "serving_slot_occupancy", "serving_ttft_p95_ms_mixed",
-        "serving_ttft_p95_ms_oneshot", "serving_max_streams_dense",
-        "serving_max_streams_paged",
+        "serving_ttft_p95_ms_oneshot", "serving_max_streams_paged",
         "spec_decode_tokens_per_sec",
         "spec_off_decode_tokens_per_sec", "spec_speedup_batch1",
         "spec_speedup_heldout", "spec_speedup_heldout_ngram",
@@ -3168,7 +3164,7 @@ def bench_tiered_kv(dev):
     for u in fw:
         u.initialize(device=dev)
     sch = InferenceScheduler(fw, max_slots=2, window=window,
-                             kv="paged", block_size=4, kv_blocks=40,
+                             block_size=4, kv_blocks=40,
                              prefill_chunk=16, prefix_cache=True,
                              warm_buckets=False,
                              kv_host_bytes=64 << 20,

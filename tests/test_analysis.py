@@ -209,10 +209,10 @@ rebound = track_jit("m.rebound", rebound)
 
 
 def test_t204_missing_stable_registration(tmp_path):
-    src = "def apply_step_slots():\n    pass\n"
+    src = "def paged_decode_step():\n    pass\n"
     f = [x for x in scan(tmp_path, {"serving/engine.py": src})
          if x.code == "T204"]
-    assert f and any(x.detail == "serving.slot_step" for x in f)
+    assert f and any(x.detail == "serving.paged_step" for x in f)
 
 
 # -- L-series ----------------------------------------------------------------

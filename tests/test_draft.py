@@ -121,9 +121,9 @@ def test_model_drafter_parity(f32, spec_trained_chain,
                              seed=31 + i))
                 for i, p in enumerate(prompts)]
     for chunk in (8,):
-        base, _ = _run_sched(fw, submits, kv="paged", block_size=4,
+        base, _ = _run_sched(fw, submits, block_size=4,
                              prefill_chunk=chunk, spec=False)
-        mod, snap = _run_sched(fw, submits, kv="paged",
+        mod, snap = _run_sched(fw, submits,
                                block_size=4, prefill_chunk=chunk,
                                spec=True, spec_k=4, drafter="model",
                                draft_head=head, check=True)
@@ -151,7 +151,7 @@ def test_model_drafter_preempt_resume_parity(f32,
 
     def run(preempt):
         sch = InferenceScheduler(fw, max_slots=2, window=64,
-                                 kv="paged", block_size=4,
+                                 block_size=4,
                                  prefill_chunk=4, spec=True,
                                  spec_k=4, drafter="model",
                                  draft_head=head,
@@ -218,9 +218,9 @@ def test_adaptive_k_shrinks_under_bad_drafts(f32,
     fw, pattern = spec_trained_chain
     garbage = MedusaDraftHead.from_chain(fw, 4, seed=3)
     submits = [((pattern * 2)[:10], 14, dict(seed=0))]
-    base, _ = _run_sched(fw, submits, kv="paged", block_size=4,
+    base, _ = _run_sched(fw, submits, block_size=4,
                          prefill_chunk=0, spec=False)
-    mod, snap = _run_sched(fw, submits, kv="paged", block_size=4,
+    mod, snap = _run_sched(fw, submits, block_size=4,
                            prefill_chunk=0, spec=True, spec_k=4,
                            drafter="model", draft_head=garbage)
     assert mod == base
@@ -237,7 +237,7 @@ def test_model_drafter_requires_head(f32, spec_trained_chain):
     drafter name is rejected loudly."""
     fw, pattern = spec_trained_chain
     submits = [((pattern * 2)[:8], 8, dict(seed=0))]
-    outs, snap = _run_sched(fw, submits, kv="paged", block_size=4,
+    outs, snap = _run_sched(fw, submits, block_size=4,
                             prefill_chunk=0, spec=True, spec_k=4,
                             drafter="model")
     assert len(outs[0]) == 16

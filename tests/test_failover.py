@@ -96,7 +96,7 @@ def test_resume_tokens_parity_greedy_and_seeded(
     back clean."""
     from veles_tpu.serving import InferenceScheduler
     fw, pattern = spec_trained_chain
-    sch = InferenceScheduler(fw, max_slots=2, window=64, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=2, window=64,
                              block_size=4, prefill_chunk=4,
                              spec=spec, warm_buckets=False).start()
     try:
@@ -128,7 +128,7 @@ def test_resume_tokens_int8_quant_noise_contract(
     read dequantized keys — the PR 12 preempt→resume contract)."""
     from veles_tpu.serving import InferenceScheduler
     fw, pattern = spec_trained_chain
-    sch = InferenceScheduler(fw, max_slots=2, window=64, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=2, window=64,
                              block_size=4, prefill_chunk=4,
                              kv_dtype="int8",
                              warm_buckets=False).start()
@@ -156,7 +156,7 @@ def test_export_ttl_gc_and_double_fetch_409(
     from veles_tpu.serving import InferenceScheduler
     from veles_tpu.serving import scheduler as sched_mod
     fw, pattern = spec_trained_chain
-    sch = InferenceScheduler(fw, max_slots=2, window=64, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=2, window=64,
                              block_size=4, prefill_chunk=4,
                              role="prefill",
                              warm_buckets=False).start()

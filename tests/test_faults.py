@@ -59,8 +59,6 @@ def _clean(sch):
     leaked, double-owned, or stuck.  Blocks the radix prefix cache
     holds (ON by default since PR 10) are RESIDENT, not leaked — the
     sweep verifies every block is exactly one of free/resident."""
-    if sch.kv != "paged":
-        return
     cache = sch.cache_
     resident = sch.prefix_.resident if sch.prefix_ is not None else 0
     sch.check_kv()
@@ -162,7 +160,7 @@ def test_deadline_expiry_frees_all_blocks(f32):
     from veles_tpu.serving import (
         DeadlineExceededError, InferenceScheduler)
     fw = _tiny_fw("fault-deadline", window=256)
-    sch = InferenceScheduler(fw, max_slots=1, window=256, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=1, window=256,
                              block_size=4, prefill_chunk=0).start()
     try:
         # slow every decode step so the 0.3s deadline lands mid-decode
@@ -191,7 +189,7 @@ def test_cancel_frees_blocks(f32):
     from veles_tpu.serving import (
         InferenceScheduler, RequestCancelledError)
     fw = _tiny_fw("fault-cancel", window=256)
-    sch = InferenceScheduler(fw, max_slots=1, window=256, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=1, window=256,
                              block_size=4, prefill_chunk=0).start()
     try:
         # pace the decode so the request is still mid-flight when the
@@ -221,7 +219,7 @@ def test_close_with_inflight_frees_blocks(f32):
     queued) must return every block; check() passes afterward."""
     from veles_tpu.serving import InferenceScheduler, SchedulerError
     fw = _tiny_fw("fault-close", window=256)
-    sch = InferenceScheduler(fw, max_slots=2, window=256, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=2, window=256,
                              block_size=4, prefill_chunk=0).start()
     # pace the decode so both requests are still mid-flight at
     # close(), however warm the caches are (spec decoding — ON by
@@ -253,7 +251,7 @@ def test_preempt_resume_token_parity(f32):
 
     def run(preempt):
         sch = InferenceScheduler(fw, max_slots=2, window=64,
-                                 kv="paged", block_size=4,
+                                 block_size=4,
                                  prefill_chunk=4).start()
         try:
             futs = [sch.submit(p, 24, **kw) for p, kw in prompts]
@@ -317,7 +315,7 @@ def test_block_pressure_shed(f32):
     shed_block_factor x pool — before the client would 408 anyway."""
     from veles_tpu.serving import InferenceScheduler, QueueFullError
     fw = _tiny_fw("fault-shed", window=64)
-    sch = InferenceScheduler(fw, max_slots=1, window=64, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=1, window=64,
                              block_size=4, kv_blocks=8, max_queue=32,
                              prefill_chunk=0,
                              shed_block_factor=1.0).start()
@@ -349,11 +347,11 @@ def test_watchdog_recovers_from_injected_hang(f32):
     # compile inside the watchdog scheduler's first iteration would
     # itself exceed the 0.3s threshold and trip a false stall
     warm_sch = InferenceScheduler(fw, max_slots=2, window=256,
-                                  kv="paged", block_size=4,
+                                  block_size=4,
                                   prefill_chunk=0).start()
     assert len(warm_sch.submit([9, 8], 2).result(60)) == 4
     warm_sch.close()
-    sch = InferenceScheduler(fw, max_slots=2, window=256, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=2, window=256,
                              block_size=4, prefill_chunk=0,
                              watchdog=0.3).start()
     try:
@@ -393,7 +391,7 @@ def test_mixed_fault_soak_no_block_leak(f32):
     from veles_tpu.serving import (
         InferenceScheduler, QueueFullError, SchedulerError)
     fw = _tiny_fw("fault-soak", window=64)
-    sch = InferenceScheduler(fw, max_slots=2, window=64, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=2, window=64,
                              block_size=4, kv_blocks=16, max_queue=4,
                              prefill_chunk=4, watchdog=30.0).start()
     try:

@@ -49,9 +49,8 @@ def test_serving_jit_entry_points_registered():
     assert not missing, "\n".join(missing)
     # the registry itself must still cover the serving surface
     covered = {name for _, name in REQUIRED_REGISTRATIONS}
-    assert {"serving.slot_step", "serving.paged_step",
+    assert {"serving.paged_step", "serving.verify_step",
             "serving.prefill", "serving.prefill_chunk",
-            "serving.kv_insert_row",
             "serving.kv_insert_blocks"} <= covered
 
 

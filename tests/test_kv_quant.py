@@ -284,7 +284,7 @@ def test_int8_stream_agreement_and_determinism(f32):
 
     def run(kv_dtype):
         sch = InferenceScheduler(fw, max_slots=2, window=64,
-                                 kv="paged", block_size=4,
+                                 block_size=4,
                                  kv_dtype=kv_dtype, prefill_chunk=4,
                                  spec=True, spec_k=2,
                                  warm_buckets=False).start()
@@ -327,7 +327,7 @@ def test_int8_preempt_resume_agreement(f32):
 
     def run(preempt):
         sch = InferenceScheduler(fw, max_slots=2, window=64,
-                                 kv="paged", block_size=4,
+                                 block_size=4,
                                  kv_dtype="int8", prefill_chunk=4,
                                  spec=True, spec_k=4,
                                  warm_buckets=False).start()
@@ -367,11 +367,21 @@ def test_int8_warm_radix_resubmit_parity(f32):
     cold run wrote, and the cold tail attends over their dequantized
     staging — the values every decode step reads through the
     dequant-fused gather."""
+    from veles_tpu import prng
     from veles_tpu.serving import InferenceScheduler
+    # the weights from a seed of the test's own: the matched blocks
+    # are REUSED and so exact, but the cold tail's rows are recomputed
+    # over dequantized keys where the cold run's prefill read float32
+    # staging, so a deeper layer's tail rows agree within quantization
+    # noise only, and whether a near-tie flips a token depends on the
+    # weights: drawn from the process's stream they depended on which
+    # tests the worker had run before (a failure after
+    # test_prng_ops.py, on the parent's tree too)
+    prng.get().seed(6)
     fw = _tiny_fw("kvq-warm")
     rng = numpy.random.default_rng(6)
     prompt = rng.integers(0, 12, (24,)).tolist()
-    sch = InferenceScheduler(fw, max_slots=2, window=64, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=2, window=64,
                              block_size=4, kv_dtype="int8",
                              prefill_chunk=4, prefix_cache=True,
                              spec=True, spec_k=2,
@@ -396,7 +406,7 @@ def test_int8_check_kv_clean_under_churn(f32):
     fw = _tiny_fw("kvq-churn")
     rng = numpy.random.default_rng(7)
     warm_p = rng.integers(0, 12, (16,)).tolist()
-    sch = InferenceScheduler(fw, max_slots=3, window=48, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=3, window=48,
                              block_size=4, kv_blocks=24,
                              kv_dtype="int8", prefill_chunk=8,
                              prefix_cache=True, spec=True, spec_k=2,
@@ -444,7 +454,7 @@ def test_fused_verify_scheduler_stream_parity(f32, fused_verify):
 
     def run(spec):
         sch = InferenceScheduler(fw, max_slots=2, window=64,
-                                 kv="paged", block_size=4,
+                                 block_size=4,
                                  prefill_chunk=4, spec=spec,
                                  spec_k=3,
                                  warm_buckets=False).start()
@@ -487,8 +497,7 @@ def test_kv_quant_ce_bound_on_trained_chain(f32, spec_trained_chain):
 # -- config / plumbing ---------------------------------------------------------
 
 def test_kv_dtype_validation_and_metrics(f32):
-    """Junk kv_dtype is a loud client error; int8 over the dense
-    cache degrades to fp32 (the documented fallback); the metrics
+    """Junk kv_dtype is a loud client error; the metrics
     snapshot advertises kv_dtype and the measured bytes-per-token
     (int8 strictly under fp32); the config key is declared."""
     from veles_tpu.serving import InferenceScheduler
@@ -496,15 +505,12 @@ def test_kv_dtype_validation_and_metrics(f32):
     with pytest.raises(ValueError):
         InferenceScheduler(fw, max_slots=2, window=64,
                            kv_dtype="int4")
-    dense = InferenceScheduler(fw, max_slots=2, window=64,
-                               kv="dense", kv_dtype="int8")
-    assert dense.kv_dtype == "fp32"
     assert root.common.serving.kv_dtype == "fp32"
     assert root.common.serving.fused_verify is False
     bpt = {}
     for dt in ("fp32", "int8"):
         sch = InferenceScheduler(fw, max_slots=2, window=64,
-                                 kv="paged", block_size=4,
+                                 block_size=4,
                                  kv_dtype=dt, spec=False,
                                  warm_buckets=False).start()
         try:
@@ -531,7 +537,7 @@ def test_int8_decode_weights_complete(f32):
 
     def run():
         sch = InferenceScheduler(fw, max_slots=1, window=64,
-                                 kv="paged", block_size=4,
+                                 block_size=4,
                                  kv_dtype="int8", prefill_chunk=0,
                                  spec=False, prefix_cache=False,
                                  warm_buckets=False).start()

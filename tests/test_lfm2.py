@@ -334,7 +334,6 @@ REFUSALS = {
     "export_import": dict(role="prefill"),
     "import": dict(role="decode"),
     "host_tier": dict(kv_host_bytes=1 << 20),
-    "dense_kv": dict(kv="dense"),
 }
 
 
@@ -376,8 +375,7 @@ def test_same_option_on_the_dense_chain_as_before(option,
            "import": sched.role == "decode",
            # the host tier hangs on the prefix cache, as before
            "host_tier": sched.kv_host_bytes == (1 << 20)
-           or not sched.prefix_cache,
-           "dense_kv": sched.kv == "dense"}
+           or not sched.prefix_cache}
     assert got[option]
 
 

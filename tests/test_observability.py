@@ -528,7 +528,7 @@ def test_alerting_engine_ticks_beside_the_loop_and_goodput_gauges(f32):
     from veles_tpu.telemetry import metrics
     fw = _tiny_fw("alerts-overhead")
     prompt = [3, 1, 4, 3, 1, 4]
-    sch = InferenceScheduler(fw, max_slots=2, window=64, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=2, window=64,
                              block_size=4, prefill_chunk=4,
                              warm_buckets=False,
                              replica_id="obs-soak").start()

@@ -2,8 +2,8 @@
 returns it anew (``serving/kv_slots.py``, ``serving/engine.py``): after
 each such call every leaf that went in is deleted and nothing was
 copied (``pool_copies`` / ``veles_serving_pool_copies_total`` stay 0),
-for compute-dtype and int8 pools, one chip and a tp=2 mesh, the dense
-slot cache and the LFM2 chain's conv state; the metadata readers still
+for compute-dtype and int8 pools, one chip and a tp=2 mesh, and the
+LFM2 chain's conv state; the metadata readers still
 answer on consumed leaves; a step that fails after consuming its input
 fails the in-flight requests and the next request is served from
 re-zeroed pools."""
@@ -74,8 +74,7 @@ def _consumed(cache, before, swaps):
     cache holds live leaves of the same shapes."""
     assert all(a.is_deleted() for a in before)
     assert cache.pool_copies == 0 and cache.pool_swaps == swaps
-    after = _leaves(cache.pools if hasattr(cache, "pools")
-                    else cache.caches)
+    after = _leaves(cache.pools)
     assert not any(a.is_deleted() for a in after)
     assert [a.shape for a in after] == [a.shape for a in before]
 
@@ -199,28 +198,6 @@ def test_fused_verify_reads_the_pool_before_it_writes_it(chain, f32):
         numpy.testing.assert_array_equal(a, b)
 
 
-@pytest.mark.parametrize("call", ["slot_decode_step", "insert"])
-def test_dense_slot_cache_is_donated_too(chain, f32, call):
-    from veles_tpu.serving.engine import slot_decode_step
-    from veles_tpu.serving.kv_slots import SlotKVCache
-    from veles_tpu.serving.prefill import prefill
-    cache = SlotKVCache(chain, max_slots=2, window=WINDOW)
-    before = _leaves(cache.caches)
-    if call == "insert":
-        rows, _ = prefill(chain, numpy.full((1, 8), 2, numpy.int32),
-                          prompt_lens=[6], window=8)
-        cache.insert(cache.alloc(), rows)
-        swaps = len(cache.caches)
-    else:
-        z = numpy.zeros((2,), numpy.int32)
-        nxt = slot_decode_step(
-            chain, cache, numpy.zeros((2, 1), numpy.int32), z,
-            z.astype(numpy.float32), z, z.astype(numpy.uint32), z)
-        assert numpy.asarray(nxt).shape == (2,)
-        swaps = 1
-    _consumed(cache, before, swaps)
-
-
 @pytest.mark.parametrize("call", ["paged_decode_step", "insert"])
 def test_lfm2_kv_pools_and_conv_state_are_donated(f32, call):
     """The LFM2 chain: paged K/V pools and per-slot conv state go in
@@ -295,12 +272,14 @@ def test_reset_pools_zeroes_lost_leaves_in_place_of_them(chain, f32):
     assert all(a.is_deleted() for a in after)
 
 
-def test_copies_counter_counts_a_leaf_that_came_back_alive():
-    from veles_tpu.serving.kv_slots import _DonatedState
+def test_copies_counter_counts_a_leaf_that_came_back_alive(chain):
+    from veles_tpu.serving.kv_slots import PagedKVCache
     from veles_tpu.serving.metrics import ServingMetrics
 
     total = _copies_total
-    cache, alive, gone = _DonatedState(), jnp.zeros((2,)), jnp.zeros((2,))
+    cache = PagedKVCache(chain, max_slots=1, window=WINDOW,
+                         block_size=BLOCK)
+    alive, gone = jnp.zeros((2,)), jnp.zeros((2,))
     gone.delete()
     cache.note_swap(gone)
     assert (cache.pool_swaps, cache.pool_copies) == (1, 0)
@@ -320,7 +299,7 @@ def test_a_second_holder_of_a_pool_is_refused_by_the_layout(chain, f32):
     """Trap 1: no program is handed one array as both halves of a
     pair; a cache layout that is not the K/V pair is refused in words
     where the cache is built."""
-    from veles_tpu.serving.kv_slots import PagedKVCache, SlotKVCache
+    from veles_tpu.serving.kv_slots import PagedKVCache
 
     class Odd:
         def init_cache(self, batch, max_len, dtype):
@@ -331,20 +310,17 @@ def test_a_second_holder_of_a_pool_is_refused_by_the_layout(chain, f32):
 
     with pytest.raises(ValueError, match="one donated pair"):
         PagedKVCache([Odd()], max_slots=1, window=8, block_size=4)
-    with pytest.raises(ValueError, match="one donated pair"):
-        SlotKVCache([Odd()], max_slots=1, window=8)
 
 
 # -- through the scheduler ----------------------------------------------------
 
 SCHEDULERS = {
-    "paged": dict(kv="paged", spec=False),
-    "paged-int8": dict(kv="paged", kv_dtype="int8", spec=False),
-    "paged-spec": dict(kv="paged", spec=True, spec_k=3),
-    "paged-tp2": dict(kv="paged", tp=2, spec=False),
-    "paged-tp2-int8-spec": dict(kv="paged", tp=2, kv_dtype="int8",
+    "paged": dict(spec=False),
+    "paged-int8": dict(kv_dtype="int8", spec=False),
+    "paged-spec": dict(spec=True, spec_k=3),
+    "paged-tp2": dict(tp=2, spec=False),
+    "paged-tp2-int8-spec": dict(tp=2, kv_dtype="int8",
                                 spec=True, spec_k=3),
-    "dense": dict(kv="dense", spec=False),
 }
 
 
@@ -354,9 +330,7 @@ def test_scheduler_never_copies_its_pools(chain, f32, name):
     prefix, speculation's verify, a preempt-free mix): every
     state-returning call wrote in place, said three ways."""
     from veles_tpu.serving import InferenceScheduler
-    kw = dict(SCHEDULERS[name])
-    if kw["kv"] == "paged":
-        kw.update(block_size=BLOCK, prefill_chunk=4)
+    kw = dict(SCHEDULERS[name], block_size=BLOCK, prefill_chunk=4)
     was = _copies_total()
     sch = InferenceScheduler(chain, max_slots=2, window=WINDOW,
                              warm_buckets=False, **kw).start()
@@ -397,7 +371,7 @@ def test_step_that_consumed_its_input_and_failed(chain, f32,
     the tokens of a fresh server."""
     from veles_tpu.serving import InferenceScheduler, SchedulerError
     from veles_tpu.serving import scheduler as sched_mod
-    kw = dict(kv="paged", block_size=BLOCK, prefill_chunk=4,
+    kw = dict(block_size=BLOCK, prefill_chunk=4,
               spec=False, prefix_cache=prefix_cache)
     prompt = [3, 1, 4, 1, 5, 9, 2, 6, 5]
     want = _reference(chain, prompt, 6, **kw)
@@ -449,7 +423,7 @@ def test_step_that_failed_before_dispatch_keeps_the_pools(chain, f32):
     its requests fail with the error, the pools stay as they are (the
     other slot's prefix cache included) and the loop serves on."""
     from veles_tpu.serving import InferenceScheduler, SchedulerError
-    kw = dict(kv="paged", block_size=BLOCK, prefill_chunk=4,
+    kw = dict(block_size=BLOCK, prefill_chunk=4,
               spec=False, prefix_cache=True)
     prompt = [3, 1, 4, 1, 5, 9, 2, 6, 5]
     want = _reference(chain, prompt, 6, **kw)

@@ -91,7 +91,7 @@ def test_phase_timeline_across_preempt_resume(f32):
     on resume, so tokens keep flowing on the same subscription)."""
     from veles_tpu.serving import InferenceScheduler
     fw = _tiny_fw("reqtrace-preempt")
-    sch = InferenceScheduler(fw, max_slots=2, window=64, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=2, window=64,
                              block_size=4, prefill_chunk=4,
                              warm_buckets=False).start()
     try:
@@ -133,7 +133,7 @@ def test_debug_requests_consistent_with_check_kv(f32):
     ``check_kv()`` passes with the table non-empty."""
     from veles_tpu.serving import InferenceScheduler
     fw = _tiny_fw("reqtrace-debug")
-    sch = InferenceScheduler(fw, max_slots=2, window=64, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=2, window=64,
                              block_size=4, prefill_chunk=4,
                              warm_buckets=False).start()
     try:
@@ -450,7 +450,7 @@ def test_tracing_overhead_is_one_event_a_decode_boundary(f32,
     def build(enabled):
         root.common.reqtrace.enabled = enabled
         return InferenceScheduler(fw, max_slots=2, window=64,
-                                  kv="paged", block_size=4,
+                                  block_size=4,
                                   prefill_chunk=4,
                                   warm_buckets=False).start()
 

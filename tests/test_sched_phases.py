@@ -75,7 +75,7 @@ def _tiny_fw(name, window=64, vocab=12, dim=16, heads=2):
 
 def _scheduler(name, **kwargs):
     return InferenceScheduler(
-        _tiny_fw(name), max_slots=2, window=64, kv="paged",
+        _tiny_fw(name), max_slots=2, window=64,
         block_size=4, prefill_chunk=4, warm_buckets=False, spec=False,
         **kwargs)
 

@@ -1,6 +1,6 @@
 """Continuous-batching serving subsystem (``veles_tpu/serving/``):
 batched/chunked prefill parity, slot-step shapes, the paged KV cache
-(block churn, paged-vs-dense token parity, memory-proportional
+(block churn, token parity with ``generate()``, memory-proportional
 admission), scheduler semantics, admission control, and the REST
 concurrency soak."""
 
@@ -102,35 +102,48 @@ def test_prefill_validates(f32):
 
 # -- per-slot step shape ------------------------------------------------------
 
-def test_slot_step_matches_scalar_step(f32):
-    """apply_step_slots with all rows at the SAME position equals
-    apply_step (the scalar step is the all-pos-equal special case),
-    for both the transformer block and the embedding."""
-    from veles_tpu import dtypes
-    fw = _tiny_fw("slotstep")
+def test_paged_step_matches_scalar_step(f32):
+    """The paged step with all rows at the SAME position equals
+    apply_step at that scalar position (the scalar step over a dense
+    cache is the all-positions-equal special case), for both the
+    transformer block and the embedding — history rows included."""
+    fw = _tiny_fw("pagedstep")
     emb, block = fw[0], fw[1]
     eparams = {n: jnp.asarray(a.map_read().mem)
                for n, a in emb.param_arrays().items()}
     bparams = {n: jnp.asarray(a.map_read().mem)
                for n, a in block.param_arrays().items()}
     toks = jnp.asarray([[3], [7]], jnp.int32)
-    pos = 4
+    pos, bs, d = 5, 4, 16
+    rows = jnp.asarray([pos, pos], jnp.int32)
     x_scalar = emb.apply_step(eparams, toks, pos)
-    x_slots = emb.apply_step_slots(
-        eparams, toks, jnp.asarray([pos, pos], jnp.int32))
+    x_slots = emb.apply_step_slots(eparams, toks, rows)
     numpy.testing.assert_allclose(numpy.asarray(x_scalar),
                                   numpy.asarray(x_slots), atol=1e-6)
-    cache = block.init_cache(2, 10, dtypes.compute_dtype())
+    # the same history twice: dense rows [2, 8, d], and the pool's
+    # blocks 1..4 holding them through tables [[1, 2], [3, 4]]
+    rng = numpy.random.default_rng(5)
+    hist = {part: rng.standard_normal((2, 2 * bs, d)).astype(
+        numpy.float32) for part in ("k", "v")}
+    for part in hist:
+        hist[part][:, pos:] = 0.0
+    cache = {part: jnp.asarray(a) for part, a in hist.items()}
+    pool = {part: jnp.concatenate(
+        [jnp.zeros((1, bs, d), jnp.float32),
+         jnp.asarray(a.reshape(4, bs, d))]) for part, a in hist.items()}
+    tables = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
     y_scalar, c_scalar = block.apply_step(bparams, x_scalar, pos,
                                           cache)
-    y_slots, c_slots = block.apply_step_slots(
-        bparams, x_slots, jnp.asarray([pos, pos], jnp.int32), cache)
+    y_paged, p_out = block.apply_step_paged(bparams, x_slots, rows,
+                                            tables, pool)
     numpy.testing.assert_allclose(numpy.asarray(y_scalar),
-                                  numpy.asarray(y_slots), atol=1e-5)
+                                  numpy.asarray(y_paged), atol=1e-5)
     for part in ("k", "v"):
         numpy.testing.assert_allclose(
             numpy.asarray(c_scalar[part]),
-            numpy.asarray(c_slots[part]), atol=1e-6)
+            numpy.asarray(p_out[part])[1:].reshape(2, 2 * bs, d),
+            atol=1e-6)
+        assert not numpy.asarray(p_out[part])[0].any()   # trash block
 
 
 # -- paged KV cache -----------------------------------------------------------
@@ -172,46 +185,164 @@ def test_paged_cache_block_churn(f32):
     b = cache.alloc(28)   # 7 blocks -> 1 of 16 left
     assert a is not None and b is not None
     assert cache.free_blocks == 1 and cache.free_slots == 2
-    assert not cache.can_admit(8)
     assert cache.alloc(8) is None
-    assert cache.can_admit(4) and cache.alloc(4) is not None
+    assert cache.alloc(4) is not None
     cache.check()
 
 
-def test_paged_vs_dense_token_parity(f32):
-    """Acceptance: the paged cache (multi-block tables, packed
-    occupancy buckets) and chunked prefill produce token streams
-    IDENTICAL to the dense slot cache — greedy and seeded sampling,
-    ragged prompts decoding concurrently."""
+def test_insert_hands_its_program_ids_of_its_own(f32, monkeypatch):
+    """The block ids an insert hands to its asynchronous scatter are a
+    copy: ``release`` zeroes the host table row at once, and ids that
+    aliased it (``jnp.asarray`` of a numpy view does on the CPU
+    backend) would send a scatter that has yet to run to the trash
+    block."""
+    from veles_tpu.serving import kv_slots
+    from veles_tpu.serving.prefill import prefill
+    fw = _tiny_fw("insert-ids", window=64)
+    cache = kv_slots.PagedKVCache(fw, max_slots=8, window=64,
+                                  block_size=4)
+    rows, _ = prefill(fw, numpy.full((1, 64), 2, numpy.int32),
+                      prompt_lens=[64], window=64)
+    seen = []
+    inner = kv_slots._insert_blocks
+
+    def spy(pool_k, pool_v, src_k, src_v, ids, start):
+        seen.append(ids.unsafe_buffer_pointer())
+        return inner(pool_k, pool_v, src_k, src_v, ids, start)
+    monkeypatch.setattr(kv_slots, "_insert_blocks", spy)
+    # the table at a 64-byte boundary, where a row's view is aliased
+    raw = numpy.zeros((cache.tables.size + 16,), numpy.int32)
+    off = (-raw.ctypes.data % 64) // 4
+    cache.tables = raw[off:off + cache.tables.size].reshape(
+        cache.tables.shape)
+    for _ in range(8):
+        cache.insert(cache.alloc(64), rows, 64)
+    lo = cache.tables.ctypes.data
+    assert len(seen) == 8
+    assert not [p for p in seen if lo <= p < lo + cache.tables.nbytes]
+
+
+def _reference_sampled(fw, prompt, steps, temperature, top_k, seed):
+    """The scan ``generate(kv_cache=True)`` runs (``_chain_step``:
+    apply_step over a dense cache, one token at a time) with the
+    scheduler's documented key schedule in place of generate()'s own
+    (one split a step, which the scheduler's streams differ from by
+    design): token ``t`` of a request is drawn with
+    ``fold_in(key(seed), t)`` from logits / temperature restricted to
+    the top-k."""
+    import jax
+    from veles_tpu import dtypes
+    from veles_tpu.models.generate import _chain_step
+    params = {i: {n: jnp.asarray(a.map_read().mem)
+                  for n, a in u.param_arrays().items()}
+              for i, u in enumerate(fw)}
+    caches = {i: u.init_cache(1, len(prompt) + steps,
+                              dtypes.compute_dtype())
+              for i, u in enumerate(fw) if hasattr(u, "init_cache")}
+    seq = list(prompt)
+    for pos in range(len(prompt) + steps - 1):
+        logits, caches = _chain_step(
+            fw, params, jnp.asarray([[seq[pos]]], jnp.int32), pos,
+            caches)
+        if pos < len(prompt) - 1:
+            continue
+        z = logits[0, 0].astype(jnp.float32) / temperature
+        z = jnp.where(z < jnp.sort(z)[-top_k], -jnp.inf, z)
+        key = jax.random.fold_in(jax.random.key(seed),
+                                 len(seq) - len(prompt))
+        seq.append(int(jax.random.categorical(key, z)))
+    return seq
+
+
+@pytest.mark.parametrize("sampled", [False, True],
+                         ids=["greedy", "sampled-topk"])
+@pytest.mark.parametrize("prefill_chunk", [0, 2],
+                         ids=["oneshot", "chunked"])
+def test_paged_matches_generate(f32, prefill_chunk, sampled):
+    """Acceptance: the scheduler (multi-block tables, packed occupancy
+    buckets, one-shot or chunked prefill) produces the token streams
+    of the offline reference — ``generate()`` for greedy requests,
+    its scan under the per-request key schedule for seeded sampling
+    with top-k — token for token, ragged prompts decoding
+    concurrently."""
     from veles_tpu.models.generate import generate
     from veles_tpu.serving import InferenceScheduler
     fw = _tiny_fw("paged-parity", blocks=2)
     prompts = [[3, 1, 4], [5], [7, 2, 9, 1], [2, 2], [11, 3, 5]]
-
-    def run(**kw):
-        sch = InferenceScheduler(fw, max_slots=3, window=16,
-                                 **kw).start()
-        try:
+    sch = InferenceScheduler(fw, max_slots=3, window=16, block_size=4,
+                             prefill_chunk=prefill_chunk).start()
+    try:
+        if sampled:
+            futs = [sch.submit(p, 5, temperature=0.9, top_k=5,
+                               seed=13 + i)
+                    for i, p in enumerate(prompts)]
+        else:
             futs = [sch.submit(p, 5, seed=0) for p in prompts]
-            futs += [sch.submit(p, 5, temperature=0.9, top_k=5,
-                                seed=13 + i)
-                     for i, p in enumerate(prompts)]
-            return [f.result(240) for f in futs]
-        finally:
-            sch.close()
-
-    dense = run(kv="dense", prefill_chunk=0)
-    paged = run(kv="paged", block_size=4, prefill_chunk=0)
-    assert paged == dense
-    # chunked prefill on top: chunks of 2 over the same prompts
-    chunked = run(kv="paged", block_size=4, prefill_chunk=2)
-    assert chunked == dense
-    # and the dense path still equals the reference generate()
-    for p, out in zip(prompts, dense):
-        ref = numpy.asarray(generate(
-            fw, numpy.asarray([p], numpy.int32), 5,
-            kv_cache=True))[0].tolist()
+        outs = [f.result(240) for f in futs]
+    finally:
+        sch.close()
+    for i, (p, out) in enumerate(zip(prompts, outs)):
+        if sampled:
+            ref = _reference_sampled(fw, p, 5, 0.9, 5, 13 + i)
+        else:
+            ref = numpy.asarray(generate(
+                fw, numpy.asarray([p], numpy.int32), 5,
+                kv_cache=True))[0].tolist()
         assert out == ref, (p, out, ref)
+
+
+class _Without:
+    """A unit seen without one of its methods."""
+
+    def __init__(self, unit, missing):
+        self._unit, self._missing = unit, missing
+
+    def __getattr__(self, name):
+        if name == self._missing:
+            raise AttributeError(name)
+        return getattr(self._unit, name)
+
+
+@pytest.mark.parametrize("missing", [
+    "apply_step_paged", "apply_prefill", "causal", "init_cache"])
+def test_unservable_chain_is_refused_where_the_scheduler_is_built(
+        f32, missing):
+    """A chain whose cacheable unit lacks the paged decode step (or
+    the batched prefill, or causality, or that has no cacheable unit
+    at all) is refused in words, naming the unit, when the scheduler
+    is built: there is no second cache to fall back to."""
+    from veles_tpu.serving import InferenceScheduler, serving_supported
+    fw = _tiny_fw("refused-" + missing)
+    block = fw[1]
+    if missing == "causal":
+        block.causal = False
+        chain = fw
+    elif missing == "init_cache":
+        chain = [fw[0], fw[2]]
+    else:
+        chain = [fw[0], _Without(block, missing), fw[2]]
+    want = {"causal": "not causal",
+            "init_cache": "no cacheable unit"}.get(missing, missing)
+    assert not serving_supported(chain)
+    with pytest.raises(ValueError, match=want) as err:
+        InferenceScheduler(chain, max_slots=2, window=16)
+    if missing != "init_cache":
+        assert block.name in str(err.value)
+
+
+def test_the_kv_option_is_gone(f32):
+    """Which KV cache is no decision any more: the scheduler and the
+    REST unit refuse the argument outright and the configuration has
+    no such key."""
+    from veles_tpu.restful_api import RESTfulAPI
+    from veles_tpu.serving import InferenceScheduler
+    fw = _tiny_fw("no-kv-option")
+    with pytest.raises(TypeError, match="kv"):
+        InferenceScheduler(fw, max_slots=2, window=16, kv="dense")
+    with pytest.raises(TypeError, match="serving_kv"):
+        RESTfulAPI(None, forwards=fw, serving_kv="dense")
+    assert "kv" not in root.common.serving
+    assert not hasattr(fw[1], "apply_step_slots")
 
 
 def test_paged_memory_admission(f32):
@@ -222,7 +353,7 @@ def test_paged_memory_admission(f32):
     from veles_tpu.serving import InferenceScheduler
     fw = _tiny_fw("paged-mem", window=16)
     sch = InferenceScheduler(fw, max_slots=4, window=16,
-                             kv="paged", block_size=4, kv_blocks=3,
+                             block_size=4, kv_blocks=3,
                              prefill_chunk=0).start()
     try:
         with pytest.raises(ValueError, match="kv_blocks"):
@@ -285,7 +416,7 @@ def test_chunked_prefill_interleaves_decode(f32):
     from veles_tpu.models.generate import generate
     from veles_tpu.serving import InferenceScheduler
     fw = _tiny_fw("chunked-mix", window=64)
-    sch = InferenceScheduler(fw, max_slots=2, window=64, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=2, window=64,
                              block_size=8, prefill_chunk=8).start()
     try:
         short = sch.submit([4, 2], 30)

@@ -88,8 +88,6 @@ def _calls(unit, rng):
     return {
         "apply_step_paged": lambda p: unit.apply_step_paged(
             p, x[:, :1], pos, tables, pool),
-        "apply_step_slots": lambda p: unit.apply_step_slots(
-            p, x[:, :1], pos, cache),
         "apply_prefill": lambda p: unit.apply_prefill(
             p, x, unit.init_cache(b, WINDOW, cd), lens=lens),
         "apply_prefill_chunk": lambda p: unit.apply_prefill_chunk(
@@ -103,9 +101,8 @@ def _calls(unit, rng):
 STEP_METHODS = [
     (0, "apply"), (0, "apply_chunk"), (0, "apply_step_slots"),
     (0, "apply_verify_slots"), (1, "apply_step_paged"),
-    (1, "apply_step_slots"), (1, "apply_prefill"),
-    (1, "apply_prefill_chunk"), (1, "apply_verify_paged"),
-    (2, "apply")]
+    (1, "apply_prefill"), (1, "apply_prefill_chunk"),
+    (1, "apply_verify_paged"), (2, "apply")]
 
 
 @pytest.mark.parametrize("index,method", STEP_METHODS)

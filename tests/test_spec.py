@@ -117,21 +117,18 @@ def test_spec_token_parity(f32):
     submits += [(p, 10, dict(temperature=0.9, top_k=5, seed=41 + i))
                 for i, p in enumerate(prompts)]
 
-    base, _ = _run_sched(fw, submits, kv="paged", block_size=4,
+    base, _ = _run_sched(fw, submits, block_size=4,
                          prefill_chunk=0, spec=False)
-    spec, snap = _run_sched(fw, submits, kv="paged", block_size=4,
+    spec, snap = _run_sched(fw, submits, block_size=4,
                             prefill_chunk=0, spec=True, spec_k=4,
                             check=True)
     assert spec == base
     assert snap["spec_drafted_tokens"] > 0
     # chunked prefill underneath changes nothing
-    chunked, snap2 = _run_sched(fw, submits, kv="paged",
+    chunked, snap2 = _run_sched(fw, submits,
                                 block_size=4, prefill_chunk=4,
                                 spec=True, spec_k=4, check=True)
     assert chunked == base
-    # the dense fallback path is untouched by the spec knobs
-    dense, _ = _run_sched(fw, submits, kv="dense", prefill_chunk=0)
-    assert dense == base
 
 
 def test_spec_accept_rate_on_repetitive_prompts(f32,
@@ -145,9 +142,9 @@ def test_spec_accept_rate_on_repetitive_prompts(f32,
     fw, pattern = spec_trained_chain
     prompts = [(pattern * 3)[:18], [2, 9] * 9, [3] * 12]
     submits = [(p, 16, dict(seed=0)) for p in prompts]
-    base, _ = _run_sched(fw, submits, kv="paged", block_size=4,
+    base, _ = _run_sched(fw, submits, block_size=4,
                          prefill_chunk=0, spec=False)
-    spec, snap = _run_sched(fw, submits, kv="paged", block_size=4,
+    spec, snap = _run_sched(fw, submits, block_size=4,
                             prefill_chunk=0, spec=True, spec_k=4,
                             check=True)
     assert spec == base
@@ -173,7 +170,7 @@ def test_spec_preempt_resume_parity(f32):
     def run(preempt):
         from veles_tpu.serving import InferenceScheduler
         sch = InferenceScheduler(fw, max_slots=2, window=64,
-                                 kv="paged", block_size=4,
+                                 block_size=4,
                                  prefill_chunk=4, spec=True,
                                  spec_k=4,
                                  warm_buckets=False).start()
@@ -275,7 +272,7 @@ def test_prefix_warm_resubmit_parity(f32):
     rng = numpy.random.default_rng(0)
     prompt = rng.integers(0, 12, (24,)).tolist()
 
-    sch = InferenceScheduler(fw, max_slots=2, window=64, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=2, window=64,
                              block_size=4, prefill_chunk=8,
                              prefix_cache=False,
                              warm_buckets=False).start()
@@ -284,7 +281,7 @@ def test_prefix_warm_resubmit_parity(f32):
     finally:
         sch.close()
 
-    sch = InferenceScheduler(fw, max_slots=2, window=64, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=2, window=64,
                              block_size=4, prefill_chunk=8,
                              prefix_cache=True).start()
     try:
@@ -327,7 +324,7 @@ def test_prefix_admission_counts_cold_blocks_only(f32):
     # needs 7 blocks; after it completes it donates its written full
     # blocks — floor((28-1)/4) = 6 resident — and a warm twin matches
     # floor((22-1)/4) = 5 of them, needing only 7 - 5 = 2 new blocks
-    sch = InferenceScheduler(fw, max_slots=2, window=32, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=2, window=32,
                              block_size=4, kv_blocks=9,
                              prefill_chunk=8, prefix_cache=True,
                              prefix_evict=False).start()
@@ -356,7 +353,7 @@ def test_prefix_eviction_under_pressure(f32):
     fw = _tiny_fw("pfx-evict")
     a = [1, 2, 3] * 6                  # 18 tokens
     b = [9, 8, 7] * 6
-    sch = InferenceScheduler(fw, max_slots=2, window=32, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=2, window=32,
                              block_size=4, kv_blocks=7,
                              prefill_chunk=8,
                              prefix_cache=True).start()
@@ -382,7 +379,7 @@ def test_prefix_mixed_soak_with_faults(f32):
     fw = _tiny_fw("pfx-soak")
     rng = numpy.random.default_rng(3)
     warm_p = rng.integers(0, 12, (16,)).tolist()
-    sch = InferenceScheduler(fw, max_slots=3, window=48, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=3, window=48,
                              block_size=4, kv_blocks=24,
                              prefill_chunk=8, prefix_cache=True,
                              spec=True, spec_k=2, warm_buckets=False,

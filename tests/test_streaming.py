@@ -61,7 +61,7 @@ def test_stream_vs_batch_bit_parity(f32):
                                      seed=41))]
     for spec in (False, True):
         sch = InferenceScheduler(fw, max_slots=2, window=64,
-                                 kv="paged", block_size=4,
+                                 block_size=4,
                                  prefill_chunk=4, spec=spec,
                                  warm_buckets=False).start()
         try:
@@ -93,7 +93,7 @@ def test_stream_cancel_frees_blocks(f32):
     from veles_tpu.serving import (
         InferenceScheduler, RequestCancelledError)
     fw = _tiny_fw("stream-cancel")
-    sch = InferenceScheduler(fw, max_slots=2, window=64, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=2, window=64,
                              block_size=4, prefill_chunk=4,
                              warm_buckets=False).start()
     try:
@@ -134,7 +134,7 @@ def test_mixed_priority_soak(f32):
     cache defaults (the soak that gates the default flip)."""
     from veles_tpu.serving import InferenceScheduler
     fw = _tiny_fw("qos-soak")
-    sch = InferenceScheduler(fw, max_slots=2, window=64, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=2, window=64,
                              block_size=4, prefill_chunk=4,
                              warm_buckets=False).start()
     try:
@@ -180,7 +180,7 @@ def test_class_aware_shedding(f32):
     full queue seats a high arrival by evicting a queued low."""
     from veles_tpu.serving import InferenceScheduler, QueueFullError
     fw = _tiny_fw("qos-shed", window=256)
-    sch = InferenceScheduler(fw, max_slots=1, window=256, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=1, window=256,
                              block_size=4, kv_blocks=16,
                              prefill_chunk=0, shed_block_factor=1.0,
                              max_queue=8, warm_buckets=False,
@@ -203,7 +203,7 @@ def test_class_aware_shedding(f32):
         # depth-cap seat eviction: fill the queue with lows, then a
         # high arrival takes the youngest low's seat (503 on the low)
         sch2 = InferenceScheduler(fw, max_slots=1, window=256,
-                                  kv="paged", block_size=4,
+                                  block_size=4,
                                   prefill_chunk=0, max_queue=2,
                                   warm_buckets=False, spec=False,
                                   prefix_cache=False).start()

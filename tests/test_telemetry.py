@@ -247,32 +247,37 @@ def test_trace_export_cli(tmp_path, capsys):
 
 def test_track_jit_counts_compiles():
     import jax
+    from jax.experimental.compilation_cache import compilation_cache
     # pin the persistent compilation cache OFF for this test: an
-    # earlier test (any scheduler soak) may have enabled the on-disk
-    # cache, and a cache populated by a previous run would label
-    # these compiles "hit" instead of "cold".
-    prev = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", None)
+    # earlier test of the same worker (any scheduler start) may have
+    # placed the on-disk cache, and a cache populated by a previous
+    # run would label these compiles "hit" instead of "cold".  The
+    # cache pins itself at the first compile, so the switch alone
+    # does nothing to one that is already in use: reset it too.
+    name = "test.track_jit_counts_compiles"
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    calls = metrics.counter(
+        "veles_jit_calls_total", labelnames=("fn",)).labels(name)
+    compiles = metrics.counter(
+        "veles_jit_compiles_total",
+        labelnames=("fn", "cache")).labels(name, "cold")
+    hist = metrics.histogram(
+        "veles_jit_compile_seconds", labelnames=("fn",)).labels(name)
+    base_calls, base_cold, base_count = \
+        calls.value, compiles.value, hist.count
     try:
-        calls = metrics.counter(
-            "veles_jit_calls_total",
-            labelnames=("fn",)).labels("test.tracked")
-        base_calls = calls.value
-        f = track_jit("test.tracked", jax.jit(lambda x: x * 2))
+        f = track_jit(name, jax.jit(lambda x: x * 2))
         assert int(f(numpy.int32(2))) == 4
         assert int(f(numpy.int32(3))) == 6        # cache hit
         assert float(f(numpy.float32(2.0))) == 4.0  # new dtype
     finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
-    compiles = metrics.counter(
-        "veles_jit_compiles_total",
-        labelnames=("fn", "cache")).labels("test.tracked", "cold")
-    assert compiles.value == 2  # cache pinned off -> all cold
-    assert calls.value - base_calls == 3
-    hist = metrics.histogram(
-        "veles_jit_compile_seconds",
-        labelnames=("fn",)).labels("test.tracked")
-    assert hist.count == 2
+        jax.config.update("jax_enable_compilation_cache", prev)
+        compilation_cache.reset_cache()
+    assert compiles.value == base_cold + 2  # cache off -> all cold
+    assert calls.value == base_calls + 3
+    assert hist.count == base_count + 2
     # the proxy stays transparent
     assert f._cache_size() >= 2
 

@@ -197,7 +197,7 @@ def test_export_byte_cap_counts_expiries(f32, spec_trained_chain):
     expiry series; the survivor stays fetchable."""
     from veles_tpu.serving import InferenceScheduler
     fw, _ = spec_trained_chain
-    sch = InferenceScheduler(fw, max_slots=2, window=64, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=2, window=64,
                              block_size=4, prefill_chunk=8,
                              prefix_cache=False, spec=False,
                              warm_buckets=False,
@@ -245,7 +245,7 @@ def test_host_promoted_parity(f32, spec_trained_chain, spec):
     rng = numpy.random.default_rng(19)
     pa = rng.integers(0, 12, (16,)).tolist()
     pb = rng.integers(0, 12, (16,)).tolist()
-    sch = InferenceScheduler(fw, max_slots=2, window=64, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=2, window=64,
                              block_size=4, kv_blocks=28,
                              prefill_chunk=8, prefix_cache=True,
                              spec=spec, spec_k=2, warm_buckets=False,
@@ -277,7 +277,7 @@ def test_host_promoted_parity_int8(f32, spec_trained_chain):
     fw, _ = spec_trained_chain
     rng = numpy.random.default_rng(23)
     pa = rng.integers(0, 12, (16,)).tolist()
-    sch = InferenceScheduler(fw, max_slots=2, window=64, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=2, window=64,
                              block_size=4, kv_blocks=28,
                              kv_dtype="int8", prefill_chunk=8,
                              prefix_cache=True, spec=False,
@@ -303,7 +303,7 @@ def test_check_kv_clean_under_churn_with_promote_faults(
     fw, _ = spec_trained_chain
     rng = numpy.random.default_rng(29)
     warm_p = rng.integers(0, 12, (16,)).tolist()
-    sch = InferenceScheduler(fw, max_slots=3, window=64, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=3, window=64,
                              block_size=4, kv_blocks=28,
                              prefill_chunk=8, prefix_cache=True,
                              spec=True, spec_k=2, warm_buckets=False,

@@ -91,10 +91,6 @@ def test_tp_specs_and_gate(f32, spec_trained_chain):
     sch = InferenceScheduler(fw, max_slots=2, window=64, tp=3,
                              warm_buckets=False)
     assert sch.tp == 0 and sch.tp_ is None   # fallback, not a crash
-    # the dense cache cannot shard head-wise — same fallback
-    dense = InferenceScheduler(fw, max_slots=2, window=64, tp=2,
-                               kv="dense", warm_buckets=False)
-    assert dense.tp == 0
     # more shards than devices is NOT a degrade: the caller sized the
     # model for 1/tp of it per chip
     with pytest.raises(ValueError, match="tp=16 needs 16 devices"):
@@ -115,7 +111,7 @@ def test_tp2_stream_parity(f32, spec_trained_chain):
     submits = [(p, 10, dict(seed=0)) for p in prompts]
     submits += [(p, 8, dict(temperature=0.9, top_k=5, seed=41 + i))
                 for i, p in enumerate(prompts)]
-    kw = dict(kv="paged", block_size=4, prefill_chunk=4, spec=True,
+    kw = dict(block_size=4, prefill_chunk=4, spec=True,
               spec_k=3)
     base, snap1 = _run(fw, submits, check=True, tp=0, **kw)
     tp2, snap2 = _run(fw, submits, check=True, tp=2, **kw)
@@ -143,11 +139,11 @@ def test_tp2_overlap_parity_with_model_drafter(f32,
     submits = [(p, 10, dict(seed=0)) for p in prompts]
     submits += [(p, 8, dict(temperature=0.9, top_k=5, seed=41 + i))
                 for i, p in enumerate(prompts)]
-    base, _ = _run(fw, submits, check=True, tp=0, kv="paged",
+    base, _ = _run(fw, submits, check=True, tp=0,
                    block_size=4, prefill_chunk=4, spec=False)
     cfg.common.serving.tp_overlap = True
     try:
-        tp2, snap = _run(fw, submits, check=True, tp=2, kv="paged",
+        tp2, snap = _run(fw, submits, check=True, tp=2,
                          block_size=4, prefill_chunk=4, spec=True,
                          spec_k=4, drafter="model", draft_head=head)
     finally:
@@ -167,7 +163,7 @@ def test_tp2_int8_parity(f32, spec_trained_chain):
     submits = [((pattern * 2)[:10], 10, dict(seed=0)),
                ([5, 2] * 4, 8, dict(temperature=0.8, top_k=4,
                                     seed=9))]
-    kw = dict(kv="paged", block_size=4, prefill_chunk=4,
+    kw = dict(block_size=4, prefill_chunk=4,
               kv_dtype="int8", spec=False, max_slots=2)
     base, snap1 = _run(fw, submits, check=True, tp=0, **kw)
     tp2, snap2 = _run(fw, submits, check=True, tp=2, **kw)
@@ -188,7 +184,7 @@ def test_tp2_preempt_resume_parity(f32, spec_trained_chain):
 
     def run(preempt):
         sch = InferenceScheduler(fw, max_slots=2, window=64,
-                                 kv="paged", block_size=4,
+                                 block_size=4,
                                  prefill_chunk=4, tp=2,
                                  warm_buckets=False).start()
         try:
@@ -222,7 +218,7 @@ def test_tp_serves_wider_model_at_fixed_chip_budget(f32):
                                    per_chip_bytes)
     fw = _tiny_fw("tp-wide", window=32, vocab=16, dim=64, heads=4,
                   blocks=2, seed=77)
-    kw = dict(max_slots=2, window=32, kv="paged", block_size=8,
+    kw = dict(max_slots=2, window=32, block_size=8,
               kv_blocks=8, prefill_chunk=0, spec=False,
               prefix_cache=False)
 
@@ -264,7 +260,7 @@ def test_disagg_handoff_parity(f32, spec_trained_chain, kv_dtype):
                                    RoleMismatchError, decode_export,
                                    encode_export)
     fw, pattern = spec_trained_chain
-    kw = dict(max_slots=2, window=64, kv="paged", block_size=4,
+    kw = dict(max_slots=2, window=64, block_size=4,
               prefill_chunk=4, kv_dtype=kv_dtype,
               warm_buckets=False)
     colo = InferenceScheduler(fw, **kw).start()

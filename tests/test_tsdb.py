@@ -401,7 +401,7 @@ def test_tenant_usage_rollup_equals_scheduler_counters(f32):
     baseline = {fam: _usage_counter_values(fam)
                 for fam in _USAGE_FAMILIES}
     sch = InferenceScheduler(_tiny_fw("tsdb-meter"), max_slots=2,
-                             window=64, kv="paged", block_size=4,
+                             window=64, block_size=4,
                              warm_buckets=False,
                              replica_id="meter-r0").start()
     try:
@@ -622,7 +622,7 @@ def test_tsdb_overhead_under_5_percent(f32, spec_trained_chain):
     from veles_tpu.serving import InferenceScheduler
     fw, pattern = spec_trained_chain
     prompt = [p % 12 for p in pattern]
-    sch = InferenceScheduler(fw, max_slots=2, window=64, kv="paged",
+    sch = InferenceScheduler(fw, max_slots=2, window=64,
                              block_size=4, prefill_chunk=4,
                              warm_buckets=False,
                              replica_id="tsdb-soak").start()

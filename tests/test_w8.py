@@ -97,7 +97,7 @@ def test_w8_spec_parity(f32, w8_chain):
 
     def run(**kw):
         sch = InferenceScheduler(fw, max_slots=3, window=64,
-                                 warm_buckets=False, kv="paged",
+                                 warm_buckets=False,
                                  block_size=4, prefill_chunk=0,
                                  **kw).start()
         try:

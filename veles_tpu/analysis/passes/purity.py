@@ -30,7 +30,6 @@ from veles_tpu.analysis.core import (
 #: silently escape cost accounting (formerly
 #: test_jit_guard.SERVING_ENTRY_POINTS)
 REQUIRED_REGISTRATIONS = (
-    ("serving/engine.py", "serving.slot_step"),
     ("serving/engine.py", "serving.paged_step"),
     ("serving/engine.py", "serving.verify_step"),
     ("serving/engine.py", "serving.sample_first"),
@@ -40,7 +39,6 @@ REQUIRED_REGISTRATIONS = (
     ("serving/prefill.py", "serving.prefill"),
     ("serving/prefill.py", "serving.prefill_chunk"),
     ("serving/openai_api.py", "serving.embed_pool"),
-    ("serving/kv_slots.py", "serving.kv_insert_row"),
     ("serving/kv_slots.py", "serving.kv_insert_blocks"),
     ("serving/kv_slots.py", "serving.kv_gather_blocks"),
     ("serving/kv_slots.py", "serving.kv_quant_insert_blocks"),

@@ -289,12 +289,13 @@ root.common.update({
                    "high": 30000.0},
     },
     # continuous-batching serving knobs (serving/scheduler.py):
-    # kv "paged"|"dense"; kv_blocks None derives the dense-equivalent
-    # pool (max_slots * ceil(window / block_size)); prefill_chunk 0
-    # disables chunked prefill; request_timeout is the whole-request
-    # deadline in seconds (queued + decoding; 0 disables); watchdog is
-    # the stuck-decode-loop detector threshold in seconds (0 disables
-    # — keep it far above the worst first-compile stall);
+    # kv_blocks None derives the pool that holds max_slots requests
+    # of full length (max_slots * ceil(window / block_size));
+    # prefill_chunk 0 disables chunked prefill; request_timeout is
+    # the whole-request deadline in seconds (queued + decoding; 0
+    # disables); watchdog is the stuck-decode-loop detector threshold
+    # in seconds (0 disables — keep it far above the worst
+    # first-compile stall);
     # shed_block_factor sheds new submits (503) once the queue's
     # committed block budget exceeds factor x kv_blocks (0 disables);
     # spec enables speculative decoding (n-gram prompt-lookup drafts
@@ -330,7 +331,6 @@ root.common.update({
     "serving": {
         "tp": 0,
         "role": "both",
-        "kv": "paged",
         "block_size": 16,
         "kv_blocks": None,
         "kv_dtype": "fp32",

@@ -491,10 +491,11 @@ class TransformerBlock(ForwardBase):
         [batch, 1, d] with row n at sequence index ``pos[n]``, reading
         and writing through ``tables`` [batch, T] physical block ids
         (serving/kv_slots.PagedKVCache).  Row-for-row the same math as
-        :meth:`apply_step_slots` restricted to the gathered blocks —
-        greedy token parity with the dense slot cache is tested.  An
-        INT8 pool (``k_scale`` beside the buffers) quantizes the new
-        row on the scatter and dequantizes fused into the gather
+        :meth:`apply_step` (its all-positions-equal special case over
+        a dense cache) restricted to the gathered blocks — token
+        parity with ``generate()`` is tested.  An INT8 pool
+        (``k_scale`` beside the buffers) quantizes the new row on the
+        scatter and dequantizes fused into the gather
         (ops/paged_attention.py q8 paths; the pallas kernel on
         accelerator targets)."""
         from veles_tpu.ops.paged_attention import (
@@ -615,37 +616,6 @@ class TransformerBlock(ForwardBase):
                 lens, self.heads)
         return self._attn_tail(params, x, o, w8=w8), \
             {"k": pk, "v": pv}
-
-    def apply_step_slots(self, params, x, pos, cache):
-        """Decode ONE position PER ROW: x [batch, 1, d] where row n
-        sits at ITS OWN sequence index ``pos[n]`` ([batch] ints,
-        traced) — the serving-slot shape: requests at different decode
-        depths share one compiled step.  Row-for-row the same math as
-        :meth:`apply_step` (which is the all-pos-equal special case):
-        K/V written at ``pos[n]``, attention over keys ≤ ``pos[n]``."""
-        from veles_tpu import dtypes
-        cd = dtypes.compute_dtype()
-        b, _, d = x.shape
-        h = self.heads
-        hd = d // h
-        q, k_new, v_new = self._qkv(params, x)
-        rows = jnp.arange(b)
-        ck = cache["k"].at[rows, pos].set(
-            k_new[:, 0].astype(cache["k"].dtype))
-        cv = cache["v"].at[rows, pos].set(
-            v_new[:, 0].astype(cache["v"].dtype))
-        length = ck.shape[1]
-        qh = q.reshape(b, 1, h, hd)
-        kh = ck.astype(cd).reshape(b, length, h, hd)
-        vh = cv.astype(cd).reshape(b, length, h, hd)
-        logits = jnp.einsum("bqhd,bkhd->bhqk", qh, kh) \
-            * (1.0 / jnp.sqrt(hd))
-        mask = (jnp.arange(length)[None, :]
-                <= pos[:, None])[:, None, None, :]
-        logits = jnp.where(mask, logits, -jnp.inf)
-        probs = jax.nn.softmax(logits, axis=-1)
-        return self._attn_out(params, x, probs, vh), \
-            {"k": ck, "v": cv}
 
     def apply_step(self, params, x, pos, cache):
         """Decode ONE position: x [batch, 1, d] at sequence index

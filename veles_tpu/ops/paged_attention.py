@@ -22,11 +22,11 @@ and only ever receive finite projections).  Padding rows of an
 occupancy bucket follow the same convention: an all-zero table writes
 into and reads from the trash block.
 
-The math is row-for-row the dense per-slot step
-(``TransformerBlock.apply_step_slots``) restricted to the gathered
+The math is row-for-row the kv-cached scalar step
+(``TransformerBlock.apply_step``) restricted to the gathered
 key range — same projection dtypes, 1/sqrt(hd) scale and softmax
-conventions — so greedy token streams are identical to the dense slot
-cache (tested in tests/test_serving.py).  The width-K cousin
+conventions — so token streams are identical to ``generate()``'s
+(tested in tests/test_serving.py).  The width-K cousin
 :func:`paged_verify_attention` scores a run of K1 consecutive tokens
 per row in one pass — the speculative-decoding verify step
 (tests/test_spec.py proves spec-on/spec-off token parity).  This jnp formulation lowers
@@ -176,7 +176,7 @@ def paged_decode_attention(q, k_new, v_new, pool_k, pool_v, tables,
 
     Returns ``(pool_k', pool_v', context)`` — the pools with the new
     K/V scattered in, and the attention context [B, 1, d] (same dtype
-    conventions as the dense slot step).
+    conventions as ``TransformerBlock.apply_step``).
 
     ``kv_heads`` (default None: the path above): grouped-query attention — the
     pools hold ``kv_heads`` heads a row (``[blocks, bs, kv_heads·hd]``)

@@ -88,7 +88,7 @@ class RESTfulAPI(Unit):
     def __init__(self, workflow, loader=None, port=0, host="127.0.0.1",
                  request_timeout=30.0, forwards=None, serving=True,
                  max_slots=4, serving_window=None, max_queue=32,
-                 max_steps=None, max_batch=None, serving_kv=None,
+                 max_steps=None, max_batch=None,
                  serving_block_size=None, serving_kv_blocks=None,
                  serving_kv_dtype=None, serving_prefill_chunk=None,
                  serving_spec=None, serving_spec_k=None,
@@ -97,6 +97,9 @@ class RESTfulAPI(Unit):
                  serving_kv_host_bytes=None,
                  serving_kv_export_bytes=None,
                  replica_id=None, **kwargs):
+        for k in kwargs:   # Unit swallows what it does not know
+            if k.startswith("serving_"):
+                raise TypeError("RESTfulAPI has no option %r" % k)
         super(RESTfulAPI, self).__init__(workflow, **kwargs)
         self.loader = loader
         #: fleet identity: every reply carries it as X-Veles-Replica
@@ -122,7 +125,6 @@ class RESTfulAPI(Unit):
         self.max_queue = int(max_queue)
         #: paged-KV / chunked-prefill knobs (None defers to
         #: ``root.common.serving.*`` — see serving/scheduler.py)
-        self.serving_kv = serving_kv
         self.serving_block_size = serving_block_size
         self.serving_kv_blocks = serving_kv_blocks
         #: KV pool storage dtype ("fp32"/"int8"; None defers to
@@ -294,7 +296,6 @@ class RESTfulAPI(Unit):
                     window=self.serving_window,
                     max_queue=self.max_queue,
                     queue_timeout=self.request_timeout,
-                    kv=self.serving_kv,
                     block_size=self.serving_block_size,
                     kv_blocks=self.serving_kv_blocks,
                     kv_dtype=self.serving_kv_dtype,
@@ -310,11 +311,11 @@ class RESTfulAPI(Unit):
                     replica_id=self.replica_id).start()
                 self.info(
                     "serving scheduler: %d slots, window %d, "
-                    "queue cap %d, kv=%s (block %d), prefill "
+                    "queue cap %d, block %d, prefill "
                     "chunk %d, tp=%d, role=%s",
                     self.scheduler_.max_slots,
                     self.scheduler_.window, self.max_queue,
-                    self.scheduler_.kv, self.scheduler_.block_size,
+                    self.scheduler_.block_size,
                     self.scheduler_.prefill_chunk,
                     self.scheduler_.tp, self.scheduler_.role)
             else:

@@ -11,20 +11,18 @@ per-token scan.  Here:
   (:func:`prefill_chunk`) splits long prompts into fixed-size chunks
   the scheduler interleaves with decode steps (Sarathi-style) so a
   joining long prompt cannot stall in-flight streams;
-- :mod:`veles_tpu.serving.kv_slots` — the KV caches: the default
-  block-PAGED cache (:class:`PagedKVCache` — vLLM PagedAttention
-  lineage: per-layer block pools + per-slot block tables, so memory
-  scales with each request's actual length and admission is
-  memory-proportional) and the legacy dense :class:`SlotKVCache`
-  (fixed ``max_slots × window`` rows — the parity baseline);
+- :mod:`veles_tpu.serving.kv_slots` — the KV cache: block-PAGED
+  (:class:`PagedKVCache` — vLLM PagedAttention lineage: per-layer
+  block pools + per-slot block tables, so memory scales with each
+  request's actual length and admission is memory-proportional);
 - :mod:`veles_tpu.serving.engine` — the shared compiled decode
   steps: per-slot positions, per-slot sampler settings, per-request
-  PRNG streams; the paged step packs only the active slots into
+  PRNG streams; the step packs only the active slots into
   power-of-two occupancy buckets and bounds attention by a block
   bucket over the deepest request;
 - :mod:`veles_tpu.serving.scheduler` — the continuous-batching
-  scheduler: requests join free slots (and, paged, claim their block
-  budget) at token boundaries and leave on stop-token/step-limit,
+  scheduler: requests join free slots and claim their block
+  budget at token boundaries and leave on stop-token/step-limit,
   with admission control (queue-depth cap → 503, queue deadline →
   408) and a background decode loop;
 - :mod:`veles_tpu.serving.metrics` — per-request TTFT, tokens/sec,
@@ -81,9 +79,8 @@ per-token scan.  Here:
 
 from veles_tpu.serving.engine import (  # noqa: F401
     hidden_supported, overlap_supported, paged_decode_step,
-    slot_decode_step, verify_step_paged, verify_supported)
-from veles_tpu.serving.kv_slots import (  # noqa: F401
-    PagedKVCache, SlotKVCache, paged_supported)
+    verify_step_paged, verify_supported)
+from veles_tpu.serving.kv_slots import PagedKVCache  # noqa: F401
 from veles_tpu.serving.prefix_cache import (  # noqa: F401
     RadixPrefixCache)
 from veles_tpu.serving.spec import (  # noqa: F401

@@ -8,21 +8,16 @@ drawn with ``fold_in(key(s), t)`` — reproducible per seed no matter
 which slot the request landed in or what traffic it shared the batch
 with).
 
-Two step families:
-
-- :func:`slot_decode_step` — the legacy DENSE path
-  (``apply_step_slots`` over a SlotKVCache).  Always runs the full
-  ``max_slots`` batch; free slots decode garbage rows whose cache
-  rows the next occupant's attention never reads.
-- :func:`paged_decode_step` — the PAGED path (``apply_step_paged``
-  over a PagedKVCache): the scheduler PACKS only the active slots
-  into a power-of-two *occupancy bucket* ``B`` and bounds the
-  attended range by a power-of-two *block bucket* ``T`` over the
-  deepest active slot, so a half-empty batch of shallow requests
-  pays neither full-batch nor full-window compute.  Executables are
-  cached per (chain, B, T) — O(log slots · log window) variants.
-  Sampling is row-wise (per-request keys), so token streams are
-  independent of packing order.
+ONE step family, over the block-paged cache (``apply_step_paged``
+over a PagedKVCache): :func:`paged_decode_step`, and beside it the
+speculative :func:`verify_step_paged`.  The scheduler PACKS only the
+active slots into a power-of-two *occupancy bucket* ``B`` and bounds
+the attended range by a power-of-two *block bucket* ``T`` over the
+deepest active slot, so a half-empty batch of shallow requests pays
+neither full-batch nor full-window compute.  Executables are cached
+per (chain, B, T) — O(log slots · log window) variants.  Sampling is
+row-wise (per-request keys), so token streams are independent of
+packing order.
 """
 
 import functools
@@ -75,27 +70,6 @@ _sample_first_jit = track_jit("serving.sample_first", jax.jit(
     trace_named("serving.sample_first", sample_first)))
 
 
-def _make_step(forwards):
-    cacheable = frozenset(i for i, u in enumerate(forwards)
-                          if hasattr(u, "init_cache"))
-
-    def step(params, toks, pos, temps, topks, seeds, counts, caches):
-        h = toks
-        out = dict(caches)
-        for i, u in enumerate(forwards):
-            if i in cacheable:
-                h, out[i] = u.apply_step_slots(params[i], h, pos,
-                                               caches[i])
-            elif hasattr(u, "apply_step_slots"):
-                h = u.apply_step_slots(params[i], h, pos)
-            else:
-                h = u.apply(params[i], h)
-        logits = h[:, 0].astype(jnp.float32)
-        keys = _fold_keys(seeds, counts)
-        return sample_slots(logits, temps, topks, keys), out
-    return step
-
-
 # Every step below takes the cache's device state (its LAST argument)
 # DONATED and returns it anew: the caller swaps the cache's attribute
 # for what came back at once and nothing else holds the old leaves
@@ -103,54 +77,13 @@ def _make_step(forwards):
 # step's rows lands in place instead of in a copy of every pool;
 # ``cache.note_swap`` counts a call whose input came back alive.
 
-@functools.lru_cache(maxsize=16)
-def _step_cached(cache_key, closure):
-    return track_jit("serving.slot_step", jax.jit(
-        trace_named("serving.slot_step", closure.fn),
-        donate_argnums=(7,)))
-
-
 def clear_step_cache():
-    """Drop the compiled slot/paged-step caches (entries pin the
-    chain's units — same lifetime note as
+    """Drop the compiled step caches (entries pin the chain's
+    units — same lifetime note as
     ``generate.clear_decode_caches``)."""
-    _step_cached.cache_clear()
     _paged_step_cached.cache_clear()
     _paged_step_tp_cached.cache_clear()
     _verify_step_cached.cache_clear()
-
-
-def slot_decode_step(forwards, cache, toks, pos, temps, topks, seeds,
-                     counts, params=None):
-    """Run ONE decode step over every slot of ``cache``
-    (:class:`serving.kv_slots.SlotKVCache`, updated in place).
-
-    ``toks`` [S, 1] — each slot's last token; ``pos`` [S] — its
-    sequence index (length - 1); ``temps``/``topks`` [S] — per-slot
-    sampler settings; ``seeds``/``counts`` [S] — per-request PRNG
-    stream (seed and draw counter for THIS step's token).  Returns the
-    [S] next tokens (device array — callers ``numpy.asarray`` it).
-
-    ``params`` — the chain's device parameters; a server passes its
-    frozen :class:`serving.weights.ServingWeights` pytree, an offline
-    caller none (the units' own float32 buffers)."""
-    from veles_tpu import dtypes
-    if params is None:
-        params = _device_params(forwards)
-    cache_key = (_arch_sig(forwards), cache.max_slots, cache.window,
-                 str(dtypes.compute_dtype()),
-                 str(dtypes.matmul_precision()))
-    fn = _step_cached(cache_key, _StepClosure(_make_step(forwards)))
-    old = cache.first_leaf()
-    nxt, cache.caches = fn(
-        params, jnp.asarray(toks, jnp.int32),
-        jnp.asarray(pos, jnp.int32),
-        jnp.asarray(temps, jnp.float32),
-        jnp.asarray(topks, jnp.int32),
-        jnp.asarray(seeds, jnp.uint32),
-        jnp.asarray(counts, jnp.int32), cache.caches)
-    cache.note_swap(old)
-    return nxt
 
 
 def hidden_supported(forwards):
@@ -326,7 +259,9 @@ def paged_decode_step(forwards, cache, toks, pos, tables, temps,
     (serving/draft.py); the flag keys the executable cache, so
     hidden-on and hidden-off never share a trace.
 
-    ``params`` as in :func:`slot_decode_step`.
+    ``params`` — the chain's device parameters; a server passes its
+    frozen :class:`serving.weights.ServingWeights` pytree, an offline
+    caller none (the units' own float32 buffers).
 
     ``slots`` [B] — the slot of each packed row, -1 for a padding row
     (the default for every row): what a unit with per-slot state
@@ -349,7 +284,7 @@ def paged_decode_step(forwards, cache, toks, pos, tables, temps,
     K/V pool writeback."""
     from veles_tpu import dtypes
     from veles_tpu.config import root
-    ctx = getattr(cache, "tp_", None)
+    ctx = cache.tp_
     if params is None:
         params = _device_params(forwards)
     tables = jnp.asarray(tables, jnp.int32)
@@ -359,11 +294,10 @@ def paged_decode_step(forwards, cache, toks, pos, tables, temps,
     # per-shard body would compute shard-local scales
     overlap = bool(ctx is not None
                    and root.common.serving.get("tp_overlap", False)
-                   and getattr(cache, "kv_dtype", "fp32") == "fp32"
+                   and cache.kv_dtype == "fp32"
                    and overlap_supported(forwards))
     cache_key = (_arch_sig(forwards), b, t, cache.block_size,
-                 cache.capacity_blocks,
-                 getattr(cache, "kv_dtype", "fp32"),
+                 cache.capacity_blocks, cache.kv_dtype,
                  ctx.size if ctx is not None else 1,
                  bool(want_hidden), overlap,
                  str(dtypes.compute_dtype()),
@@ -475,7 +409,7 @@ def verify_step_paged(forwards, cache, toks, pos, lens, tables,
     hidden into the next iteration's model-based draft."""
     from veles_tpu import dtypes
     from veles_tpu.config import root
-    ctx = getattr(cache, "tp_", None)
+    ctx = cache.tp_
     if params is None:
         params = _device_params(forwards)
     tables = jnp.asarray(tables, jnp.int32)
@@ -487,7 +421,7 @@ def verify_step_paged(forwards, cache, toks, pos, lens, tables,
     # trace time) — they must key the executable or a toggle would
     # silently reuse the stale trace; the tp mesh size keys it too
     # (sharded params/pools compile a different SPMD program)
-    kv_dtype = getattr(cache, "kv_dtype", "fp32")
+    kv_dtype = cache.kv_dtype
     fused = bool(root.common.serving.get("fused_verify", False))
     cache_key = (_arch_sig(forwards), b, k1, t, cache.block_size,
                  cache.capacity_blocks, kv_dtype, fused,

@@ -1,4 +1,4 @@
-"""Serving KV caches: block-paged (default) and dense slot rows.
+"""The serving KV cache: block-paged.
 
 :class:`PagedKVCache` — vLLM-lineage PagedAttention layout (Kwon et
 al., SOSP 2023): K/V live in per-layer POOLS of fixed-size blocks
@@ -12,26 +12,21 @@ TRASH block: never allocated, it absorbs the writes of occupancy-
 bucket padding rows and backs the stale tail entries of every table
 (see ops/paged_attention.py for why the garbage is exactly masked).
 
-:class:`SlotKVCache` — the legacy dense layout (one fixed
-``[max_slots, window, d]`` buffer pair per cacheable block, request ↔
-slot row), kept as the parity baseline and the fallback for chains
-without a paged step.
-
-A slot's lifecycle in either cache: **alloc** (a request leaves the
-queue and claims a slot — and, paged, its whole block budget, so
-decode can never die of mid-flight block starvation), **insert** (the
-prefilled batch-1 staging row is copied in — block-scattered or
-row-replaced), **decode** (the shared compiled step writes position
-``len-1`` and attends over ``[0, len)``), **release** (stop-token /
-step-limit frees slot + blocks; no zeroing needed — every attended
-row [0, len) was written by the current occupant).
+A slot's lifecycle: **alloc** (a request leaves the queue and claims
+a slot and its whole block budget, so decode can never die of
+mid-flight block starvation), **insert** (the prefilled batch-1
+staging row is block-scattered in), **decode** (the shared compiled
+step writes position ``len-1`` and attends over ``[0, len)``),
+**release** (stop-token / step-limit frees slot + blocks; no zeroing
+needed — every attended row [0, len) was written by the current
+occupant).
 
 All methods must be called from ONE thread (the scheduler's decode
 loop).
 
 THE DEVICE STATE IS DONATED, ALWAYS.  Every jitted program that takes
-a cache's device state (``pools`` / ``caches``) and returns it anew —
-the decode and verify steps of serving/engine.py, and the inserts and
+the cache's device state (``pools``) and returns it anew — the
+decode and verify steps of serving/engine.py, and the inserts and
 the import below — takes it DONATED, so the scatter lands in place
 instead of in a copy of the whole pool.  The invariant that makes it
 safe, said once: whoever calls such a program owns the ONLY reference
@@ -55,22 +50,6 @@ import jax
 import jax.numpy as jnp
 
 from veles_tpu.telemetry import trace_named, track_jit
-
-
-def _row_pair(dst_k, dst_v, src_k, src_v, slot):
-    # ONE dispatch per layer for the K/V pair (the per-tensor-name
-    # variant paid two); slot rides traced so inserts share the
-    # executable, src may be narrower than the row (decode rewrites
-    # [prompt, len) itself, and rows ≥ len are masked)
-    start = (slot, jnp.int32(0), jnp.int32(0))
-    return (jax.lax.dynamic_update_slice(
-                dst_k, src_k.astype(dst_k.dtype), start),
-            jax.lax.dynamic_update_slice(
-                dst_v, src_v.astype(dst_v.dtype), start))
-
-
-_insert_row_pair = track_jit("serving.kv_insert_row",
-                             jax.jit(_row_pair, donate_argnums=(0, 1)))
 
 
 def _block_pair(pool_k, pool_v, src_k, src_v, ids, start):
@@ -233,132 +212,13 @@ def _pairs_only(state, names, what):
                                       sorted(names)))
 
 
-class _DonatedState:
-    """What both caches share: the account of their state-returning
-    calls and the recovery of a device state lost to a failed one
-    (``STATE`` names the attribute that holds it)."""
-
-    STATE = None
-    #: state-returning calls that came back / that copied
-    pool_swaps = pool_copies = 0
-
-    def first_leaf(self):
-        """The first array of the device state: what a caller hands to
-        :meth:`note_swap` once its call has come back."""
-        return next(iter(next(iter(
-            getattr(self, self.STATE).values())).values()))
-
-    def note_swap(self, old):
-        """Account one state-returning call that came back: ``old`` is
-        the first leaf that went in donated.  Still alive (a host-side
-        flag, no device sync) means the program could not write in
-        place and copied (``veles_serving_pool_copies_total``,
-        expected 0)."""
-        self.pool_swaps += 1
-        if not old.is_deleted():
-            self.pool_copies += 1
-
-    def pools_lost(self):
-        """True when a call that failed after it consumed its donated
-        input left deleted leaves behind."""
-        return any(a.is_deleted() for a in
-                   jax.tree.leaves(getattr(self, self.STATE)))
-
-    def reset_pools(self):
-        """Zero the whole device state: every request and every
-        resident prefix in it is lost (the caller fails and forgets
-        them).  Shape, dtype and sharding still answer on a deleted
-        leaf."""
-        setattr(self, self.STATE, jax.tree.map(
-            lambda a: jnp.zeros(a.shape, a.dtype, device=a.sharding),
-            getattr(self, self.STATE)))
-
-
-def paged_supported(forwards):
-    """True when every cacheable block speaks the paged decode step
-    (``apply_step_paged``) — the scheduler otherwise falls back to the
-    dense slot cache."""
-    has = False
-    for u in forwards:
-        if hasattr(u, "init_cache"):
-            has = True
-            if not hasattr(u, "apply_step_paged"):
-                return False
-    return has
-
-
-class SlotKVCache(_DonatedState):
-    """Per-layer dense slot-major K/V buffers + free-slot
-    bookkeeping (the legacy layout; parity baseline for the paged
-    cache)."""
-
-    STATE = "caches"
-
-    def __init__(self, forwards, max_slots, window):
-        from veles_tpu import dtypes
-        self.max_slots = int(max_slots)
-        self.window = int(window)
-        if self.max_slots < 1 or self.window < 2:
-            raise ValueError("need max_slots >= 1 and window >= 2")
-        self.caches = {
-            i: u.init_cache(self.max_slots, self.window,
-                            dtypes.compute_dtype())
-            for i, u in enumerate(forwards)
-            if hasattr(u, "init_cache")}
-        if not self.caches:
-            raise ValueError("chain has no cacheable blocks")
-        _pairs_only(self.caches, ("k", "v"), "the dense slot cache")
-        # lowest slot first — keeps occupancy dense and debuggable
-        self._free = list(range(self.max_slots - 1, -1, -1))
-
-    @property
-    def free_slots(self):
-        return len(self._free)
-
-    @property
-    def active_slots(self):
-        return self.max_slots - len(self._free)
-
-    def can_admit(self, total_tokens):
-        """A dense slot reserves the full window row regardless of
-        the request's length — a free slot is the only requirement."""
-        return bool(self._free)
-
-    def alloc(self, total_tokens=0):
-        """Claim a free slot index, or None when all are busy."""
-        return self._free.pop() if self._free else None
-
-    def release(self, slot):
-        slot = int(slot)
-        if slot in self._free:
-            raise ValueError("slot %d double-freed" % slot)
-        self._free.append(slot)
-
-    def insert(self, slot, row_caches, length=None):
-        """Adopt a prefilled batch-1 staging row (serving/prefill.py
-        output, width ≤ window) into ``slot``.  Rows the staging
-        didn't cover are stale from the previous occupant — harmless:
-        decode attends only over [0, len) and writes every position
-        ≥ prompt_len itself, so stale K/V is never read."""
-        s = jnp.int32(slot)
-        w = self.window
-        for i, layer in self.caches.items():
-            old = layer["k"]
-            src = {n: a[:, :w] if a.shape[1] > w else a
-                   for n, a in row_caches[i].items()}
-            k, v = _insert_row_pair(old, layer["v"], src["k"],
-                                    src["v"], s)
-            self.caches[i] = {"k": k, "v": v}
-            self.note_swap(old)
-
-
-class PagedKVCache(_DonatedState):
+class PagedKVCache:
     """Block-paged K/V pools + per-slot block tables.
 
     ``block_size`` tokens per block; ``kv_blocks`` — the pool's
-    usable capacity in blocks (default: the dense equivalent,
+    usable capacity in blocks (default:
     ``max_slots · ceil(window / block_size)``, so a default-sized pool
-    admits everything the dense cache would).  ``window`` stays the
+    admits ``max_slots`` requests of full length).  ``window`` stays the
     per-request length bound (the positional-table limit), NOT a
     per-request memory reservation.
 
@@ -383,12 +243,13 @@ class PagedKVCache(_DonatedState):
     :meth:`insert` and by every decode step, never zeroed (an
     admission's staging starts from ``init_cache``'s zeros) and
     forgotten at release.  It costs no block: ``bytes_per_token`` and
-    ``can_admit`` count the paged layers alone, :meth:`state_bytes`
+    admission count the paged layers alone, :meth:`state_bytes`
     gives both.  A prefix of blocks says nothing about such a state,
     so block export/import, the warm gather, int8 pools and a tp mesh
     refuse a chain that has one."""
 
-    STATE = "pools"
+    #: state-returning calls that came back / that copied
+    pool_swaps = pool_copies = 0
 
     def __init__(self, forwards, max_slots, window, block_size=16,
                  kv_blocks=None, kv_dtype="fp32", tp=None):
@@ -474,10 +335,6 @@ class PagedKVCache(_DonatedState):
         return len(self._free_slots)
 
     @property
-    def active_slots(self):
-        return self.max_slots - len(self._free_slots)
-
-    @property
     def free_blocks(self):
         return len(self._free_blocks)
 
@@ -518,8 +375,34 @@ class PagedKVCache(_DonatedState):
                 a.nbytes for a in layer.values())
         return out
 
+    def first_leaf(self):
+        """The first array of the device state: what a caller hands to
+        :meth:`note_swap` once its call has come back."""
+        return next(iter(next(iter(self.pools.values())).values()))
+
+    def note_swap(self, old):
+        """Account one state-returning call that came back: ``old`` is
+        the first leaf that went in donated.  Still alive (a host-side
+        flag, no device sync) means the program could not write in
+        place and copied (``veles_serving_pool_copies_total``,
+        expected 0)."""
+        self.pool_swaps += 1
+        if not old.is_deleted():
+            self.pool_copies += 1
+
+    def pools_lost(self):
+        """True when a call that failed after it consumed its donated
+        input left deleted leaves behind."""
+        return any(a.is_deleted() for a in jax.tree.leaves(self.pools))
+
     def reset_pools(self):
-        super().reset_pools()
+        """Zero the whole device state: every request and every
+        resident prefix in it is lost (the caller fails and forgets
+        them).  Shape, dtype and sharding still answer on a deleted
+        leaf."""
+        self.pools = jax.tree.map(
+            lambda a: jnp.zeros(a.shape, a.dtype, device=a.sharding),
+            self.pools)
         self.moe_counts = None
 
     def _blocks_only(self, what):
@@ -528,14 +411,6 @@ class PagedKVCache(_DonatedState):
 
     def blocks_needed(self, total_tokens):
         return -(-max(int(total_tokens), 1) // self.block_size)
-
-    def can_admit(self, total_tokens):
-        """Memory-proportional admission: a free slot AND enough free
-        blocks for the request's WHOLE budget (prompt + steps — the
-        full reservation up front means decode can never starve for a
-        block mid-flight)."""
-        return bool(self._free_slots) \
-            and self.blocks_needed(total_tokens) <= len(self._free_blocks)
 
     def alloc(self, total_tokens, shared=()):
         """Claim a slot and its full block budget, or None when slots
@@ -679,7 +554,10 @@ class PagedKVCache(_DonatedState):
             raise ValueError(
                 "from_block %d leaves nothing of the %d-block insert"
                 % (f, need))
-        ids = jnp.asarray(self.tables[slot, f:need])
+        # a host COPY: on the CPU backend ``jnp.asarray`` of a numpy
+        # view may alias the host table, which ``release`` zeroes while
+        # the asynchronous scatter has yet to read its ids
+        ids = jnp.asarray(self.tables[slot, f:need].copy())
         start = jnp.int32(f * self.block_size)
         for i in self.pools:
             src = row_caches[i]

@@ -28,26 +28,36 @@ from veles_tpu.serving.kv_slots import slot_state_units
 from veles_tpu.telemetry import trace_named, track_jit
 
 
-def serving_supported(forwards):
-    """True when the chain can serve through the slot scheduler:
-    every cacheable block is causal and speaks the serving step
-    shapes (``apply_prefill`` + a decode step: ``apply_step_paged``
-    or the dense ``apply_step_slots``) AND every other
-    sequence-dependent unit has a per-slot step or is position-wise."""
+def serving_refusal(forwards):
+    """Why the chain cannot serve through the slot scheduler, in
+    words, or None when it can: every cacheable unit is causal and
+    speaks the serving step shapes (``apply_prefill`` +
+    ``apply_step_paged``) AND every other sequence-dependent unit has
+    a per-slot step or is position-wise."""
     has_cache = False
     for u in forwards:
         if hasattr(u, "init_cache"):
             has_cache = True
-            if not u.causal or not hasattr(u, "apply_prefill") \
-                    or not (hasattr(u, "apply_step_slots")
-                            or hasattr(u, "apply_step_paged")):
-                return False
+            if not u.causal:
+                return "cacheable unit %s is not causal" % u.name
+            missing = [m for m in ("apply_prefill", "apply_step_paged")
+                       if not hasattr(u, m)]
+            if missing:
+                return "cacheable unit %s has no %s" % (
+                    u.name, " and no ".join(missing))
         elif getattr(u, "DECODE_POINTWISE", False):
             continue
         elif not hasattr(u, "apply_step") \
                 or not hasattr(u, "apply_step_slots"):
-            return False
-    return has_cache
+            return ("unit %s is neither position-wise nor has a "
+                    "per-slot step (apply_step + apply_step_slots)"
+                    % u.name)
+    return None if has_cache else "the chain has no cacheable unit"
+
+
+def serving_supported(forwards):
+    """True when :func:`serving_refusal` finds nothing to refuse."""
+    return serving_refusal(forwards) is None
 
 
 def serving_window(forwards):

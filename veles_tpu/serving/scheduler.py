@@ -7,12 +7,12 @@ mutation, and a single loop is what lets every in-flight request share
 one compiled step):
 
 1. **admit** — while capacity allows, the oldest queued request
-   claims a slot.  Under the default PAGED KV cache
-   (:class:`serving.kv_slots.PagedKVCache`) admission is
+   claims a slot of the paged KV cache
+   (:class:`serving.kv_slots.PagedKVCache`).  Admission is
    memory-proportional: the request also claims its whole block
    budget (``ceil((prompt + steps) / block_size)`` blocks), so short
    requests pack many more concurrent streams into the same HBM than
-   the dense window-per-slot layout;
+   a window-sized row per slot would;
 2. **prefill** — prompts up to ``prefill_chunk`` prefill in ONE
    compiled pass; longer prompts prefill in ``prefill_chunk``-token
    CHUNKS, at most one chunk per loop iteration, INTERLEAVED with the
@@ -23,12 +23,12 @@ one compiled step):
    is inserted into the cache and the first token samples from the
    final logits (the TTFT edge);
 3. **step** — active slots advance one token through the shared
-   compiled step.  The paged path packs ONLY the active slots into a
+   compiled step.  It packs ONLY the active slots into a
    power-of-two occupancy bucket and bounds attention by a
    power-of-two block bucket over the deepest request
    (:func:`serving.engine.paged_decode_step`), so a half-empty batch
    of shallow requests pays neither full-batch nor full-window
-   compute; the dense fallback runs the fixed full-slot step;
+   compute;
 4. **retire** — a slot that generated its stop token or hit its step
    limit completes its future and frees slot + blocks at the token
    boundary, where the next queued request joins.
@@ -68,7 +68,7 @@ signals ``drained`` — the rolling-restart hook behind ``POST
 :mod:`veles_tpu.faults`) let tier-1 exercise every one of these paths
 deterministically.
 
-Decode speed (both paged-only, off by default): **speculative
+Decode speed (both off by default): **speculative
 decoding** (``spec`` + ``spec_k``) drafts up to k tokens per slot by
 n-gram prompt lookup (:mod:`veles_tpu.serving.spec`) and scores the
 pending token plus all drafts in ONE batched verify pass
@@ -112,9 +112,9 @@ Per-class TTFT/preempt/shed counters ride
 ``veles_serving_class_*``.
 
 Config knobs (``root.common.serving.*``, overridable per scheduler):
-``kv`` ("paged"/"dense"), ``block_size`` (tokens per KV block,
-default 16), ``kv_blocks`` (pool capacity in blocks; default the
-dense-equivalent ``max_slots · ceil(window / block_size)``),
+``block_size`` (tokens per KV block, default 16), ``kv_blocks``
+(pool capacity in blocks; default
+``max_slots · ceil(window / block_size)``),
 ``kv_dtype`` ("fp32" default — the bit-parity baseline — or "int8":
 paged pools stored quantized with per-row scales beside the block
 tables, roughly halving bytes per cached token so the same HBM
@@ -123,9 +123,10 @@ budget decodes ~2x the concurrent streams; quality-gated by
 Under int8 a preempt→resume continues within quantization noise
 rather than bit-identically — the re-prefill computes deeper
 layers from f32 staging attention where the original decode read
-dequantized keys — while warm radix resubmits stay exact because
-matched blocks are REUSED, not recomputed; the fp32 default keeps
-every PR 7 bit-exactness contract),
+dequantized keys — while a warm radix resubmit REUSES its matched
+blocks exactly and recomputes only the cold tail (over dequantized
+keys: the same noise, one block deep); the fp32 default keeps every
+PR 7 bit-exactness contract),
 ``prefill_chunk`` (chunk width in tokens, rounded up to a power of
 two; 0 disables chunking, default 64), ``request_timeout`` /
 ``watchdog`` / ``shed_block_factor`` (lifecycle knobs above; 0
@@ -201,15 +202,14 @@ from veles_tpu.logger import Logger
 from veles_tpu.telemetry import reqtrace
 from veles_tpu.telemetry.spans import annotation
 from veles_tpu.serving.engine import (
-    first_tokens, paged_decode_step, slot_decode_step,
-    verify_step_paged, verify_supported)
+    first_tokens, paged_decode_step, verify_step_paged,
+    verify_supported)
 from veles_tpu.serving.kv_host import HostKVTier
 from veles_tpu.serving.kv_slots import (
-    PagedKVCache, SlotKVCache, paged_supported, slot_state_units,
-    state_refusal)
+    PagedKVCache, slot_state_units, state_refusal)
 from veles_tpu.serving.metrics import ServingMetrics
 from veles_tpu.serving.prefill import (
-    chunked_supported, prefill, prefill_chunk, serving_supported,
+    chunked_supported, prefill, prefill_chunk, serving_refusal,
     serving_window)
 from veles_tpu.serving.prefix_cache import RadixPrefixCache
 from veles_tpu.serving.draft import draft_supported
@@ -502,14 +502,14 @@ class InferenceScheduler(Logger):
     ``queue_timeout`` — default admission deadline in seconds (408
     for requests still queued past it);
     ``prefill_bucket`` — smallest compiled prefill width;
-    ``kv`` / ``block_size`` / ``kv_blocks`` / ``prefill_chunk`` —
+    ``block_size`` / ``kv_blocks`` / ``prefill_chunk`` —
     paged-cache and chunked-prefill knobs (None defers to
     ``root.common.serving.*``; see the module docstring)."""
 
     def __init__(self, forwards, max_slots=4, window=None,
                  max_queue=32, queue_timeout=30.0, prefill_bucket=8,
-                 kv=None, block_size=None, kv_blocks=None,
-                 kv_dtype=None, prefill_chunk=None, warm_buckets=None,
+                 block_size=None, kv_blocks=None, kv_dtype=None,
+                 prefill_chunk=None, warm_buckets=None,
                  request_timeout=None, watchdog=None,
                  shed_block_factor=None, spec=None, spec_k=None,
                  drafter=None, draft_head=None, draft_k_min=None,
@@ -517,11 +517,11 @@ class InferenceScheduler(Logger):
                  tp=None, role=None, replica_id=None,
                  kv_host_bytes=None, kv_export_bytes=None):
         super(InferenceScheduler, self).__init__()
-        if not serving_supported(forwards):
+        refused = serving_refusal(forwards)
+        if refused:
             raise ValueError(
-                "chain cannot serve through the slot scheduler (needs "
-                "causal cacheable blocks with apply_prefill/"
-                "apply_step_slots; see serving_supported)")
+                "chain cannot serve through the slot scheduler: %s "
+                "(see serving.prefill.serving_refusal)" % refused)
         window = window or serving_window(forwards)
         if not window or int(window) < 2:
             raise ValueError(
@@ -533,21 +533,6 @@ class InferenceScheduler(Logger):
         self.max_queue = int(max_queue)
         self.queue_timeout = float(queue_timeout)
         self.prefill_bucket = int(prefill_bucket)
-        kv = kv or _serving_conf("kv", "paged")
-        if kv not in ("paged", "dense"):
-            raise ValueError("kv must be 'paged' or 'dense'")
-        if kv == "paged" and not paged_supported(forwards):
-            self.info("chain has no paged decode step; falling back "
-                      "to the dense slot cache")
-            kv = "dense"
-        paged_only = [u.name for u in forwards
-                      if hasattr(u, "init_cache")
-                      and not hasattr(u, "apply_step_slots")]
-        if kv == "dense" and paged_only:
-            raise ValueError(
-                "kv='dense' is not carried by %s: they have the paged "
-                "decode step alone" % ", ".join(paged_only))
-        self.kv = kv
         #: units that keep ONE fixed state per slot beside the paged
         #: K/V (serving/kv_slots.py).  What follows does not carry
         #: such a state and is refused in words when asked for by
@@ -562,13 +547,12 @@ class InferenceScheduler(Logger):
         if kv_blocks is None:
             kv_blocks = _serving_conf("kv_blocks", None)
         self.kv_blocks = int(
-            kv_blocks or self.max_slots * self.blocks_per_slot) \
-            if self.kv == "paged" else 0
+            kv_blocks or self.max_slots * self.blocks_per_slot)
         #: KV pool storage dtype: "fp32" (compute-dtype pools; the
         #: parity baseline — token streams byte-identical to PR 5-11)
         #: or "int8" (per-row scales beside the block tables, ~half
         #: the bytes per cached token → ~2x streams per HBM budget;
-        #: quality-gated, see serving/kv_quality.py).  Paged only.
+        #: quality-gated, see serving/kv_quality.py).
         asked = kv_dtype
         kv_dtype = kv_dtype or _serving_conf("kv_dtype", "fp32")
         if kv_dtype == "int8" and not self._without_state(
@@ -576,10 +560,6 @@ class InferenceScheduler(Logger):
             kv_dtype = "fp32"
         if kv_dtype not in ("fp32", "int8"):
             raise ValueError("kv_dtype must be 'fp32' or 'int8'")
-        if kv_dtype == "int8" and self.kv != "paged":
-            self.info("kv_dtype='int8' needs the paged cache; "
-                      "falling back to fp32")
-            kv_dtype = "fp32"
         self.kv_dtype = kv_dtype
         chunk = prefill_chunk if prefill_chunk is not None \
             else _serving_conf("prefill_chunk", 64)
@@ -602,7 +582,7 @@ class InferenceScheduler(Logger):
         self.watchdog = float(_serving_conf("watchdog", 300.0)
                               if watchdog is None else watchdog)
         #: shed new submits once the queue's committed block budget
-        #: exceeds factor x kv_blocks (0 disables; paged only)
+        #: exceeds factor x kv_blocks (0 disables)
         self.shed_block_factor = float(
             _serving_conf("shed_block_factor", 4.0)
             if shed_block_factor is None else shed_block_factor)
@@ -610,7 +590,7 @@ class InferenceScheduler(Logger):
         #: tokens per slot by n-gram prompt lookup and score them in
         #: ONE batched verify pass — output streams stay bit-
         #: identical (greedy and per-seed sampling), accepted drafts
-        #: are pure latency win.  Paged-KV only.
+        #: are pure latency win.
         spec = bool(self._without_state(
             "speculative decoding (spec)", spec,
             _serving_conf("spec", False)))
@@ -618,10 +598,9 @@ class InferenceScheduler(Logger):
                           if spec_k is None else spec_k)
         if spec and self.spec_k < 1:
             raise ValueError("spec_k must be >= 1")
-        if spec and (self.kv != "paged"
-                     or not verify_supported(forwards)):
-            self.info("chain/kv mode cannot run the paged verify "
-                      "step; speculative decoding disabled")
+        if spec and not verify_supported(forwards):
+            self.info("chain cannot run the paged verify step; "
+                      "speculative decoding disabled")
             spec = False
         self.spec = spec
         self._proposer = NgramProposer(k=self.spec_k) if spec \
@@ -676,18 +655,17 @@ class InferenceScheduler(Logger):
         self.draft_shrink = float(_serving_conf("draft_shrink", 0.5))
         self.draft_grow = float(_serving_conf("draft_grow", 0.8))
         #: cross-request radix prefix cache (serving/prefix_cache.py)
-        #: — needs the paged cache, chunked prefill for the cold
-        #: tail, and a power-of-two block size (the staging/chunk
-        #: tilings assume it)
+        #: — needs chunked prefill for the cold tail and a
+        #: power-of-two block size (the staging/chunk tilings assume
+        #: it)
         pfx = bool(self._without_state(
             "the radix prefix cache (prefix_cache): a hit hands over "
             "K/V blocks and no state,", prefix_cache,
             _serving_conf("prefix_cache", False)))
-        if pfx and (self.kv != "paged" or not self.prefill_chunk
+        if pfx and (not self.prefill_chunk
                     or self.block_size & (self.block_size - 1)):
-            self.info("prefix cache needs kv='paged', chunked "
-                      "prefill and a power-of-two block size; "
-                      "disabled")
+            self.info("prefix cache needs chunked prefill and a "
+                      "power-of-two block size; disabled")
             pfx = False
         self.prefix_cache = pfx
         self.prefix_evict = bool(
@@ -716,8 +694,8 @@ class InferenceScheduler(Logger):
         #: tensor-parallel mesh size (0 = off): shards the jitted
         #: steps over a {"tp": N} mesh — Megatron weight splits +
         #: head-wise paged pools, per-chip kv_blocks HBM / N
-        #: (serving/tp.py; module docstring).  Needs the paged cache,
-        #: N devices, and a chain whose blocks declare tp layouts.
+        #: (serving/tp.py; module docstring).  Needs N devices and a
+        #: chain whose blocks declare tp layouts.
         if tp is not None and int(tp) == 1:
             tp = 0
         tp = int(self._without_state("tp", tp,
@@ -733,11 +711,7 @@ class InferenceScheduler(Logger):
                 # model and its pools for 1/tp of them per chip
                 raise ValueError("tp=%d needs %d devices, found %d"
                                  % (tp, tp, len(jax.devices())))
-            if self.kv != "paged":
-                self.info("tp needs the paged cache; serving "
-                          "unsharded")
-                tp = 0
-            elif not tp_supported(forwards, tp):
+            if not tp_supported(forwards, tp):
                 self.info("chain does not divide over tp=%d (heads/"
                           "d_model/hidden divisibility, or a MoE/"
                           "int8-weight block); serving unsharded", tp)
@@ -755,9 +729,6 @@ class InferenceScheduler(Logger):
         if role != "both":
             self._without_state(
                 "role=%r (block export and import)" % role, True, True)
-        if role == "prefill" and self.kv != "paged":
-            raise ValueError("role='prefill' needs the paged cache "
-                             "(block export is block-granular)")
         self.role = role
         #: identity for the per-replica metric labels (satellite of
         #: the last-scheduler-wins gauge fix): the fleet's replica id
@@ -941,12 +912,11 @@ class InferenceScheduler(Logger):
             raise ValueError(
                 "prompt_len + steps = %d exceeds the serving window "
                 "(%d)" % (len(prompt) + steps, self.window))
-        if self.kv == "paged":
-            need = -(-(len(prompt) + steps) // self.block_size)
-            if need > self.kv_blocks:
-                raise ValueError(
-                    "request needs %d KV blocks > pool capacity %d "
-                    "(kv_blocks)" % (need, self.kv_blocks))
+        need = -(-(len(prompt) + steps) // self.block_size)
+        if need > self.kv_blocks:
+            raise ValueError(
+                "request needs %d KV blocks > pool capacity %d "
+                "(kv_blocks)" % (need, self.kv_blocks))
         temperature = float(temperature or 0.0)
         top_k = int(top_k or 0)
         if top_k and not temperature:
@@ -1005,7 +975,7 @@ class InferenceScheduler(Logger):
                     % len(self._queue))
                 err.retry_after = _RETRY_AFTER[prio]
                 raise err
-            if self.kv == "paged" and self.shed_block_factor > 0 \
+            if self.shed_block_factor > 0 \
                     and self._queued_blocks + need \
                     > self.shed_block_factor * _SHED_FRAC[prio] \
                     * self.kv_blocks:
@@ -1047,8 +1017,6 @@ class InferenceScheduler(Logger):
                 "decode-role replica imports KV (POST "
                 "/serving/kv_import) — prefill belongs on the "
                 "prefill pool")
-        if self.kv != "paged":
-            raise ValueError("prefill export needs the paged cache")
         prompt = [int(t) for t in prompt]
         if not prompt:
             raise ValueError("prompt must be non-empty")
@@ -1143,8 +1111,6 @@ class InferenceScheduler(Logger):
             raise RoleMismatchError(
                 "prefill-role replica exports KV — imports belong "
                 "on the decode pool")
-        if self.kv != "paged":
-            raise ValueError("kv import needs the paged cache")
         prompt = [int(t) for t in export.get("prompt", ())]
         steps = int(steps)
         if not prompt or int(export.get("length", -1)) != len(prompt):
@@ -1198,10 +1164,9 @@ class InferenceScheduler(Logger):
         return req.future
 
     def _submit_prefix_job(self, kind, payload):
-        if self.kv != "paged" or not self.prefix_cache:
+        if not self.prefix_cache:
             raise ValueError(
-                "prefix %s needs the paged cache with the prefix "
-                "cache enabled" % kind)
+                "prefix %s needs the prefix cache enabled" % kind)
         fut = concurrent.futures.Future()
         with self._wake:
             if self._closed:
@@ -1303,9 +1268,7 @@ class InferenceScheduler(Logger):
         return len(req.prompt) + req.steps
 
     def _blocks_for(self, req):
-        """The paged block budget a request commits (0 when dense)."""
-        if self.kv != "paged":
-            return 0
+        """The block budget a request commits."""
         return -(-self._budget_tokens(req) // self.block_size)
 
     def cancel(self, future, reason="cancelled by client"):
@@ -1572,7 +1535,9 @@ class InferenceScheduler(Logger):
                 + len(self._aux)
 
     def _kv_snapshot(self):
-        out = {"kv_mode": self.kv,
+        # "kv_mode": the one layout; dashboards and the benchmark's
+        # ``served_by`` record read the key
+        out = {"kv_mode": "paged",
                "prefill_chunk": self.prefill_chunk,
                "prefilling": len(self._prefilling),
                "tp": self.tp,
@@ -1588,24 +1553,23 @@ class InferenceScheduler(Logger):
         # first such call (the warm-up's first step, where it runs)
         out["pools_in_place"] = cache.pool_copies == 0 \
             if cache is not None and cache.pool_swaps else None
-        if self.kv == "paged":
-            out["kv_dtype"] = self.kv_dtype
-            out["kv_bytes_per_token"] = \
-                cache.bytes_per_token() if cache is not None else None
-            out["kv_block_size"] = self.block_size
-            out["kv_blocks_total"] = self.kv_blocks
-            # the loop thread owns the free lists; these reads are
-            # monitoring-grade (len() is atomic enough for a gauge)
-            out["kv_blocks_used"] = \
-                cache.used_blocks if cache is not None else 0
-            out["kv_blocks_free"] = \
-                cache.free_blocks if cache is not None \
-                else self.kv_blocks
-            # what is resident for it: the paged K/V pools, and the
-            # per-slot state beside them (0 for a chain with none)
-            out["state_bytes"] = \
-                cache.state_bytes() if cache is not None else None
-            out["state_units"] = sorted(self._state_units.values())
+        out["kv_dtype"] = self.kv_dtype
+        out["kv_bytes_per_token"] = \
+            cache.bytes_per_token() if cache is not None else None
+        out["kv_block_size"] = self.block_size
+        out["kv_blocks_total"] = self.kv_blocks
+        # the loop thread owns the free lists; these reads are
+        # monitoring-grade (len() is atomic enough for a gauge)
+        out["kv_blocks_used"] = \
+            cache.used_blocks if cache is not None else 0
+        out["kv_blocks_free"] = \
+            cache.free_blocks if cache is not None \
+            else self.kv_blocks
+        # what is resident for it: the paged K/V pools, and the
+        # per-slot state beside them (0 for a chain with none)
+        out["state_bytes"] = \
+            cache.state_bytes() if cache is not None else None
+        out["state_units"] = sorted(self._state_units.values())
         out["spec"] = self.spec
         out["spec_k"] = self.spec_k if self.spec else 0
         out["drafter"] = self.drafter if self.spec else None
@@ -1673,8 +1637,7 @@ class InferenceScheduler(Logger):
         out = []
         for phase, req in rows:
             blocks = shared = 0
-            if req.slot is not None and self.kv == "paged" \
-                    and cache is not None:
+            if req.slot is not None and cache is not None:
                 blocks = int(cache.n_blocks[req.slot])
                 shared = int(cache.n_shared[req.slot])
             row = {
@@ -1705,7 +1668,7 @@ class InferenceScheduler(Logger):
         exactly one of {trash, free, resident, slot-private} and
         every slot's shared prefix is resident."""
         cache = self.cache_
-        if cache is None or self.kv != "paged":
+        if cache is None:
             return
         cache.check(resident=self.prefix_.resident_blocks()
                     if self.prefix_ is not None else ())
@@ -1775,14 +1738,10 @@ class InferenceScheduler(Logger):
     # -- decode loop ----------------------------------------------------
 
     def _make_cache(self):
-        if self.kv == "paged":
-            return PagedKVCache(self.forwards, self.max_slots,
-                                self.window,
-                                block_size=self.block_size,
-                                kv_blocks=self.kv_blocks,
-                                kv_dtype=self.kv_dtype,
-                                tp=self.tp_)
-        return SlotKVCache(self.forwards, self.max_slots, self.window)
+        return PagedKVCache(self.forwards, self.max_slots, self.window,
+                            block_size=self.block_size,
+                            kv_blocks=self.kv_blocks,
+                            kv_dtype=self.kv_dtype, tp=self.tp_)
 
     def _warm_paged(self, cache):
         """Compile the paged step's (occupancy, depth) bucket ladder
@@ -1847,13 +1806,12 @@ class InferenceScheduler(Logger):
             cache = self._make_cache()
             if self.prefix_cache:
                 self.prefix_ = RadixPrefixCache(self.block_size)
-            if self.kv == "paged" and self.warm_buckets:
+            if self.warm_buckets:
                 self._warm_paged(cache)
             self.cache_ = cache
-            if self.kv == "paged":
-                self.stats.set_kv_dtype(self.kv_dtype,
-                                        cache.bytes_per_token())
-                self.stats.set_state_bytes(cache.state_bytes())
+            self.stats.set_kv_dtype(self.kv_dtype,
+                                    cache.bytes_per_token())
+            self.stats.set_state_bytes(cache.state_bytes())
         except Exception as e:  # surface init failures to clients
             with self._wake:
                 self._closed = True
@@ -1972,8 +1930,6 @@ class InferenceScheduler(Logger):
         cache hits raise the concurrent-stream ceiling; evictable
         refcount-0 resident blocks count as headroom too."""
         total = self._budget_tokens(req)
-        if self.kv != "paged":
-            return cache.can_admit(total)
         if not cache.free_slots:
             return False
         need = cache.blocks_needed(total)
@@ -1995,9 +1951,6 @@ class InferenceScheduler(Logger):
         cold residents if the free list is short, then alloc with
         the matched blocks heading the table."""
         total = self._budget_tokens(req)
-        if self.kv != "paged":
-            req.slot = cache.alloc(total)
-            return req.slot is not None
         handle = None
         # an IMPORT scatters into its leading table blocks — they
         # must be privately owned, never prefix-cache residents, so
@@ -2042,12 +1995,13 @@ class InferenceScheduler(Logger):
         request that FINISHED cleanly donates the full blocks of its
         prompt + generated stream to the prefix cache (insert-on-
         release) — the warm state future identical prefixes match."""
+        pfx = self.prefix_
         if req.slot is None:
             if req.prefix_handle is not None:
-                self.prefix_.release(req.prefix_handle)
+                pfx.release(req.prefix_handle)
                 req.prefix_handle = None
             return
-        if self.kv != "paged" or self.prefix_ is None:
+        if pfx is None:
             cache.release(req.slot)
         else:
             donate = 0
@@ -2063,11 +2017,10 @@ class InferenceScheduler(Logger):
             shared, donated = cache.release(req.slot,
                                             donate=max(0, donate))
             if req.prefix_handle is not None:
-                self.prefix_.release(req.prefix_handle)
+                pfx.release(req.prefix_handle)
                 req.prefix_handle = None
             if seq is not None and (shared or donated):
-                _, rejected = self.prefix_.insert(seq,
-                                                  shared + donated)
+                _, rejected = pfx.insert(seq, shared + donated)
                 if rejected:  # an identical twin donated first
                     cache.reclaim(rejected)
             self._sync_prefix_gauges()
@@ -2274,9 +2227,7 @@ class InferenceScheduler(Logger):
                 "requests", stalled, len(victims))
 
     def _sync_kv_gauges(self, cache):
-        if self.kv == "paged":
-            self.stats.set_kv_blocks(cache.used_blocks,
-                                     cache.free_blocks)
+        self.stats.set_kv_blocks(cache.used_blocks, cache.free_blocks)
 
     def _expire_locked(self):
         now = time.monotonic()
@@ -2302,9 +2253,8 @@ class InferenceScheduler(Logger):
     def _staging_width(self, p_len, chunk):
         """Width of the batch-1 staging K/V row a prompt prefills
         into: the power-of-two bucket of the prompt, floored so it
-        tiles both the chunk width and (paged) the block size."""
-        bs = self.block_size if self.kv == "paged" else 1
-        floor = max(self.prefill_bucket, bs, chunk or 1)
+        tiles both the chunk width and the block size."""
+        floor = max(self.prefill_bucket, self.block_size, chunk or 1)
         return _bucket(p_len, floor, 1 << 30)
 
     def _begin_admit(self, req, cache):
@@ -2475,14 +2425,11 @@ class InferenceScheduler(Logger):
         preempt-resume — exactly the counter the decode step would
         have folded, so the resumed stream never forks."""
         try:
-            if self.kv == "paged":
-                # a warm admission skips its shared prefix blocks —
-                # they are the prefix cache's, and already hold
-                # exactly these rows
-                cache.insert(req.slot, row_caches, len(req.pf_seq),
-                             from_block=req.pf_matched)
-            else:
-                cache.insert(req.slot, row_caches, len(req.pf_seq))
+            # a warm admission skips its shared prefix blocks — they
+            # are the prefix cache's, and already hold exactly these
+            # rows
+            cache.insert(req.slot, row_caches, len(req.pf_seq),
+                         from_block=req.pf_matched)
         except Exception as e:
             self._retire(req, cache, error=e)
             self._recover_pools(cache, e)
@@ -2635,10 +2582,7 @@ class InferenceScheduler(Logger):
             return
         try:
             faults.fire("serving.scheduler.step")
-            if self.kv == "paged":
-                self._step_paged(cache, active)
-            else:
-                self._step_dense(cache, active)
+            self._step_paged(cache, active)
         except Exception as e:
             # every active request rode the batch, so each is failed
             # with the error; the loop lives on for the next request
@@ -2789,11 +2733,7 @@ class InferenceScheduler(Logger):
         share = dt / len(active)
         usage = {}
         for slot, req in active.items():
-            if self.kv == "paged":
-                blocks = int(cache.n_blocks[slot])
-            else:
-                blocks = -(-(len(req.prompt) + len(req.generated))
-                           // self.block_size)
+            blocks = int(cache.n_blocks[slot])
             rec = usage.setdefault(req.tenant or "anon", [0.0, 0.0])
             rec[0] += blocks * dt
             rec[1] += share
@@ -2974,40 +2914,6 @@ class InferenceScheduler(Logger):
                 reqtrace.record_step(emitted, duration=dt,
                                      mode="verify", slots=n, bucket=b,
                                      k=k)
-
-    def _step_dense(self, cache, active):
-        """Legacy full-batch step: free slots decode garbage rows."""
-        s = self.max_slots
-        with self._phases("pack"):
-            toks = numpy.zeros((s, 1), numpy.int32)
-            pos = numpy.zeros((s,), numpy.int32)
-            temps = numpy.zeros((s,), numpy.float32)
-            topks = numpy.zeros((s,), numpy.int32)
-            seeds = numpy.zeros((s,), numpy.uint32)
-            counts = numpy.zeros((s,), numpy.int32)
-            arrays = (toks, pos, temps, topks, seeds, counts)
-            for slot, req in active.items():
-                self._fill_row(arrays, slot, req)
-        with self._phases("step") as launch:
-            nxt = numpy.asarray(slot_decode_step(
-                self.forwards, cache, toks, pos, temps, topks, seeds,
-                counts, params=self.weights_.params))
-        dt = launch.seconds
-        with self._phases("observe"):
-            self.stats.record_step(len(active), s, tokens=len(active))
-            self._meter_step(active, cache, dt)
-        with self._phases("emit"):
-            for slot, req in active.items():
-                self._emit(req, int(nxt[slot]))
-                self._maybe_finish(req, cache)
-        if self._tron:
-            with self._phases("observe"):
-                emitted = {}
-                for r in active.values():
-                    emitted[r.trace] = emitted.get(r.trace, 0) + 1
-                reqtrace.record_step(emitted, duration=dt,
-                                     mode="decode", slots=len(active),
-                                     bucket=s)
 
     def _maybe_finish(self, req, cache, error=None):
         done = error is not None \
