@@ -242,7 +242,7 @@ def _lower_paged_step(fw, s, params):
     def vec(dtype, *shape):
         return jax.ShapeDtypeStruct((b,) + shape, dtype)
     return b, jax.jit(_make_paged_step(fw)).lower(
-        params, vec(jnp.int32, 1), vec(jnp.int32), vec(jnp.int32, t),
+        params, vec(jnp.int32), vec(jnp.int32), vec(jnp.int32, t),
         vec(jnp.float32), vec(jnp.int32), vec(jnp.uint32),
         vec(jnp.int32), vec(jnp.int32), pools)
 
@@ -325,7 +325,7 @@ def _pool_programs(one_chip):
     stage = arr(jnp.bfloat16, 1, 1024, d)
     return math.prod(pool.shape), {
         "paged_step": (step, on_chip((
-            _abstract_params(fw, True), arr(jnp.int32, b, 1),
+            _abstract_params(fw, True), arr(jnp.int32, b),
             arr(jnp.int32, b), arr(jnp.int32, b, t),
             arr(jnp.float32, b), arr(jnp.int32, b), arr(jnp.uint32, b),
             arr(jnp.int32, b), arr(jnp.int32, b), pools)), 2 * len(pools)),

@@ -115,7 +115,7 @@ def _make_paged_step(forwards, want_hidden=False):
 
     def step(params, toks, pos, tables, temps, topks, seeds, counts,
              slots, pools):
-        h = toks
+        h = toks[:, None]
         hid = None
         out = dict(pools)
         moe = []
@@ -205,7 +205,7 @@ def _make_paged_step_tp(forwards, ctx, pools, want_hidden=False):
 
     def body(params, toks, pos, tables, temps, topks, seeds, counts,
              slots, pools_):
-        h = toks
+        h = toks[:, None]
         hid = None
         out = dict(pools_)
         for i, u in enumerate(forwards):
@@ -249,11 +249,16 @@ def paged_decode_step(forwards, cache, toks, pos, tables, temps,
 
     All arrays are packed to the caller's occupancy bucket ``B``
     (padding rows: token 0, position 0, an all-zero table — they
-    write into and read from the reserved trash block): ``toks``
-    [B, 1], ``pos``/``temps``/``topks``/``seeds``/``counts`` [B],
+    write into and read from the reserved trash block): ``toks``,
+    ``pos``/``temps``/``topks``/``seeds``/``counts`` [B],
     ``tables`` [B, T] physical block ids (T·block_size must cover
-    ``max(pos) + 1``).  Returns the [B] next tokens; the caller maps
-    packed rows back to its slots.  ``want_hidden`` additionally
+    ``max(pos) + 1``).  Returns the [B] next tokens, a DEVICE array
+    that nothing has waited for; the caller maps packed rows back to
+    its slots.  The step takes its tokens in the shape it hands them
+    back, so ``toks`` may be the array the previous call returned,
+    passed on before anyone has read it (the scheduler's launch-ahead:
+    no transfer, no further dispatch); host tokens may also come as
+    [B, 1].  ``want_hidden`` additionally
     returns the [B, d] f32 last hidden state (the final unit's
     input) — the model-based draft head's conditioning
     (serving/draft.py); the flag keys the executable cache, so
@@ -289,6 +294,15 @@ def paged_decode_step(forwards, cache, toks, pos, tables, temps,
         params = _device_params(forwards)
     tables = jnp.asarray(tables, jnp.int32)
     b, t = tables.shape
+    if not isinstance(toks, jax.Array):
+        toks = numpy.asarray(toks, numpy.int32).reshape(b)
+        # placed as a step leaves its tokens: the executable cache
+        # then holds one entry a bucket whichever way the tokens came,
+        # and the first launch from device tokens in a bucket is no
+        # compile.  A step over uncommitted parameters leaves them
+        # uncommitted, as the plain upload below does
+        if cache.token_sharding is not None:
+            toks = jax.device_put(toks, cache.token_sharding)
     # fp32 pools only: the int8 pool's per-row amax must reduce over
     # the FULL feature axis (GSPMD does that collectively); a
     # per-shard body would compute shard-local scales
@@ -327,6 +341,7 @@ def paged_decode_step(forwards, cache, toks, pos, tables, temps,
     cache.moe_counts = pools.pop(MOE_COUNTS, None)
     cache.pools = pools
     cache.note_swap(old)
+    cache.token_sharding = got[0].sharding if got[0].committed else None
     return (got[0], got[1]) if want_hidden else got[0]
 
 

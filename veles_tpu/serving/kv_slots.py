@@ -327,6 +327,12 @@ class PagedKVCache:
         #: the routed layers' counts of the last decode step, a device
         #: array [layers, 4] (serving/engine.paged_decode_step)
         self.moe_counts = None
+        #: where the last decode step left its tokens, if it COMMITTED
+        #: them there (None before the first step, and for a step over
+        #: uncommitted parameters): the step places host tokens alike,
+        #: so a launch from the host and one from the previous step's
+        #: device tokens are ONE call signature
+        self.token_sharding = None
 
     # -- occupancy reads ------------------------------------------------
 

@@ -222,9 +222,10 @@ def _loop_series():
             "buckets, the numpy rows, the block tables"),
         "step": metrics.counter(
             "veles_serving_loop_step_seconds_total",
-            "loop seconds from a decode/verify launch through the "
-            "readback of its tokens: the one phase that waits on "
-            "the device"),
+            "loop seconds launching decode/verify steps and waiting "
+            "for their tokens (under launch-ahead: the launch of a "
+            "step, then the wait for the step BEFORE it): the one "
+            "phase that waits on the device"),
         "emit": metrics.counter(
             "veles_serving_loop_emit_seconds_total",
             "loop seconds accepting tokens: stream sinks, "
@@ -242,15 +243,27 @@ def _loop_series():
             "passes of the scheduler loop that found work"),
         "steps": metrics.counter(
             "veles_serving_steps_total",
-            "decode/verify steps launched (step phases)"),
+            "decode/verify steps launched"),
+        "steps_ahead": metrics.counter(
+            "veles_serving_steps_ahead_total",
+            "decode steps launched from the device-resident tokens "
+            "of the step before them, before the host had read "
+            "those (scheduler launch-ahead; 0 under speculation)"),
+        "rows_discarded": metrics.counter(
+            "veles_serving_rows_discarded_total",
+            "rows of a landed decode step whose request had left its "
+            "slot while the step was in flight (stop token, cancel, "
+            "deadline, failure): not emitted, not counted as a token "
+            "or a busy slot-step"),
         "steps_after_prefill": metrics.counter(
             "veles_serving_steps_after_prefill_total",
             "steps launched in a pass that ran a prefill phase "
             "before them (the prompt's device time rides in the "
-            "step's readback)"),
+            "readback of that step, a pass later under launch-"
+            "ahead)"),
         "step_after_prefill": metrics.counter(
             "veles_serving_loop_step_after_prefill_seconds_total",
-            "step-phase seconds of those steps"),
+            "step-phase seconds of those passes"),
         "queue_wait": metrics.counter(
             "veles_serving_queue_wait_seconds_total",
             "submit-to-admission seconds, summed over first tokens"),
@@ -932,6 +945,11 @@ class ServingMetrics:
         self._global = _registry_series()
         self._loop = _loop_series()
         self._pool_copies_seen = 0
+        #: this scheduler's own share of three of those counters (the
+        #: registry's are the process's): decode/verify launches, the
+        #: launches that ran ahead, the rows a landing discarded
+        self.steps_launched = self.steps_ahead = 0
+        self.rows_discarded = 0
         #: replica-side SLO accounting (TTFT + e2e vs the per-class
         #: objectives under root.common.slo.*)
         self.slo = SLOTracker("serving")
@@ -1263,12 +1281,16 @@ class ServingMetrics:
         self._global["weight_leaves_cast"].inc(int(leaves_cast))
 
     def record_loop_pass(self, seconds, steps, steps_after_prefill,
-                         step_after_prefill_seconds, passes=1,
-                         pool_copies=None):
+                         step_after_prefill_seconds, steps_ahead=0,
+                         rows_discarded=0, passes=1, pool_copies=None):
         """The scheduler loop's phase account since its last flush
         (``scheduler._LoopPhases.drain()``): ``seconds`` by phase.
         The loop calls this once a pass, so the account costs the
         registry one visit a pass however many phases ran.
+        ``steps``: decode/verify launches (a step stretch that only
+        waited adds seconds and none); ``steps_ahead`` /
+        ``rows_discarded``: the launch-ahead's two counts
+        (``scheduler._step_paged``, ``_land_flight``).
         ``pool_copies``: the cache's running count (the warm-up's
         included); what it grew by since the last pass is counted."""
         if pool_copies is not None \
@@ -1284,6 +1306,13 @@ class ServingMetrics:
         self._loop["passes"].inc(passes)
         if steps:
             self._loop["steps"].inc(steps)
+            self.steps_launched += steps
+        if steps_ahead:
+            self._loop["steps_ahead"].inc(steps_ahead)
+            self.steps_ahead += steps_ahead
+        if rows_discarded:
+            self._loop["rows_discarded"].inc(rows_discarded)
+            self.rows_discarded += rows_discarded
         if steps_after_prefill:
             self._loop["steps_after_prefill"].inc(steps_after_prefill)
             self._loop["step_after_prefill"].inc(
@@ -1397,6 +1426,10 @@ class ServingMetrics:
                 "max_slots": int(max_slots),
                 "slot_occupancy": round(occ, 4),
                 "slot_busy_steps": self.slot_busy_steps,
+                "steps_ahead_share": round(
+                    self.steps_ahead / self.steps_launched, 4)
+                if self.steps_launched else None,
+                "rows_discarded": self.rows_discarded,
                 "prefill_chunks": self.prefill_chunks,
                 "prefill_chunk_tokens": self.prefill_chunk_tokens,
                 "requests_cancelled": self.cancelled,

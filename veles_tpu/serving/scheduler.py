@@ -28,10 +28,16 @@ one compiled step):
    power-of-two block bucket over the deepest request
    (:func:`serving.engine.paged_decode_step`), so a half-empty batch
    of shallow requests pays neither full-batch nor full-window
-   compute;
+   compute.  The step is LAUNCHED here and LANDED (its tokens read,
+   emitted, counted) one launch later: while the batch stays the
+   same, step N+1 goes out from step N's device-resident tokens
+   before the host has read them, so the device runs back to back
+   through the host's launch and the loop's own work
+   (``InferenceScheduler._step_paged``; speculation drafts from the
+   host's tokens and lands every step at once);
 4. **retire** — a slot that generated its stop token or hit its step
-   limit completes its future and frees slot + blocks at the token
-   boundary, where the next queued request joins.
+   limit completes its future and frees slot + blocks where its token
+   lands, and the next queued request joins.
 
 Admission control: a full queue raises :class:`QueueFullError` (HTTP
 503) at submit; a request still queued past its deadline fails with
@@ -174,8 +180,10 @@ Phase account (always on): every instant of the loop thread is charged
 to exactly one of ``PHASES`` — ``parked`` (waiting for work), ``admit``
 (the loop's own bookkeeping; the phase wherever no block names
 another), ``prefill``, ``aux``, ``draft``, ``pack`` (a step's inputs),
-``step`` (launch through token readback: the one phase that waits on
-the device), ``emit`` and ``observe`` (statistics, metering, request
+``step`` (a step's launch and the wait for a launched step's tokens,
+under launch-ahead those of the step BEFORE it: the one phase that
+waits on the device; a wait alone adds seconds and no step), ``emit``
+and ``observe`` (statistics, metering, request
 tracing, gauges, the account's own flush).  ``with self._phases(name)``
 marks a stretch; :class:`_LoopPhases` keeps plain floats on the loop
 thread and one ``ServingMetrics.record_loop_pass`` a pass moves them
@@ -344,15 +352,19 @@ PHASES = ("parked", "admit", "prefill", "aux", "draft", "pack", "step",
 class _Phase(object):
     """``with phases("step") as ph``: one stretch of one phase;
     ``ph.seconds`` is its last uninterrupted stretch, read after the
-    block (the whole of it where nothing nests, as in ``step``)."""
+    block (the whole of it where nothing nests, as in ``step``).
+    ``launch=False``: a ``step`` stretch that only waits for a step
+    launched earlier, so it adds seconds and no step."""
 
-    __slots__ = ("account", "name", "outer", "seconds")
+    __slots__ = ("account", "name", "launch", "outer", "seconds")
 
-    def __init__(self, account, name):
-        self.account, self.name = account, name
+    def __init__(self, account, name, launch=True):
+        self.account, self.name, self.launch = account, name, launch
 
     def __enter__(self):
         self.outer = self.account.switch(self.name)
+        if self.outer != self.name:    # a stretch of its own
+            self.account.launching = self.launch
         return self
 
     def __exit__(self, *exc):
@@ -376,6 +388,7 @@ class _LoopPhases(object):
     def __init__(self):
         self._reset()
         self.current = "admit"
+        self.launching = True      # a step stretch counts one step
         self._since = time.perf_counter()
         self._span = annotation("veles.sched.admit")
         self._span.__enter__()
@@ -385,16 +398,22 @@ class _LoopPhases(object):
         self.steps = self.steps_after_prefill = 0
         self.step_after_prefill_seconds = 0.0
         self.prefilled = False     # a prefill phase ran this pass
+        # launch-ahead (InferenceScheduler._step_paged), flushed with
+        # the pass: steps launched from the tokens of a step nobody
+        # had read yet, and rows of a landed step that were discarded
+        self.steps_ahead = self.rows_discarded = 0
 
-    def __call__(self, name):
-        return _Phase(self, name)
+    def __call__(self, name, launch=True):
+        return _Phase(self, name, launch)
 
     def elapsed(self):
         """Seconds since the current phase was entered or resumed."""
         return time.perf_counter() - self._since
 
     def switch(self, name):
-        """Make ``name`` the current phase; returns the one it was."""
+        """Make ``name`` the current phase; returns the one it was.
+        A ``step`` stretch counts as one step unless its block said
+        ``launch=False`` (the wait for a step launched before)."""
         was = self.current
         if name == was:
             return was
@@ -402,12 +421,13 @@ class _LoopPhases(object):
         took = now - self._since
         self.seconds[was] += took
         if was == "step":
-            self.steps += 1
+            self.steps += self.launching
             if self.prefilled:
-                self.steps_after_prefill += 1
+                self.steps_after_prefill += self.launching
                 self.step_after_prefill_seconds += took
         if name == "prefill":
             self.prefilled = True
+        self.launching = True
         self.current, self._since = name, now
         self._span.__exit__(None, None, None)
         self._span = annotation("veles.sched." + name)
@@ -418,7 +438,8 @@ class _LoopPhases(object):
         """The totals since the last drain, as the arguments of
         ``ServingMetrics.record_loop_pass``; the pass ends here."""
         out = (self.seconds, self.steps, self.steps_after_prefill,
-               self.step_after_prefill_seconds)
+               self.step_after_prefill_seconds, self.steps_ahead,
+               self.rows_discarded)
         self._reset()
         return out
 
@@ -490,6 +511,21 @@ class _Request(object):
                 self.future.set_exception(error)
             except concurrent.futures.InvalidStateError:
                 pass
+
+
+class _Flight(object):
+    """A decode step that was launched and has not been read: its
+    packed slot order, the request of each row, the device arrays it
+    will hand over (a bucket of tokens; the routed layers' counts and
+    the hidden state where the chain has them) and its launch time.  Loop thread
+    only; at most one exists (``InferenceScheduler._flight``)."""
+
+    __slots__ = ("slots", "reqs", "nxt", "hid", "moe", "t0")
+
+    def __init__(self, slots, reqs, nxt, hid, moe):
+        self.slots, self.reqs = slots, reqs
+        self.nxt, self.hid, self.moe = nxt, hid, moe
+        self.t0 = time.perf_counter()
 
 
 class InferenceScheduler(Logger):
@@ -779,6 +815,8 @@ class InferenceScheduler(Logger):
         #: dropped by close()
         self.weights_ = None
         self._phases = None          # _LoopPhases, the loop thread's
+        self._flight = None          # _Flight: the step not yet read
+        self._landed_at = 0.0        # perf_counter of the last landing
         self.prefix_ = None          # radix cache (loop thread too)
         #: host KV tier — constructed HERE (no device dependencies)
         #: so the reference is immutable across threads; only the
@@ -1666,7 +1704,10 @@ class InferenceScheduler(Logger):
         """Invariant sweep over the paged cache INCLUDING the prefix
         cache's resident blocks (tests/soaks): every block is
         exactly one of {trash, free, resident, slot-private} and
-        every slot's shared prefix is resident."""
+        every slot's shared prefix is resident.  Host tables only: a
+        step in flight holds no block and moves none (what a row it
+        will discard does is on the device alone), so any thread may
+        ask and nothing is landed for it."""
         cache = self.cache_
         if cache is None:
             return
@@ -1773,7 +1814,7 @@ class InferenceScheduler(Logger):
             for t in depths:
                 paged_decode_step(
                     self.forwards, cache,
-                    numpy.zeros((b, 1), numpy.int32),
+                    numpy.zeros((b,), numpy.int32),
                     numpy.zeros((b,), numpy.int32),
                     numpy.zeros((b, t), numpy.int32),
                     numpy.zeros((b,), numpy.float32),
@@ -1830,14 +1871,20 @@ class InferenceScheduler(Logger):
                     self.stats.record_loop_pass(
                         *phases.drain(), pool_copies=cache.pool_copies)
         finally:
+            # the tokens of a step still in flight belong to their
+            # requests before close() fails what is unfinished
+            self._land(cache)
             # what the last pass and the wait before close() took
             phases.close()
             self.stats.record_loop_pass(*phases.drain(), passes=0)
 
     def _idle_locked(self):
+        # (a step in flight whose riders have all left their slots
+        # is landed by the next pass: the loop never parks on one)
         return not (self._closed or self._queue or self._active
                     or self._prefilling or self._preempts_owed
-                    or self._aux or self._prefix_jobs)
+                    or self._aux or self._prefix_jobs
+                    or self._flight is not None)
 
     def _pass(self, cache):
         """One pass of the loop: park until there is work, then one
@@ -1919,7 +1966,7 @@ class InferenceScheduler(Logger):
         if self._prefilling:
             with phases("prefill"):
                 self._prefill_tick(cache)
-        if self._active:
+        if self._active or self._flight is not None:
             self._step(cache)
         return True
 
@@ -2162,7 +2209,10 @@ class InferenceScheduler(Logger):
         ``below`` with no strictly-lower-class victim is dropped.
         The victim keeps its generated prefix and requeues at the
         front of ITS class, so it resumes as soon as its own freed
-        blocks (or better) are available."""
+        blocks (or better) are available.  The victim's re-prefill
+        reads its ``generated``, so a step in flight lands first."""
+        if self._preempts_owed:
+            self._land(cache)
         while True:
             with self._lock:
                 if not self._preempts_owed:
@@ -2510,6 +2560,9 @@ class InferenceScheduler(Logger):
         finished request, so repeat prompts prefill warm on this
         replica too."""
         p_len = len(req.pf_seq)
+        # the gather below is read to the host: a failed step in
+        # flight surfaces at its own landing, not as this request's
+        self._land(cache)
         try:
             faults.fire("serving.scheduler.kv_export")
             n = cache.blocks_needed(p_len)
@@ -2575,22 +2628,47 @@ class InferenceScheduler(Logger):
 
     def _step(self, cache):
         """Advance every active request one token through the shared
-        compiled step, then retire finished ones at the boundary."""
+        compiled step; a finished one retires where its token lands
+        (:meth:`_land_flight`)."""
         with self._lock:
             active = dict(self._active)
-        if not active:
-            return
+        flight = self._flight
         try:
             faults.fire("serving.scheduler.step")
             self._step_paged(cache, active)
         except Exception as e:
-            # every active request rode the batch, so each is failed
-            # with the error; the loop lives on for the next request
-            self.exception("decode step failed: %r", e)
-            if not self._recover_pools(cache, e):
-                for req in active.values():
-                    if req.slot is not None:   # not retired in the step
-                        self._retire(req, cache, error=e)
+            # a step fails where it is launched or, once the device
+            # runs it, where its tokens are read: one launch later.
+            # By then the step after it has consumed what it returned,
+            # so the riders of both are failed
+            riders = list(active.values())
+            for ridden in (flight, self._flight):
+                if ridden is not None:
+                    riders.extend(ridden.reqs)
+            self._step_failed(cache, e, riders)
+
+    def _step_failed(self, cache, error, riders):
+        """Every request that rode the failed batch is failed with
+        the error (or all of them, if the pools went with it); the
+        loop lives on for the next request."""
+        self.exception("decode step failed: %r", error)
+        self._flight = None
+        if not self._recover_pools(cache, error):
+            for req in riders:
+                if req.slot is not None:   # not retired meanwhile
+                    self._retire(req, cache, error=error)
+
+    def _land(self, cache):
+        """Land the step in flight, if there is one, before something
+        that changes the row set or reads what it will emit (a
+        preempt, a KV export, the loop's end)."""
+        flight, self._flight = self._flight, None
+        if flight is None:
+            return
+        try:
+            self._land_flight(cache, flight)
+        except Exception as e:
+            self._step_failed(cache, e, flight.reqs)
 
     def _recover_pools(self, cache, error=None):
         """The other half of donating the cache's device state
@@ -2632,15 +2710,6 @@ class InferenceScheduler(Logger):
         req.generated.append(tok)
         if req.sink is not None:
             req.sink(tok)
-
-    def _fill_row(self, arrays, j, req):
-        toks, pos, temps, topks, seeds, counts = arrays
-        toks[j, 0] = req.generated[-1]
-        pos[j] = len(req.prompt) + len(req.generated) - 1
-        temps[j] = req.temperature
-        topks[j] = req.top_k
-        seeds[j] = req.seed
-        counts[j] = len(req.generated)
 
     def _pick_model(self, req):
         """Per-slot drafter arbitration: take the model head unless
@@ -2739,25 +2808,64 @@ class InferenceScheduler(Logger):
             rec[1] += share
         self.stats.record_tenant_step(usage)
 
+    def _runs_ahead(self, flight, active):
+        """May the next step be launched before ``flight`` is read?
+        Yes where it is the same batch one token on: the same requests
+        in the same slots, none of which ends on its budget with the
+        token in flight (all known on the host without that token).
+        A stop token cannot be foreseen: its row runs ahead and is
+        discarded when it lands (:meth:`_land_flight`)."""
+        return len(active) == len(flight.slots) and all(
+            active.get(slot) is req
+            and len(req.generated) + 1 < req.steps
+            for slot, req in zip(flight.slots, flight.reqs))
+
     def _step_paged(self, cache, active):
         """Packed step: ONLY the active slots ride the batch, padded
         to a power-of-two occupancy bucket; the attended range is the
-        power-of-two block bucket of the deepest request."""
+        power-of-two block bucket of the deepest request.
+
+        Launch-ahead: the step launched here stays IN FLIGHT when
+        this returns, and its tokens are read one launch later.  With
+        step N in flight and the batch unchanged (:meth:`_runs_ahead`)
+        step N+1 is packed from host facts (positions and draw
+        counters are N's plus one), takes N's tokens as the device
+        array N returned, and is launched BEFORE N is landed: the
+        device runs back to back while the host emits N and goes on
+        to its next pass.  Otherwise N lands first and the step is
+        packed from the host's tokens.  The speculative path drafts
+        from the host's tokens (and the hidden state), so under
+        ``spec`` every step lands at once and nothing runs ahead."""
+        flight, self._flight = self._flight, None
         if self.spec:
             with self._phases("draft"):
                 drafts, sources = self._draft(active)
             if drafts:
                 self._step_verify(cache, active, drafts, sources)
                 return
+        ahead = flight is not None and self._runs_ahead(flight, active)
+        if flight is not None and not ahead:
+            self._land_flight(cache, flight)
+            with self._lock:
+                active = dict(self._active)
+        if not active:
+            return
         with self._phases("pack"):
             slots = sorted(active)
             n = len(slots)
             b = _bucket(n, 1, self.max_slots)
             bs = cache.block_size
+            # ``ahead``: 1 where each row's newest token is still in
+            # flight, so every count below is one more than the host's
             deepest = max(len(active[s].prompt)
-                          + len(active[s].generated) for s in slots)
+                          + len(active[s].generated)
+                          for s in slots) + ahead
             t = _bucket(-(-deepest // bs), 1, cache.blocks_per_slot)
-            toks = numpy.zeros((b, 1), numpy.int32)
+            # the same bucket as the step in flight (the same rows), so
+            # its [b] device tokens go in as they are: no readback, no
+            # upload
+            toks = flight.nxt if ahead \
+                else numpy.zeros((b,), numpy.int32)
             pos = numpy.zeros((b,), numpy.int32)
             temps = numpy.zeros((b,), numpy.float32)
             topks = numpy.zeros((b,), numpy.int32)
@@ -2766,35 +2874,78 @@ class InferenceScheduler(Logger):
             tables = numpy.zeros((b, t), numpy.int32)
             rows = numpy.full((b,), -1, numpy.int32)   # -1: padding
             rows[:n] = slots
-            arrays = (toks, pos, temps, topks, seeds, counts)
             for j, slot in enumerate(slots):
-                self._fill_row(arrays, j, active[slot])
+                req = active[slot]
+                drawn = len(req.generated) + ahead
+                if not ahead:
+                    toks[j] = req.generated[-1]
+                pos[j] = len(req.prompt) + drawn - 1
+                temps[j] = req.temperature
+                topks[j] = req.top_k
+                seeds[j] = req.seed
+                counts[j] = drawn
             tables[:n] = cache.table_rows(slots, t)
             want_h = self._draft_head is not None
-        with self._phases("step") as launch:
+        with self._phases("step"):
             got = paged_decode_step(
                 self.forwards, cache, toks, pos, tables, temps, topks,
                 seeds, counts, want_hidden=want_h,
                 params=self.weights_.params, slots=rows)
-            if want_h:
-                nxt, hid = got
-                hid = numpy.asarray(hid)
-            else:
-                nxt = got
-            nxt = numpy.asarray(nxt)
-            # the routed layers' counts come with the step: computed
-            # by then, one small copy, no further wait on the device
-            moe = None if cache.moe_counts is None \
-                else numpy.asarray(cache.moe_counts)
-        dt = launch.seconds
+            nxt, hid = got if want_h else (got, None)
+            # the routed layers' counts come with the step
+            self._flight = _Flight(slots, [active[s] for s in slots],
+                                   nxt, hid, cache.moe_counts)
+        if ahead:
+            self._phases.steps_ahead += 1
+            self._land_flight(cache, flight)
+        if self.spec:
+            self._land(cache)
+
+    def _land_flight(self, cache, flight):
+        """Read a launched step's tokens and hand them on: count the
+        step, meter it, emit a token a row, retire what finished.
+        The one wait on the device, charged to ``step`` as the launch
+        is, and counted as no step.
+
+        A row whose request no longer holds its slot (it ended on a
+        stop token with this step already launched, or was cancelled,
+        expired or failed meanwhile) is DISCARDED: not emitted, not
+        counted as a token or a busy slot-step.  What the row did on
+        the device is harmless.  The device runs calls in dispatch
+        order, and the row's K/V write (the right row of its own
+        sequence, inside blocks it still held at launch) and its
+        per-slot state write were dispatched BEFORE any later
+        admission's ``insert`` / ``insert_state`` into the blocks or
+        slot it gave back, which overwrite them; a prefix block
+        promoted at retire lies below the row and holds the same
+        values either way."""
+        with self._phases("step", launch=False):
+            nxt = numpy.asarray(flight.nxt)
+            hid = None if flight.hid is None \
+                else numpy.asarray(flight.hid)
+            # computed by then: one small copy, no further wait
+            moe = None if flight.moe is None \
+                else numpy.asarray(flight.moe)
+        # the step's own stretch of the clock: from its launch, or
+        # from the landing before it where the device ran the two
+        # back to back
+        now = time.perf_counter()
+        dt = now - max(flight.t0, self._landed_at)
+        self._landed_at = now
+        with self._lock:
+            rows = [(j, slot, req) for j, (slot, req)
+                    in enumerate(zip(flight.slots, flight.reqs))
+                    if self._active.get(slot) is req]
+        n, b = len(rows), len(nxt)
+        self._phases.rows_discarded += len(flight.slots) - n
         with self._phases("observe"):
-            # plain decode: every active slot emits exactly one token
+            # plain decode: every live row emits exactly one token
             self.stats.record_step(n, b, tokens=n, moe=moe)
-            self._meter_step(active, cache, dt)
+            self._meter_step({slot: req for _, slot, req in rows},
+                             cache, dt)
         with self._phases("emit"):
-            for j, slot in enumerate(slots):
-                req = active[slot]
-                if want_h:
+            for j, slot, req in rows:
+                if hid is not None:
                     # hidden of the position just decoded — what the
                     # Medusa heads condition on next iteration
                     req.hid = hid[j]
@@ -2803,9 +2954,8 @@ class InferenceScheduler(Logger):
         if self._tron:
             with self._phases("observe"):
                 emitted = {}
-                for s in slots:  # batch rows may SHARE a client trace id
-                    tr = active[s].trace
-                    emitted[tr] = emitted.get(tr, 0) + 1
+                for _, _, req in rows:  # rows may SHARE a trace id
+                    emitted[req.trace] = emitted.get(req.trace, 0) + 1
                 reqtrace.record_step(emitted, duration=dt,
                                      mode="decode", slots=n, bucket=b)
 
