@@ -351,3 +351,62 @@ def test_pools_are_written_in_place_on_v5e(name, one_chip,
     shape = r"bf16\[1025,16,4096\]"
     assert size == 1025 * 16 * 4096
     assert re.findall(r"= %s\S* copy\(" % shape, text) == []
+
+
+# -- compiled: the looped stack's step at its cell's size ---------------------
+
+def test_looped_step_is_in_place_and_copies_no_stacked_weight(
+        one_chip, no_compile_cache):
+    """The decode step of the looped stack (benchmark/configs/
+    ouro-2.6b.json under a narrow head), compiled for the described
+    chip at 8 rows and the 32-block bucket: both pools alias their
+    outputs, and no ``copy`` of a pool, of a stacked ``[48, ...]``
+    weight or of a layer's widened gathered rows is left (each was seen
+    while the step was written: the last two cost a layer application
+    134 MB of traffic and the step 1.6 GB)."""
+    import json
+    import os
+    import re
+
+    import numpy
+
+    from veles_tpu.accelerated_units import AcceleratedWorkflow
+    from veles_tpu.memory import Array
+    from veles_tpu.models.generate import _StepClosure
+    from veles_tpu.models.standard import make_forwards
+    from veles_tpu.serving import engine
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs",
+                           "ouro-2.6b.json")) as f:
+        s = dict(json.load(f)["shapes"], vocab=1024)
+    d, n = s["dim"], s["layers"]
+    fw = make_forwards(
+        AcceleratedWorkflow(None, name="ouro-lowering"),
+        Array(numpy.zeros((1, s["positions"]), numpy.int32)),
+        [dict(type="embedding", vocab=s["vocab"], dim=d,
+              learned_positions=False),
+         dict(type="ouro_stack", dim=d, layers=n, passes=s["passes"],
+              heads=s["heads"], hidden=s["ffn"]),
+         dict(type="plain_token_logits", vocab=s["vocab"])])
+
+    def arr(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    stack = {name: arr(jnp.bfloat16 if name in fw[1].MATMUL_PARAMS
+                       else jnp.float32, *shape)
+             for name, shape in fw[1].param_shapes().items()}
+    params = {0: {"weights": arr(jnp.bfloat16, s["vocab"], d)}, 1: stack,
+              2: {"weights": arr(jnp.bfloat16, d, s["vocab"])}}
+    b, t, blocks = 8, 32, 8 * 32 + 1
+    pool = arr(jnp.bfloat16, s["passes"] * n, blocks, 16, d)
+    step = engine._paged_step_cached(
+        "looped-in-place", _StepClosure(engine._make_paged_step(fw)))
+    text = step.lower(
+        params, arr(jnp.int32, b), arr(jnp.int32, b),
+        arr(jnp.int32, b, t), arr(jnp.float32, b), arr(jnp.int32, b),
+        arr(jnp.uint32, b), arr(jnp.int32, b), arr(jnp.int32, b),
+        {1: {"k": pool, "v": pool}}).compile().as_text()
+    assert text.split("\n", 1)[0].count("-alias)") == 2
+    copied = re.findall(r"= \w+\[([0-9,]+)\]\S* copy\(", text)
+    big = [shape for shape in copied
+           if numpy.prod([int(x) for x in shape.split(",")]) >= 1 << 22]
+    assert big == []

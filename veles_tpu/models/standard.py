@@ -21,6 +21,7 @@ from veles_tpu.models.attention import MultiHeadAttention
 from veles_tpu.models.embedding import Embedding
 from veles_tpu.models.lfm2 import Lfm2Block, NormedTokenLogits
 from veles_tpu.models.moe import MoE
+from veles_tpu.models.ouro import OuroStack, PlainTokenLogits
 from veles_tpu.models.transformer import MeanPoolSeq, TransformerBlock, TokenProjection
 from veles_tpu.models.all2all import (
     All2All, All2AllRELU, All2AllSigmoid, All2AllSoftmax,
@@ -63,6 +64,8 @@ LAYER_TYPES = {
     "token_logits": TokenProjection,
     "lfm2_block": Lfm2Block,
     "rms_token_logits": NormedTokenLogits,
+    "ouro_stack": OuroStack,
+    "plain_token_logits": PlainTokenLogits,
 }
 
 

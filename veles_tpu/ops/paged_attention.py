@@ -164,6 +164,40 @@ def grouped_attend(q, keys, values, qpos, kv_heads):
                           b, s, heads * hd)
 
 
+def single_query_attend(q, keys, values, pos):
+    """Causal attention of ONE query a row over full heads: ``q``
+    [b, heads, hd] at position ``pos`` [b] over ``keys``/``values``
+    [b, L, heads * hd], key row i at position i.  The gathered rows
+    stay ``[L, heads * hd]`` matrices, as the pools hold them: the
+    scores are ``keys @ Q`` with Q [heads * hd, heads] holding head
+    h's query in its own rows of column h and zeros elsewhere, and the
+    context is row h's own columns of ``probs[heads, L] @ values``.
+    Both are plain products over rows read once (the batched einsum of
+    :func:`grouped_attend` at one query a head makes the compiler
+    widen and relay the rows head by head in HBM first); the zeros add
+    nothing, so the arithmetic is :func:`grouped_attend`'s: operands
+    and probabilities in the compute dtype, float32 sums and softmax.
+    -> [b, 1, heads * hd] float32."""
+    from veles_tpu import dtypes
+    cd = dtypes.compute_dtype()
+    b, heads, hd = q.shape
+    d = heads * hd
+    length = keys.shape[1]
+    own = jnp.arange(d)[:, None] // hd == jnp.arange(heads)[None, :]
+    by_head = jnp.where(own[None], q.reshape(b, d, 1), 0).astype(cd)
+    scores = jnp.einsum("bld,bdh->blh", keys.astype(cd), by_head,
+                        precision=dtypes.matmul_precision(),
+                        preferred_element_type=jnp.float32) \
+        * (1.0 / jnp.sqrt(jnp.float32(hd)))
+    mask = jnp.arange(length)[None, :] <= pos[:, None]
+    scores = jnp.where(mask[..., None], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=1).astype(cd)
+    full = jnp.einsum("blh,bld->bhd", probs, values.astype(cd),
+                      precision=dtypes.matmul_precision(),
+                      preferred_element_type=jnp.float32)
+    return jnp.where(own.T[None], full, 0).sum(axis=1).reshape(b, 1, d)
+
+
 def paged_decode_attention(q, k_new, v_new, pool_k, pool_v, tables,
                            pos, heads, kv_heads=None):
     """One decode position per row against a paged KV pool.
@@ -181,7 +215,9 @@ def paged_decode_attention(q, k_new, v_new, pool_k, pool_v, tables,
     ``kv_heads`` (default None: the path above): grouped-query attention — the
     pools hold ``kv_heads`` heads a row (``[blocks, bs, kv_heads·hd]``)
     under ``heads`` query heads, attended by :func:`grouped_attend`
-    (float32 scores; context float32)."""
+    (float32 scores; context float32), or by
+    :func:`single_query_attend` where every query head has its own
+    (``kv_heads == heads``)."""
     from veles_tpu import dtypes
     cd = dtypes.compute_dtype()
     b, _, d = q.shape
@@ -194,6 +230,10 @@ def paged_decode_attention(q, k_new, v_new, pool_k, pool_v, tables,
     pv = pool_v.at[blk, off].set(v_new[:, 0].astype(pool_v.dtype))
     if kv_heads is not None:
         rows = tables.shape[1] * bs
+        if kv_heads == heads:
+            return pk, pv, single_query_attend(
+                q.reshape(b, h, hd), pk[tables].reshape(b, rows, -1),
+                pv[tables].reshape(b, rows, -1), pos)
         return pk, pv, grouped_attend(
             q.reshape(b, 1, h, hd), pk[tables].reshape(b, rows, -1),
             pv[tables].reshape(b, rows, -1), pos[:, None], kv_heads)

@@ -99,9 +99,11 @@ def hidden_supported(forwards):
         and not hasattr(last, "init_cache")
 
 
-#: key of the routed layers' counts in the pools a paged step returns
-#: (an int like the chain indices: a pytree's keys must sort)
+#: keys of the routed layers' counts and of a looped stack's counts in
+#: the pools a paged step returns (ints like the chain indices: a
+#: pytree's keys must sort)
 MOE_COUNTS = -1
+STACK_COUNTS = -2
 
 
 def _make_paged_step(forwards, want_hidden=False):
@@ -118,7 +120,7 @@ def _make_paged_step(forwards, want_hidden=False):
         h = toks[:, None]
         hid = None
         out = dict(pools)
-        moe = []
+        moe, stack = [], []
         for i, u in enumerate(forwards):
             if want_hidden and i == last:
                 # the final unit's INPUT is the target's last hidden
@@ -130,6 +132,8 @@ def _make_paged_step(forwards, want_hidden=False):
                     **({"slots": slots} if i in by_slot else {}))
                 if "moe" in out[i]:   # a routed layer's counts
                     moe.append(out[i].pop("moe"))
+                if "stack" in out[i]:  # a looped stack's counts
+                    stack.append(out[i].pop("stack"))
             elif hasattr(u, "apply_step_slots"):
                 h = u.apply_step_slots(params[i], h, pos)
             else:
@@ -139,6 +143,8 @@ def _make_paged_step(forwards, want_hidden=False):
         nxt = sample_slots(logits, temps, topks, keys)
         if moe:   # ONE small array a step: [routed layers, 4]
             out[MOE_COUNTS] = jnp.stack(moe)
+        if stack:  # ONE small array a step: [stacks, 2 + passes]
+            out[STACK_COUNTS] = jnp.stack(stack)
         if want_hidden:
             return nxt, hid[:, 0], out
         return nxt, out
@@ -272,7 +278,10 @@ def paged_decode_step(forwards, cache, toks, pos, tables, temps,
     (the default for every row): what a unit with per-slot state
     indexes its state pool by.  A chain with routed layers leaves
     their counts of this step, one int32 [layers, 4] device array, in
-    ``cache.moe_counts`` (None otherwise).
+    ``cache.moe_counts`` (None otherwise); a chain with a looped stack
+    leaves its counts, float32 [stacks, 2 + passes] = (passes run, live
+    rows, the live rows' exit mass of each pass), in
+    ``cache.stack_counts``.
 
     A cache built with a tensor-parallel context (``cache.tp_`` —
     serving/tp.py) runs the step SPMD over the tp mesh: ``params`` ride
@@ -339,6 +348,7 @@ def paged_decode_step(forwards, cache, toks, pos, tables, temps,
         else numpy.asarray(slots, numpy.int32), cache.pools)
     pools = got[-1]
     cache.moe_counts = pools.pop(MOE_COUNTS, None)
+    cache.stack_counts = pools.pop(STACK_COUNTS, None)
     cache.pools = pools
     cache.note_swap(old)
     cache.token_sharding = got[0].sharding if got[0].committed else None

@@ -76,6 +76,25 @@ _insert_blocks = track_jit("serving.kv_insert_blocks", jax.jit(
     donate_argnums=(0, 1)))
 
 
+def _stack_block_pair(pool_k, pool_v, src_k, src_v, ids, start):
+    # _block_pair for a unit whose cache is several cache layers behind
+    # one block table: src [C, 1, W, d] -> pool [C, blocks, bs, d], every
+    # cache layer's blocks in ONE dispatch (not one a cache layer)
+    n = ids.shape[0]
+    c, _, bs, d = pool_k.shape
+
+    def staged(src):
+        return jax.lax.dynamic_slice_in_dim(
+            src[:, 0], start, n * bs, axis=1).reshape(c, n, bs, d)
+    return (pool_k.at[:, ids].set(staged(src_k).astype(pool_k.dtype)),
+            pool_v.at[:, ids].set(staged(src_v).astype(pool_v.dtype)))
+
+
+_insert_stack_blocks = track_jit("serving.kv_insert_blocks", jax.jit(
+    trace_named("serving.kv_insert_blocks", _stack_block_pair),
+    donate_argnums=(0, 1)))
+
+
 @functools.lru_cache(maxsize=1)
 def _gather_blocks_jit():
     # built lazily (no module-level executable ref): the prefix-cache
@@ -169,13 +188,25 @@ def _import_blocks_jit():
                      jax.jit(pair, donate_argnums=(0, 1)))
 
 
+def _units_of_kind(forwards, kind):
+    return {i: u.name for i, u in enumerate(forwards)
+            if hasattr(u, "init_cache")
+            and getattr(u, "cache_kind", "paged") == kind}
+
+
 def slot_state_units(forwards):
     """{chain index: unit name} of the cacheable units whose cache is
     ONE fixed state per slot (``cache_kind == "slot"``: a short
     convolution's last rows) and not rows that grow with the text."""
-    return {i: u.name for i, u in enumerate(forwards)
-            if hasattr(u, "init_cache")
-            and getattr(u, "cache_kind", "paged") == "slot"}
+    return _units_of_kind(forwards, "slot")
+
+
+def stacked_units(forwards):
+    """{chain index: unit name} of the cacheable units whose cache is
+    SEVERAL cache layers of paged rows behind one block table
+    (``cache_kind == "stack"``: a stack run several times a token keeps
+    a K/V row pair for every layer application)."""
+    return _units_of_kind(forwards, "stack")
 
 
 def state_refusal(what, units):
@@ -185,6 +216,28 @@ def state_refusal(what, units):
         "%s is not carried for a chain with per-slot state (%s): "
         "blocks alone do not hold its requests"
         % (what, ", ".join(sorted(units.values()))))
+
+
+def stack_refusal(what, units):
+    """The error of asking, for a chain that holds a stack of cache
+    layers behind one block table, for what does not carry it
+    (``units``: :func:`stacked_units`)."""
+    return ValueError(
+        "%s is not carried for a stack of cache layers behind one block "
+        "table (%s): its programs move one layer's K and V pair"
+        % (what, ", ".join(sorted(units.values()))))
+
+
+def blocks_only_refusal(what, state_units, stack_units):
+    """The error of asking for ``what``, which moves blocks of ONE
+    layer's K and V pair, on a chain that holds more than such blocks
+    (:func:`slot_state_units`, :func:`stacked_units`); None when the
+    chain holds nothing else."""
+    if state_units:
+        return state_refusal(what, state_units)
+    if stack_units:
+        return stack_refusal(what, stack_units)
+    return None
 
 
 def _state_rows(pool, src, slot):
@@ -246,7 +299,18 @@ class PagedKVCache:
     admission count the paged layers alone, :meth:`state_bytes`
     gives both.  A prefix of blocks says nothing about such a state,
     so block export/import, the warm gather, int8 pools and a tp mesh
-    refuse a chain that has one."""
+    refuse a chain that has one.
+
+    THE THIRD KIND: A STACK.  A unit that is a whole stack of layers
+    run several times a token (:func:`stacked_units`) holds a K/V row
+    pair for every layer application: its pool is ONE array pair
+    ``[cache layers, num_blocks, block_size, d]`` behind the same block
+    table, its staging ``[cache layers, 1, width, d]``.  A block id
+    names that block of every cache layer, so admission, release and
+    the tables are the paged kind's; ``bytes_per_token`` counts every
+    cache layer, and :meth:`insert` scatters all of them in one
+    dispatch.  The programs that move ONE layer's pair (export/import,
+    the warm gather, int8 pools, a tp mesh) refuse it in words."""
 
     #: state-returning calls that came back / that copied
     pool_swaps = pool_copies = 0
@@ -271,6 +335,7 @@ class PagedKVCache:
             raise ValueError("need kv_blocks >= 1")
         num = self.capacity_blocks + 1          # + the trash block 0
         self.state_units = slot_state_units(forwards)
+        self.stack_units = stacked_units(forwards)
         if tp is not None:
             self._blocks_only("tp")
         if kv_dtype == "int8":
@@ -327,6 +392,9 @@ class PagedKVCache:
         #: the routed layers' counts of the last decode step, a device
         #: array [layers, 4] (serving/engine.paged_decode_step)
         self.moe_counts = None
+        #: a looped stack's counts of the last decode step, a device
+        #: array [stacks, 2 + passes] (serving/engine.paged_decode_step)
+        self.stack_counts = None
         #: where the last decode step left its tokens, if it COMMITTED
         #: them there (None before the first step, and for a step over
         #: uncommitted parameters): the step places host tokens alike,
@@ -365,9 +433,9 @@ class PagedKVCache:
             for name, arr in layer.items():
                 if name.endswith("_scale"):   # one scale per row
                     total += arr.dtype.itemsize
-                else:
-                    total += arr.shape[-1] * arr.dtype.itemsize \
-                        // shards
+                else:    # a row, of every cache layer of a stack
+                    total += int(numpy.prod(arr.shape[:-3])) \
+                        * arr.shape[-1] * arr.dtype.itemsize // shards
         return int(total)
 
     def state_bytes(self):
@@ -409,11 +477,13 @@ class PagedKVCache:
         self.pools = jax.tree.map(
             lambda a: jnp.zeros(a.shape, a.dtype, device=a.sharding),
             self.pools)
-        self.moe_counts = None
+        self.moe_counts = self.stack_counts = None
 
     def _blocks_only(self, what):
-        if self.state_units:
-            raise state_refusal(what, self.state_units)
+        refused = blocks_only_refusal(what, self.state_units,
+                                      self.stack_units)
+        if refused is not None:
+            raise refused
 
     def blocks_needed(self, total_tokens):
         return -(-max(int(total_tokens), 1) // self.block_size)
@@ -570,7 +640,7 @@ class PagedKVCache:
             if i in self.state_units:
                 self._insert_state(i, src, slot)
                 continue
-            wk = next(iter(src.values())).shape[1]
+            wk = next(iter(src.values())).shape[-2]
             if wk < need * self.block_size:
                 raise ValueError(
                     "staging width %d < %d blocks x %d" %
@@ -584,8 +654,10 @@ class PagedKVCache:
                 self.pools[i] = {"k": k, "v": v, "k_scale": sk,
                                  "v_scale": sv}
             else:
-                k, v = _insert_blocks(old, layer["v"], src["k"],
-                                      src["v"], ids, start)
+                fn = _insert_stack_blocks if i in self.stack_units \
+                    else _insert_blocks
+                k, v = fn(old, layer["v"], src["k"], src["v"], ids,
+                          start)
                 self.pools[i] = {"k": k, "v": v}
             self.note_swap(old)
 

@@ -358,6 +358,25 @@ def _registry_series():
             "veles_serving_moe_hottest_rows_total",
             "live rows on the most loaded expert, summed over routed "
             "layers and decode steps"),
+        "stack_passes": metrics.counter(
+            "veles_serving_stack_passes_total",
+            "passes of a looped stack that decode steps ran: decode "
+            "steps x the passes of each step"),
+        "stack_rows": metrics.counter(
+            "veles_serving_stack_rows_total",
+            "live rows of the decode steps of a looped stack (padding "
+            "rows excluded): what the exit mass is a share of"),
+        "stack_exit_mass": metrics.counter(
+            "veles_serving_stack_exit_mass_total",
+            "exit distribution of a looped stack summed over the live "
+            "rows of decode steps, by pass: the mass the exit gate puts "
+            "on leaving after that pass (reported, never applied; the "
+            "passes of a row sum to 1)",
+            labelnames=("pass",)),
+        "stack_exit_mass_before_last": metrics.counter(
+            "veles_serving_stack_exit_mass_before_last_total",
+            "the same mass summed over every pass but the last: the "
+            "share of passes an adaptive exit could skip"),
         "pool_copies": metrics.counter(
             "veles_serving_pool_copies_total",
             "calls that return the cache's device state (decode and "
@@ -1318,7 +1337,8 @@ class ServingMetrics:
             self._loop["step_after_prefill"].inc(
                 step_after_prefill_seconds)
 
-    def record_step(self, active, slots, tokens=None, moe=None):
+    def record_step(self, active, slots, tokens=None, moe=None,
+                    stack=None):
         """One batched decode/verify boundary: ``active`` real rows
         rode a padded ``slots``-row bucket; ``tokens`` is what the
         step actually emitted (spec verify can emit up to k+1 per
@@ -1328,7 +1348,19 @@ class ServingMetrics:
         last healthy rate).  The step's seconds are the loop's
         ``step`` phase (:meth:`record_loop_pass`).  ``moe``: the
         routed layers' counts of a decode step, int [layers, 4] =
-        (1, pairs, experts touched, rows on the hottest expert)."""
+        (1, pairs, experts touched, rows on the hottest expert).
+        ``stack``: a looped stack's counts of a decode step, float
+        [stacks, 2 + passes] = (passes run, live rows, the live rows'
+        exit mass of each pass)."""
+        if stack is not None:
+            passes, rows, *mass = stack.sum(axis=0).tolist()
+            self._global["stack_passes"].inc(passes)
+            self._global["stack_rows"].inc(rows)
+            for r, m in enumerate(mass):
+                self._global["stack_exit_mass"].labels(
+                    **{"pass": str(r)}).inc(m)
+            self._global["stack_exit_mass_before_last"].inc(
+                sum(mass[:-1]))
         if moe is not None:
             for name, total in zip(
                     ("moe_layer_steps", "moe_pairs",

@@ -166,7 +166,7 @@ def prefill_chunk(forwards, chunk, offset, chunk_lens, caches,
     chunk = jnp.asarray(chunk, jnp.int32)
     b, c = chunk.shape
     state = slot_state_units(forwards)   # a fixed state has no width
-    widths = {tuple(a.shape[1] for a in layer.values())
+    widths = {tuple(a.shape[-2] for a in layer.values())
               for i, layer in caches.items() if i not in state}
     w = next(iter(widths))[0]
     if any(x != w for tup in widths for x in tup):

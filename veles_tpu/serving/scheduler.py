@@ -214,7 +214,7 @@ from veles_tpu.serving.engine import (
     verify_supported)
 from veles_tpu.serving.kv_host import HostKVTier
 from veles_tpu.serving.kv_slots import (
-    PagedKVCache, slot_state_units, state_refusal)
+    PagedKVCache, blocks_only_refusal, slot_state_units, stacked_units)
 from veles_tpu.serving.metrics import ServingMetrics
 from veles_tpu.serving.prefill import (
     chunked_supported, prefill, prefill_chunk, serving_refusal,
@@ -516,15 +516,16 @@ class _Request(object):
 class _Flight(object):
     """A decode step that was launched and has not been read: its
     packed slot order, the request of each row, the device arrays it
-    will hand over (a bucket of tokens; the routed layers' counts and
-    the hidden state where the chain has them) and its launch time.  Loop thread
+    will hand over (a bucket of tokens; the routed layers' counts, a
+    looped stack's counts and the hidden state where the chain has
+    them) and its launch time.  Loop thread
     only; at most one exists (``InferenceScheduler._flight``)."""
 
-    __slots__ = ("slots", "reqs", "nxt", "hid", "moe", "t0")
+    __slots__ = ("slots", "reqs", "nxt", "hid", "moe", "stack", "t0")
 
-    def __init__(self, slots, reqs, nxt, hid, moe):
+    def __init__(self, slots, reqs, nxt, hid, moe, stack):
         self.slots, self.reqs = slots, reqs
-        self.nxt, self.hid, self.moe = nxt, hid, moe
+        self.nxt, self.hid, self.moe, self.stack = nxt, hid, moe, stack
         self.t0 = time.perf_counter()
 
 
@@ -575,6 +576,10 @@ class InferenceScheduler(Logger):
         #: argument; asked for by the configuration's default alone it
         #: is turned off and said so (`_without_state`)
         self._state_units = slot_state_units(forwards)
+        #: units whose cache is a STACK of cache layers behind one
+        #: block table: the same options move one layer's K and V pair
+        #: and are refused or turned off the same way
+        self._stack_units = stacked_units(forwards)
         self.block_size = int(
             block_size or _serving_conf("block_size", 16))
         if self.block_size < 1:
@@ -829,16 +834,19 @@ class InferenceScheduler(Logger):
         """The value of an option that per-slot state is not carried
         through: ``asked`` (the argument; None: not given) or else the
         configuration's ``default``.  On a chain with such state
-        (``_state_units``) an option that was asked for is refused in
-        words, and one that only the configuration's default turns on
-        is turned off and logged; never run wrongly."""
+        (``_state_units``), or with a stack of cache layers behind one
+        block table (``_stack_units``), an option that was asked for is
+        refused in words, and one that only the configuration's default
+        turns on is turned off and logged; never run wrongly."""
         value = default if asked is None else asked
-        if not value or not self._state_units:
+        if not value or not (self._state_units or self._stack_units):
             return value
         if asked is not None:
-            raise state_refusal(what, self._state_units)
-        self.info("%s off: not carried for per-slot state (%s)", what,
-                  ", ".join(sorted(self._state_units.values())))
+            raise blocks_only_refusal(what, self._state_units,
+                                      self._stack_units)
+        self.info("%s off: not carried for %s", what, ", ".join(sorted(
+            list(self._state_units.values())
+            + list(self._stack_units.values()))))
         return type(value)()
 
     # -- client side ----------------------------------------------------
@@ -2892,9 +2900,11 @@ class InferenceScheduler(Logger):
                 seeds, counts, want_hidden=want_h,
                 params=self.weights_.params, slots=rows)
             nxt, hid = got if want_h else (got, None)
-            # the routed layers' counts come with the step
+            # the routed layers' and a looped stack's counts come
+            # with the step
             self._flight = _Flight(slots, [active[s] for s in slots],
-                                   nxt, hid, cache.moe_counts)
+                                   nxt, hid, cache.moe_counts,
+                                   cache.stack_counts)
         if ahead:
             self._phases.steps_ahead += 1
             self._land_flight(cache, flight)
@@ -2926,6 +2936,8 @@ class InferenceScheduler(Logger):
             # computed by then: one small copy, no further wait
             moe = None if flight.moe is None \
                 else numpy.asarray(flight.moe)
+            stack = None if flight.stack is None \
+                else numpy.asarray(flight.stack)
         # the step's own stretch of the clock: from its launch, or
         # from the landing before it where the device ran the two
         # back to back
@@ -2940,7 +2952,7 @@ class InferenceScheduler(Logger):
         self._phases.rows_discarded += len(flight.slots) - n
         with self._phases("observe"):
             # plain decode: every live row emits exactly one token
-            self.stats.record_step(n, b, tokens=n, moe=moe)
+            self.stats.record_step(n, b, tokens=n, moe=moe, stack=stack)
             self._meter_step({slot: req for _, slot, req in rows},
                              cache, dt)
         with self._phases("emit"):
