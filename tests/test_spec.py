@@ -104,13 +104,23 @@ def _run_sched(fw, submits, window=64, check=False, **kw):
         sch.close()
 
 
-def test_spec_token_parity(f32):
+@pytest.fixture(params=["float32", "bfloat16"])
+def compute_dtype(request):
+    saved = root.common.precision.get("compute_dtype", "bfloat16")
+    root.common.precision.compute_dtype = request.param
+    yield request.param
+    root.common.precision.compute_dtype = saved
+
+
+def test_spec_token_parity(compute_dtype):
     """Acceptance: spec-on produces streams BIT-IDENTICAL to
     spec-off — greedy and seeded sampling, one-shot and chunked
     prefill, repetitive and non-repetitive prompts decoding
     concurrently — and the KV block sweep is clean after the
-    rollbacks."""
-    fw = _tiny_fw("spec-parity")
+    rollbacks.  Under bfloat16 too: an accepted token comes from the
+    verify step's scores and a plain one from the decode step's, and
+    both sum them in float32 over bfloat16 operands."""
+    fw = _tiny_fw("spec-parity-" + compute_dtype)
     prompts = [[3, 1, 4, 3, 1, 4, 3, 1], [5, 2] * 6, [7] * 5,
                [1, 2, 3, 4], [9, 8, 9, 8, 9]]
     submits = [(p, 12, dict(seed=0)) for p in prompts]

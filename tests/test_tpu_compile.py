@@ -353,20 +353,15 @@ def test_pools_are_written_in_place_on_v5e(name, one_chip,
     assert re.findall(r"= %s\S* copy\(" % shape, text) == []
 
 
-# -- compiled: the looped stack's step at its cell's size ---------------------
+# -- compiled: the decode steps relay no gathered rows and copy no weight ------
 
-def test_looped_step_is_in_place_and_copies_no_stacked_weight(
-        one_chip, no_compile_cache):
-    """The decode step of the looped stack (benchmark/configs/
-    ouro-2.6b.json under a narrow head), compiled for the described
-    chip at 8 rows and the 32-block bucket: both pools alias their
-    outputs, and no ``copy`` of a pool, of a stacked ``[48, ...]``
-    weight or of a layer's widened gathered rows is left (each was seen
-    while the step was written: the last two cost a layer application
-    134 MB of traffic and the step 1.6 GB)."""
+def _looped_program(one_chip):
+    """(jitted step, abstract arguments on the described chip, number
+    of pool arguments) of the looped stack (benchmark/configs/
+    ouro-2.6b.json under a narrow head) at 8 rows and the 32-block
+    bucket."""
     import json
     import os
-    import re
 
     import numpy
 
@@ -400,13 +395,86 @@ def test_looped_step_is_in_place_and_copies_no_stacked_weight(
     pool = arr(jnp.bfloat16, s["passes"] * n, blocks, 16, d)
     step = engine._paged_step_cached(
         "looped-in-place", _StepClosure(engine._make_paged_step(fw)))
-    text = step.lower(
+    return step, (
         params, arr(jnp.int32, b), arr(jnp.int32, b),
         arr(jnp.int32, b, t), arr(jnp.float32, b), arr(jnp.int32, b),
         arr(jnp.uint32, b), arr(jnp.int32, b), arr(jnp.int32, b),
-        {1: {"k": pool, "v": pool}}).compile().as_text()
-    assert text.split("\n", 1)[0].count("-alias)") == 2
+        {1: {"k": pool, "v": pool}}), 2
+
+
+def _opt67_tp2_program(topo):
+    """The same for OPT's step as a cache built with ``tp=2`` runs it
+    (``engine.paged_decode_step``'s GSPMD form: weights Megatron-wise,
+    pools head-wise, the attention per shard), two layers deep on two
+    of the described chips."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from veles_tpu.models.generate import _StepClosure
+    from veles_tpu.serving import engine
+    from veles_tpu.serving.tp import HEADWISE, ServingTP
+    fw, s = _opt67_chain(layers=2, vocab=1024)
+    ctx = ServingTP(2, devices=topo.devices)
+    b, t = 8, 64
+
+    def arr(dtype, *shape, spec=P()):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(ctx.mesh, spec))
+    params = {i: {name: arr(a.dtype, *a.shape,
+                            spec=u.tp_param_spec(name, 2) or P()
+                            if hasattr(u, "tp_param_spec") else P())
+                  for name, a in layer.items()}
+              for (i, layer), u in zip(
+                  _abstract_params(fw, True).items(), fw)}
+    pool = arr(jnp.bfloat16, 8 * 128 + 1, 16, s["dim"], spec=HEADWISE)
+    pools = {i: {"k": pool, "v": pool} for i, u in enumerate(fw)
+             if hasattr(u, "init_cache")}
+    step = engine._paged_step_cached(
+        "tp2-in-place", _StepClosure(engine._make_paged_step(
+            fw, attend=ctx.decode_attention)))
+    return step, (
+        params, arr(jnp.int32, b), arr(jnp.int32, b),
+        arr(jnp.int32, b, t), arr(jnp.float32, b), arr(jnp.int32, b),
+        arr(jnp.uint32, b), arr(jnp.int32, b), arr(jnp.int32, b),
+        pools), 2 * len(pools)
+
+
+def _big_copies(text):
+    """Shapes of the compiled module's ``copy`` ops of 2**22 elements
+    or more: a pool, a stacked or transposed weight, a layer's gathered
+    rows relaid (or widened) head by head."""
+    import math
+    import re
     copied = re.findall(r"= \w+\[([0-9,]+)\]\S* copy\(", text)
-    big = [shape for shape in copied
-           if numpy.prod([int(x) for x in shape.split(",")]) >= 1 << 22]
-    assert big == []
+    return [shape for shape in copied
+            if math.prod(int(x) for x in shape.split(",")) >= 1 << 22]
+
+
+@pytest.mark.parametrize("program", ["looped", "opt67", "opt67_tp2"])
+def test_decode_step_is_in_place_and_holds_no_big_copy(
+        program, topo, one_chip, no_compile_cache):
+    """The decode steps of both full-head chains, compiled for the
+    described chip at their cells' widths (the looped stack whole, OPT
+    two layers deep at 8 rows and the 64-block bucket, on one chip and
+    as a mesh of two partitions it): every pool aliases its output,
+    and no ``copy`` of 2**22 elements or more is left.  Each was seen: a pool (PR 30); a stacked ``[48, ...]``
+    weight and a layer's widened gathered rows while the looped step
+    was written (134 MB of traffic a layer application, 1.6 GB a
+    step); and in OPT's step, until its attention took the looped
+    stack's form, four gathered operands ``bf16[1024,8,32,128]``
+    relaid head by head and a transposed ``wq`` ``bf16[4096,4096]``
+    (PERF.md, PR 34).  The mesh of two sums across the chips twice a
+    block, after each row-parallel product, and not a third time for
+    the attention's scores (six all-reduces where the partitioner is
+    left the attention: ``ServingTP.decode_attention``)."""
+    import re
+    if program == "looped":
+        step, args, n_pools = _looped_program(one_chip)
+    elif program == "opt67":
+        step, args, n_pools = _pool_programs(one_chip)[1]["paged_step"]
+    else:
+        step, args, n_pools = _opt67_tp2_program(topo)
+    text = step.lower(*args).compile().as_text()
+    assert text.split("\n", 1)[0].count("-alias)") == n_pools
+    assert _big_copies(text) == []
+    assert len(re.findall(r" all-reduce(-start)?\(", text)) \
+        == (4 if program == "opt67_tp2" else 0)

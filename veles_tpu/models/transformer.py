@@ -486,7 +486,8 @@ class TransformerBlock(ForwardBase):
         dev = getattr(self, "device", None)
         return dev.jax_device.platform if dev else None
 
-    def apply_step_paged(self, params, x, pos, tables, pool):
+    def apply_step_paged(self, params, x, pos, tables, pool,
+                         attend=None):
         """Decode ONE position PER ROW against a PAGED KV pool: x
         [batch, 1, d] with row n at sequence index ``pos[n]``, reading
         and writing through ``tables`` [batch, T] physical block ids
@@ -497,7 +498,12 @@ class TransformerBlock(ForwardBase):
         (``k_scale`` beside the buffers) quantizes the new row on the
         scatter and dequantizes fused into the gather
         (ops/paged_attention.py q8 paths; the pallas kernel on
-        accelerator targets)."""
+        accelerator targets).
+
+        ``attend``: what stands in for
+        ``ops.paged_attention.paged_decode_attention`` over fp32
+        pools, same arguments and results (a tp step's per-shard
+        form: ``ServingTP.decode_attention``)."""
         from veles_tpu.ops.paged_attention import (
             paged_decode_attention, paged_decode_attention_q8)
         q, k_new, v_new = self._qkv(params, x)
@@ -509,7 +515,7 @@ class TransformerBlock(ForwardBase):
                 self.heads, backend=self._backend())
             return self._attn_tail(params, x, o, w8=w8), \
                 {"k": pk, "v": pv, "k_scale": sk, "v_scale": sv}
-        pk, pv, o = paged_decode_attention(
+        pk, pv, o = (attend or paged_decode_attention)(
             q, k_new, v_new, pool["k"], pool["v"], tables, pos,
             self.heads)
         return self._attn_tail(params, x, o, w8=w8), \
@@ -584,9 +590,10 @@ class TransformerBlock(ForwardBase):
         sequence index ``pos[n] + j``, ``lens`` [batch] marking how
         many positions are real (padding scatters to the trash
         block) — against the paged pool in ONE pass.  Position-for-
-        position the same math as :meth:`apply_step_paged` (its
-        K1 = 1 special case), so accepting the matched prefix of the
-        scored run reproduces sequential decode exactly.
+        position the arithmetic of :meth:`apply_step_paged` (which
+        spells its one query a row as two plain products), so
+        accepting the matched prefix of the scored run reproduces
+        sequential decode.
 
         INT8 pools always take the fused q8 verify (quantizing
         scatter + dequant-fused attend); fp32 pools take the PR 9
@@ -626,28 +633,20 @@ class TransformerBlock(ForwardBase):
         that the mask excludes.  Mirrors mha_apply's dense-core
         conventions (projection dtypes, 1/sqrt(hd) scaling, softmax
         over the key axis) so greedy decode is token-for-token
-        identical in f32."""
-        from veles_tpu import dtypes
-        cd = dtypes.compute_dtype()
+        identical in f32; the scores, softmax and sums are float32
+        over compute-dtype operands, as in every paged decode and
+        verify step (``ops.paged_attention.grouped_attend``)."""
+        from veles_tpu.ops.paged_attention import grouped_attend
         b, _, d = x.shape
         h = self.heads
-        hd = d // h
         q, k_new, v_new = self._qkv(params, x)
         ck = jax.lax.dynamic_update_slice(
             cache["k"], k_new.astype(cache["k"].dtype), (0, pos, 0))
         cv = jax.lax.dynamic_update_slice(
             cache["v"], v_new.astype(cache["v"].dtype), (0, pos, 0))
-        length = ck.shape[1]
-        qh = q.reshape(b, 1, h, hd)
-        kh = ck.astype(cd).reshape(b, length, h, hd)
-        vh = cv.astype(cd).reshape(b, length, h, hd)
-        logits = jnp.einsum("bqhd,bkhd->bhqk", qh, kh) \
-            * (1.0 / jnp.sqrt(hd))
-        mask = (jnp.arange(length) <= pos)[None, None, None, :]
-        logits = jnp.where(mask, logits, -jnp.inf)
-        probs = jax.nn.softmax(logits, axis=-1)
-        return self._attn_out(params, x, probs, vh), \
-            {"k": ck, "v": cv}
+        o = grouped_attend(q.reshape(b, 1, h, d // h), ck, cv,
+                           jnp.full((b, 1), pos), h)
+        return self._attn_tail(params, x, o), {"k": ck, "v": cv}
 
     def export_config(self):
         cfg = {"heads": self.heads, "hidden": int(self.hidden),

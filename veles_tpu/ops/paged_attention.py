@@ -24,13 +24,19 @@ into and reads from the trash block.
 
 The math is row-for-row the kv-cached scalar step
 (``TransformerBlock.apply_step``) restricted to the gathered
-key range — same projection dtypes, 1/sqrt(hd) scale and softmax
-conventions — so token streams are identical to ``generate()``'s
-(tested in tests/test_serving.py).  The width-K cousin
-:func:`paged_verify_attention` scores a run of K1 consecutive tokens
-per row in one pass — the speculative-decoding verify step
-(tests/test_spec.py proves spec-on/spec-off token parity).  This jnp formulation lowers
-to a gather + batched GEMM on every backend; the fused pallas kernel
+key range — same projection dtypes, 1/sqrt(hd) scale and ONE score
+convention, :func:`grouped_attend`'s: operands and probabilities in
+the compute dtype, float32 scores, softmax and sums — so token
+streams are ``generate()``'s (tests/test_serving.py in float32,
+tests/test_serving_weights.py in bfloat16).  A decode step's ONE
+query a row goes through :func:`single_query_attend` where every
+query head has its own K/V head (the transformer block, the looped
+stack), through :func:`grouped_attend` where fewer do (LFM2).  The
+width-K cousin :func:`paged_verify_attention` scores a run of K1
+consecutive tokens per row in one pass — the speculative-decoding
+verify step (tests/test_spec.py proves spec-on/spec-off token parity,
+in float32 and in bfloat16).  These jnp formulations lower
+to a gather + GEMMs on every backend; the fused pallas kernel
 (``ops/pallas_paged.py`` — gathered blocks stay in VMEM, dequant
 fused for int8 pools) slots in behind the same signatures on
 accelerator targets, the way ``ops/flash.py`` fronts the training
@@ -46,8 +52,10 @@ gather, per-head attention and the int8 per-row amax all partition
 over the feature axis without code changes here — GSPMD keeps each
 head's Q·K/probs·V chip-local (tp divides heads, so the
 ``[..., h, hd]`` reshape lands on whole heads), and only the output
-projection downstream reduces across chips.  The int8 scales stay
-replicated: their amax over the sharded axis reduces exactly, so
+projection downstream reduces across chips.  The one-query decode
+form has no head axis for the partitioner to follow, so a tp step
+runs it per shard (``ServingTP.decode_attention``).  The int8 scales
+stay replicated: their amax over the sharded axis reduces exactly, so
 the quantized pool bytes are bit-identical to an unsharded pool's.
 """
 
@@ -99,17 +107,19 @@ def paged_verify_attention(q, k_new, v_new, pool_k, pool_v, tables,
     padding never corrupts a live block; their output rows are
     garbage the caller must not read.
 
-    Position-for-position the same math as
-    :func:`paged_decode_attention` (which is the K1 = 1, lens = 1
-    special case): scatter first, then gather the table's blocks,
-    causal mask ``key ≤ pos[n] + j`` per query.  Because the scatter
-    lands before the gather, a query at position p sees the drafts
-    at positions ≤ p written THIS pass — exactly the cache state a
-    sequential per-token decode of those tokens would have produced.
+    Position-for-position the arithmetic of
+    :func:`paged_decode_attention` (one query a row, ``lens`` = 1,
+    spelt as two plain products there): scatter first, then gather
+    the table's blocks, causal mask ``key ≤ pos[n] + j`` per query,
+    :func:`grouped_attend`'s conventions (operands and probabilities
+    in the compute dtype, float32 scores, softmax and sums).  Because
+    the scatter lands before the gather, a query at position p sees
+    the drafts at positions ≤ p written THIS pass — exactly the cache
+    state a sequential per-token decode of those tokens would have
+    produced.
 
-    Returns ``(pool_k', pool_v', context)`` with context [B, K1, d]."""
-    from veles_tpu import dtypes
-    cd = dtypes.compute_dtype()
+    Returns ``(pool_k', pool_v', context)`` with context [B, K1, d]
+    float32."""
     b, k1, d = q.shape
     h = heads
     hd = d // h
@@ -121,20 +131,10 @@ def paged_verify_attention(q, k_new, v_new, pool_k, pool_v, tables,
     off = jnp.where(valid, qpos % bs, 0)
     pk = pool_k.at[blk, off].set(k_new.astype(pool_k.dtype))
     pv = pool_v.at[blk, off].set(v_new.astype(pool_v.dtype))
-    kg = pk[tables]
-    vg = pv[tables]
-    length = kg.shape[1] * bs
-    qh = q.reshape(b, k1, h, hd)
-    kh = kg.astype(cd).reshape(b, length, h, hd)
-    vh = vg.astype(cd).reshape(b, length, h, hd)
-    logits = jnp.einsum("bqhd,bkhd->bhqk", qh, kh) \
-        * (1.0 / jnp.sqrt(hd))
-    mask = (jnp.arange(length)[None, None, :]
-            <= qpos[:, :, None])[:, None]
-    logits = jnp.where(mask, logits, -jnp.inf)
-    probs = jax.nn.softmax(logits, axis=-1)
-    return pk, pv, jnp.einsum("bhqk,bkhd->bqhd", probs,
-                              vh).reshape(b, k1, d)
+    rows = tables.shape[1] * bs
+    return pk, pv, grouped_attend(
+        q.reshape(b, k1, h, hd), pk[tables].reshape(b, rows, d),
+        pv[tables].reshape(b, rows, d), qpos, h)
 
 
 def grouped_attend(q, keys, values, qpos, kv_heads):
@@ -172,11 +172,14 @@ def single_query_attend(q, keys, values, pos):
     scores are ``keys @ Q`` with Q [heads * hd, heads] holding head
     h's query in its own rows of column h and zeros elsewhere, and the
     context is row h's own columns of ``probs[heads, L] @ values``.
-    Both are plain products over rows read once (the batched einsum of
-    :func:`grouped_attend` at one query a head makes the compiler
-    widen and relay the rows head by head in HBM first); the zeros add
+    Both are plain products over rows read once (a batched einsum
+    with the heads as a batch axis, at one query a head, makes the
+    compiler relay the gathered rows head by head in HBM first, and
+    widen them where the sums are float32); the zeros add
     nothing, so the arithmetic is :func:`grouped_attend`'s: operands
     and probabilities in the compute dtype, float32 sums and softmax.
+    Every full-head chain's decode step takes it, whole or per tp
+    shard (:func:`paged_decode_attention`).
     -> [b, 1, heads * hd] float32."""
     from veles_tpu import dtypes
     cd = dtypes.compute_dtype()
@@ -209,17 +212,15 @@ def paged_decode_attention(q, k_new, v_new, pool_k, pool_v, tables,
     ``pos`` [B] ints, traced.
 
     Returns ``(pool_k', pool_v', context)`` — the pools with the new
-    K/V scattered in, and the attention context [B, 1, d] (same dtype
-    conventions as ``TransformerBlock.apply_step``).
+    K/V scattered in, and the attention context [B, 1, d] float32
+    (operands and probabilities in the compute dtype, float32 scores,
+    softmax and sums).
 
-    ``kv_heads`` (default None: the path above): grouped-query attention — the
+    ``kv_heads`` (default None: every query head has its own): the
     pools hold ``kv_heads`` heads a row (``[blocks, bs, kv_heads·hd]``)
-    under ``heads`` query heads, attended by :func:`grouped_attend`
-    (float32 scores; context float32), or by
-    :func:`single_query_attend` where every query head has its own
-    (``kv_heads == heads``)."""
-    from veles_tpu import dtypes
-    cd = dtypes.compute_dtype()
+    under ``heads`` query heads.  Full heads (None or ``== heads``)
+    attend through :func:`single_query_attend`, fewer through
+    :func:`grouped_attend`."""
     b, _, d = q.shape
     h = heads
     hd = d // h
@@ -228,36 +229,20 @@ def paged_decode_attention(q, k_new, v_new, pool_k, pool_v, tables,
     off = pos % bs
     pk = pool_k.at[blk, off].set(k_new[:, 0].astype(pool_k.dtype))
     pv = pool_v.at[blk, off].set(v_new[:, 0].astype(pool_v.dtype))
-    if kv_heads is not None:
-        rows = tables.shape[1] * bs
-        if kv_heads == heads:
+    rows = tables.shape[1] * bs
+    # the scope names the gather and the two products in each op's
+    # metadata (XLA names the fusions themselves, so a trace's event
+    # NAMES need not carry it: PERF.md, Open questions); the gather
+    # takes ONLY the table's blocks — [B, T, bs, d] -> [B, T·bs, d]:
+    # the window never materializes
+    with jax.named_scope("veles_paged_decode_attention"):
+        if kv_heads in (None, heads):
             return pk, pv, single_query_attend(
                 q.reshape(b, h, hd), pk[tables].reshape(b, rows, -1),
                 pv[tables].reshape(b, rows, -1), pos)
         return pk, pv, grouped_attend(
             q.reshape(b, 1, h, hd), pk[tables].reshape(b, rows, -1),
             pv[tables].reshape(b, rows, -1), pos[:, None], kv_heads)
-    # the scope names the gather + GEMM in each op's metadata (XLA
-    # names the fusions themselves, so a trace's event NAMES need not
-    # carry it: PERF.md, Open questions)
-    with jax.named_scope("veles_paged_decode_attention"):
-        # gather ONLY the table's blocks — [B, T, bs, d] ->
-        # [B, T·bs, d]; the window never materializes
-        kg = pk[tables]
-        vg = pv[tables]
-        length = kg.shape[1] * bs
-        qh = q.reshape(b, 1, h, hd)
-        kh = kg.astype(cd).reshape(b, length, h, hd)
-        vh = vg.astype(cd).reshape(b, length, h, hd)
-        logits = jnp.einsum("bqhd,bkhd->bhqk", qh, kh) \
-            * (1.0 / jnp.sqrt(hd))
-        mask = (jnp.arange(length)[None, :]
-                <= pos[:, None])[:, None, None, :]
-        logits = jnp.where(mask, logits, -jnp.inf)
-        probs = jax.nn.softmax(logits, axis=-1)
-        ctx = jnp.einsum("bhqk,bkhd->bqhd", probs,
-                         vh).reshape(b, 1, d)
-    return pk, pv, ctx
 
 
 # -- int8 quantized pools ---------------------------------------------------
@@ -421,14 +406,5 @@ def paged_verify_attention_fused(q, k_new, v_new, pool_k, pool_v,
     rows = jnp.arange(b)[:, None]
     kg = kg.at[rows, qpos].set(k_new.astype(cd))
     vg = vg.at[rows, qpos].set(v_new.astype(cd))
-    qh = q.reshape(b, k1, h, hd)
-    kh = kg.reshape(b, length, h, hd)
-    vh = vg.reshape(b, length, h, hd)
-    logits = jnp.einsum("bqhd,bkhd->bhqk", qh, kh) \
-        * (1.0 / jnp.sqrt(hd))
-    mask = (jnp.arange(length)[None, None, :]
-            <= qpos[:, :, None])[:, None]
-    logits = jnp.where(mask, logits, -jnp.inf)
-    probs = jax.nn.softmax(logits, axis=-1)
-    return pk, pv, jnp.einsum("bhqk,bkhd->bqhd", probs,
-                              vh).reshape(b, k1, d)
+    return pk, pv, grouped_attend(q.reshape(b, k1, h, hd), kg, vg,
+                                  qpos, h)

@@ -106,13 +106,17 @@ MOE_COUNTS = -1
 STACK_COUNTS = -2
 
 
-def _make_paged_step(forwards, want_hidden=False):
+def _make_paged_step(forwards, want_hidden=False, attend=None):
     cacheable = frozenset(i for i, u in enumerate(forwards)
                           if hasattr(u, "init_cache"))
     # units that keep per-slot state or count live rows are told which
     # slot each packed row is (-1: a padding row)
     by_slot = frozenset(i for i in cacheable
                         if hasattr(forwards[i], "cache_kind"))
+    # a tp step hands the units that declare a tp layout its
+    # per-shard attention (ServingTP.decode_attention)
+    told = frozenset(i for i in cacheable if attend is not None
+                     and hasattr(forwards[i], "tp_shardable"))
     last = len(forwards) - 1
 
     def step(params, toks, pos, tables, temps, topks, seeds, counts,
@@ -129,7 +133,8 @@ def _make_paged_step(forwards, want_hidden=False):
             if i in cacheable:
                 h, out[i] = u.apply_step_paged(
                     params[i], h, pos, tables, pools[i],
-                    **({"slots": slots} if i in by_slot else {}))
+                    **({"slots": slots} if i in by_slot else
+                       {"attend": attend} if i in told else {}))
                 if "moe" in out[i]:   # a routed layer's counts
                     moe.append(out[i].pop("moe"))
                 if "stack" in out[i]:  # a looped stack's counts
@@ -333,7 +338,9 @@ def paged_decode_step(forwards, cache, toks, pos, tables, temps,
     else:
         fn = _paged_step_cached(
             cache_key, _StepClosure(_make_paged_step(
-                forwards, want_hidden=want_hidden)))
+                forwards, want_hidden=want_hidden,
+                attend=ctx.decode_attention if ctx is not None
+                else None)))
     old = cache.first_leaf()
     got = fn(
         params, jnp.asarray(toks, jnp.int32),

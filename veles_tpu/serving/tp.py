@@ -27,11 +27,17 @@ points (``apply_prefill_chunk``, ``apply_step_paged``,
 runs SPMD over the mesh with no per-step host logic changes.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from veles_tpu.parallel.mesh import build_mesh
+
+#: how a paged layer's K/V pool ``[num_blocks, block_size, d]`` (and
+#: a decode step's q/k/v rows ``[B, 1, d]``) lie on the mesh: head-wise
+HEADWISE = P(None, None, "tp")
 
 
 def tp_allreduce(x, axis, size):
@@ -115,9 +121,26 @@ class ServingTP:
                     got[name] = jax.device_put(a, self.sharding(P()))
                 else:
                     got[name] = jax.device_put(
-                        a, self.sharding(P(None, None, "tp")))
+                        a, self.sharding(HEADWISE))
             out[i] = got
         return out
+
+    def decode_attention(self, q, k_new, v_new, pool_k, pool_v,
+                         tables, pos, heads):
+        """``ops.paged_attention.paged_decode_attention`` inside the
+        GSPMD step, each shard on its own heads over its own columns
+        of the head-wise pools.  Its one-query products contract over
+        the feature axis the mesh shards; a query column is zero
+        outside its own head's rows, which the partitioner cannot
+        know, so left to it every block sums its scores across the
+        chips (one more all-reduce a block: PERF.md, PR 34)."""
+        from veles_tpu.ops.paged_attention import paged_decode_attention
+        return jax.shard_map(
+            functools.partial(paged_decode_attention,
+                              heads=heads // self.size),
+            mesh=self.mesh, in_specs=(HEADWISE,) * 5 + (P(), P()),
+            out_specs=(HEADWISE,) * 3, check_vma=False)(
+                q, k_new, v_new, pool_k, pool_v, tables, pos)
 
 
 def per_chip_bytes(tree):
