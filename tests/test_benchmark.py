@@ -6,3 +6,4 @@ PR adds to ``BENCHMARK.json``."""
 from benchmark.tests.test_benchmark import *  # noqa: F401,F403
 from benchmark.tests.test_lfm2 import *  # noqa: F401,F403
 from benchmark.tests.test_ouro import *  # noqa: F401,F403
+from benchmark.tests.test_solar import *  # noqa: F401,F403

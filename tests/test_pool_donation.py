@@ -227,7 +227,7 @@ def test_lfm2_kv_pools_and_conv_state_are_donated(f32, call):
             z.astype(numpy.uint32), z, params=params,
             slots=numpy.asarray([slot], numpy.int32))
         assert numpy.asarray(nxt).shape == (1,)
-        assert numpy.asarray(cache.moe_counts).shape[1] == 4
+        assert numpy.asarray(cache.step_counts["moe"]).shape[1] == 4
         swaps = 1
     kinds = {"conv" if i in cache.state_units else "kv"
              for i in cache.pools}

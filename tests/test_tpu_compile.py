@@ -353,6 +353,97 @@ def test_pools_are_written_in_place_on_v5e(name, one_chip,
     assert re.findall(r"= %s\S* copy\(" % shape, text) == []
 
 
+def _solar_programs(one_chip):
+    """{name: (jitted entry point, abstract arguments on the described
+    chip, number of donated state leaves)} of a ``solar_open2`` chain at
+    its cell's widths (benchmark/configs/solar-open2-250b-ep8.json), one
+    GQA and one KDA layer deep under a narrow head, 16 rows at the
+    64-block bucket: paged K/V rows beside a per-slot state of two
+    arrays, the float32 one 4.2 MB a slot."""
+    import json
+    import os
+
+    import numpy
+
+    from veles_tpu.accelerated_units import AcceleratedWorkflow
+    from veles_tpu.memory import Array
+    from veles_tpu.models.generate import _StepClosure
+    from veles_tpu.models.standard import make_forwards
+    from veles_tpu.serving import engine, kv_slots
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs",
+                           "solar-open2-250b-ep8.json")) as f:
+        s = dict(json.load(f)["shapes"], vocab=1024)
+    block = dict(
+        type="solar_block", dim=s["dim"], hidden=s["expert_ffn"],
+        heads=s["heads"], kv_heads=s["kv_heads"],
+        head_dim=s["head_dim"], n_experts=s["experts"],
+        top_k=s["experts_per_token"], held=tuple(s["held"]))
+    fw = make_forwards(
+        AcceleratedWorkflow(None, name="solar-lowering"),
+        Array(numpy.zeros((1, s["positions"]), numpy.int32)),
+        [dict(type="embedding", vocab=s["vocab"], dim=s["dim"],
+              learned_positions=False),
+         dict(block, operator="gqa"), dict(block, operator="kda"),
+         dict(type="rms_token_logits", vocab=s["vocab"])])
+
+    def arr(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def abstract(tree):
+        return jax.tree.map(lambda a: arr(a.dtype, *a.shape), tree)
+    params = {0: {"weights": arr(jnp.bfloat16, s["vocab"], s["dim"])},
+              3: {"embedding_norm": arr(jnp.float32, s["dim"]),
+                  "weights": arr(jnp.bfloat16, s["dim"], s["vocab"])}}
+    for i in (1, 2):
+        params[i] = {
+            name: arr(jnp.bfloat16 if name in fw[i].MATMUL_PARAMS
+                      else jnp.float32, *shape)
+            for name, shape in fw[i].param_shapes().items()}
+    b, t, slots = 16, 64, 16
+    pools = {1: abstract(jax.eval_shape(
+        lambda: fw[1].init_cache(slots * t + 1, 16, jnp.bfloat16))),
+        2: abstract(jax.eval_shape(
+            lambda: fw[2].init_cache(slots + 1, 16, jnp.bfloat16)))}
+    staged = abstract(jax.eval_shape(
+        lambda: fw[2].init_cache(1, 256, jnp.bfloat16)))
+    step = engine._paged_step_cached(
+        "solar-in-place", _StepClosure(engine._make_paged_step(fw)))
+    return {
+        "paged_step": (step, (
+            params, arr(jnp.int32, b), arr(jnp.int32, b),
+            arr(jnp.int32, b, t), arr(jnp.float32, b),
+            arr(jnp.int32, b), arr(jnp.uint32, b), arr(jnp.int32, b),
+            arr(jnp.int32, b), pools), 4),
+        "kv_insert_state": (kv_slots._insert_state, (
+            pools[2], staged, arr(jnp.int32)), 2)}
+
+
+@pytest.mark.parametrize("name", ["kv_insert_state", "paged_step"])
+def test_matrix_state_is_written_in_place_on_v5e(name, one_chip,
+                                                 no_compile_cache):
+    """Compiled for the described chip at the cell's widths, the decode
+    step of a chain that keeps a float32 matrix state a slot beside its
+    paged rows, and the insert of a prefilled state, alias every donated
+    leaf to its output and copy no state pool whole (74 MB a layer).
+    The grouped products are the chip's own calls."""
+    import re
+    fn, args, donated = _solar_programs(one_chip)[name]
+    text = fn.lower(*args).compile().as_text()
+    head = text.split("\n", 1)[0]
+    assert head.count("-alias)") == donated, head[:400]
+    for pool in (r"f32\[17,64,128,128\]", r"bf16\[17,3,24576\]"):
+        assert re.findall(r"= %s\S* copy\(" % pool, text) == []
+    if name == "paged_step":
+        assert "custom-call" in text
+        # the program's named scopes ride every op's metadata: what a
+        # reader of the device trace can tell the parts of a layer by
+        for scope in ("veles_solar_kda_conv", "veles_solar_kda_state",
+                      "veles_solar_gqa", "veles_solar_held_experts",
+                      "veles_solar_shared_expert"):
+            assert scope in text, scope
+
+
 # -- compiled: the decode steps relay no gathered rows and copy no weight ------
 
 def _looped_program(one_chip):

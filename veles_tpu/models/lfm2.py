@@ -64,16 +64,26 @@ def rotary(x, positions, theta):
         + jnp.concatenate([-x2, x1], axis=-1) * jnp.sin(angle)
 
 
-def routed_ffn(params, u, top_k, norm_topk, scaling, live=None):
-    """u [n, d] float32 -> ([n, d] float32, int32[4] counts of this
-    call: 1, routed pairs, experts touched, rows on the hottest
-    expert).  ``live`` [n] bool: rows that are not live take the
-    experts of row 0, so they open no expert of their own, and count
-    nothing."""
+def routed_ffn(params, u, top_k, norm_topk, scaling, live=None,
+               held=None):
+    """u [n, d] float32 -> ([n, d] float32, int32 counts of this call:
+    1, routed pairs, experts touched, rows on the hottest expert).
+    ``live`` [n] bool: rows that are not live take the experts of row
+    0, so they open no expert of their own, and count nothing.
+
+    ``held`` = (first, count): the router is as wide as the model's
+    experts, ``expert_w*`` hold experts [first, first + count) alone,
+    and only the chosen pairs that fall there are computed; the others
+    enter no group of the grouped products and add nothing (an
+    expert-parallel layer's share, without its exchange).  The pairs
+    still count all the live rows' choices, the experts touched and the
+    hottest rows are of the held experts, and a fifth count gives the
+    pairs that fell on them.  None: every expert is here, four
+    counts."""
     from veles_tpu import dtypes
     cd = dtypes.compute_dtype()
     n, d = u.shape
-    n_experts = params["expert_w1"].shape[0]
+    n_groups = params["expert_w1"].shape[0]
     s = jax.nn.sigmoid(jnp.matmul(
         u, params["router"].astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
@@ -87,9 +97,14 @@ def routed_ffn(params, u, top_k, norm_topk, scaling, live=None):
     if norm_topk:
         gate = gate / (gate.sum(axis=-1, keepdims=True) + 1e-6)
     gate = gate * scaling
-    flat = chosen.reshape(-1)
+    if held is None:
+        flat = chosen.reshape(-1)
+    else:       # the absent experts' pairs sort past the last group
+        here = (chosen >= held[0]) & (chosen < held[0] + held[1])
+        flat = jnp.where(here, chosen - held[0], n_groups).reshape(-1)
     order = jnp.argsort(flat, stable=True)
-    sizes = jnp.zeros((n_experts,), jnp.int32).at[flat].add(1)
+    sizes = jnp.zeros((n_groups,), jnp.int32).at[flat].add(
+        1, mode="drop")
     rows = u.astype(cd)[order // top_k]
 
     def grouped(x, w):
@@ -100,12 +115,18 @@ def routed_ffn(params, u, top_k, norm_topk, scaling, live=None):
     hid = (jax.nn.silu(grouped(rows, params["expert_w1"]))
            * grouped(rows, params["expert_w3"])).astype(cd)
     out = grouped(hid, params["expert_w2"])[jnp.argsort(order)]
-    out = (out.reshape(n, top_k, d) * gate[..., None]).sum(axis=1)
-    counted = jnp.zeros((n_experts,), jnp.int32).at[flat].add(
-        jnp.repeat(live.astype(jnp.int32), top_k))
-    return out, jnp.stack([jnp.int32(1), counted.sum(),
-                           (counted > 0).sum().astype(jnp.int32),
-                           counted.max()])
+    out = out.reshape(n, top_k, d)
+    if held is not None:    # rows past the groups hold no product:
+        out = jnp.where(here[..., None], out, 0.0)   # they add nothing
+    out = (out * gate[..., None]).sum(axis=1)
+    lives = jnp.repeat(live.astype(jnp.int32), top_k)
+    counted = jnp.zeros((n_groups,), jnp.int32).at[flat].add(
+        lives, mode="drop")
+    counts = [jnp.int32(1), counted.sum() if held is None
+              else lives.sum(), (counted > 0).sum().astype(jnp.int32),
+              counted.max()]
+    return out, jnp.stack(counts + ([] if held is None
+                                    else [counted.sum()]))
 
 
 class Lfm2Block(ForwardBase):
@@ -287,7 +308,7 @@ class Lfm2Block(ForwardBase):
         ``cache`` (TransformerBlock.apply_prefill_chunk's contract: K/V
         rows at or past a row's ``chunk_lens`` are zeroed; the conv
         state stops at it)."""
-        from veles_tpu.ops.paged_attention import grouped_attend
+        from veles_tpu.ops.paged_attention import staged_chunk_attend
         b, c, _ = x.shape
         u = self._normed(params, x)
         if self.operator == "conv":
@@ -296,22 +317,10 @@ class Lfm2Block(ForwardBase):
         positions = offset + jnp.arange(c)[None, :] \
             + jnp.zeros((b, 1), jnp.int32)
         q, k_new, v_new = self._qkv(params, u, positions)
-        k_new = k_new.reshape(b, c, -1)
-        if chunk_lens is not None:
-            keep = (jnp.arange(c)[None, :]
-                    < chunk_lens[:, None])[..., None]
-            k_new = jnp.where(keep, k_new, 0)
-            v_new = jnp.where(keep, v_new, 0)
-        at = (jnp.int32(0), offset, jnp.int32(0))
-        ck = jax.lax.dynamic_update_slice(
-            cache["k"], k_new.astype(cache["k"].dtype), at)
-        cv = jax.lax.dynamic_update_slice(
-            cache["v"], v_new.astype(cache["v"].dtype), at)
-        kw = int(key_width or ck.shape[1])
-        ctx = grouped_attend(q, ck[:, :kw], cv[:, :kw], positions,
-                             self.kv_heads)
-        return self._tail(params, x, _dot(ctx, params["wo"]))[0], \
-            {"k": ck, "v": cv}
+        ctx, rows = staged_chunk_attend(
+            q, k_new.reshape(b, c, -1), v_new, cache, offset,
+            chunk_lens, key_width, self.kv_heads)
+        return self._tail(params, x, _dot(ctx, params["wo"]))[0], rows
 
     def apply_step_paged(self, params, x, pos, tables, pool,
                          slots=None):

@@ -22,6 +22,7 @@ from veles_tpu.models.embedding import Embedding
 from veles_tpu.models.lfm2 import Lfm2Block, NormedTokenLogits
 from veles_tpu.models.moe import MoE
 from veles_tpu.models.ouro import OuroStack, PlainTokenLogits
+from veles_tpu.models.solar import SolarBlock
 from veles_tpu.models.transformer import MeanPoolSeq, TransformerBlock, TokenProjection
 from veles_tpu.models.all2all import (
     All2All, All2AllRELU, All2AllSigmoid, All2AllSoftmax,
@@ -63,6 +64,7 @@ LAYER_TYPES = {
     "last_timestep": LastTimestep,
     "token_logits": TokenProjection,
     "lfm2_block": Lfm2Block,
+    "solar_block": SolarBlock,
     "rms_token_logits": NormedTokenLogits,
     "ouro_stack": OuroStack,
     "plain_token_logits": PlainTokenLogits,

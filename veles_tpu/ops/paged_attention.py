@@ -164,6 +164,33 @@ def grouped_attend(q, keys, values, qpos, kv_heads):
                           b, s, heads * hd)
 
 
+def staged_chunk_attend(q, k_new, v_new, cache, offset, chunk_lens,
+                        key_width, kv_heads):
+    """A prefill chunk's grouped attention over its staging rows: ``q``
+    [b, C, heads, hd] and the chunk's new ``k_new``/``v_new``
+    [b, C, kv_heads * hd] at positions [offset, offset + C) continuing
+    ``cache`` = {"k", "v"} [b, W, kv_heads * hd].  The new rows are
+    written at ``offset`` (those at or past a row's ``chunk_lens``
+    zeroed, as a one-shot prefill's ragged rows) and the queries attend
+    causally over the first ``key_width`` (default W) rows.
+    -> (context [b, C, heads * hd] float32, {"k", "v"})."""
+    b, c = q.shape[:2]
+    positions = offset + jnp.arange(c)[None, :] \
+        + jnp.zeros((b, 1), jnp.int32)
+    if chunk_lens is not None:
+        keep = (jnp.arange(c)[None, :] < chunk_lens[:, None])[..., None]
+        k_new = jnp.where(keep, k_new, 0)
+        v_new = jnp.where(keep, v_new, 0)
+    at = (jnp.int32(0), offset, jnp.int32(0))
+    ck = jax.lax.dynamic_update_slice(
+        cache["k"], k_new.astype(cache["k"].dtype), at)
+    cv = jax.lax.dynamic_update_slice(
+        cache["v"], v_new.astype(cache["v"].dtype), at)
+    kw = int(key_width or ck.shape[1])
+    return grouped_attend(q, ck[:, :kw], cv[:, :kw], positions,
+                          kv_heads), {"k": ck, "v": cv}
+
+
 def single_query_attend(q, keys, values, pos):
     """Causal attention of ONE query a row over full heads: ``q``
     [b, heads, hd] at position ``pos`` [b] over ``keys``/``values``

@@ -15,7 +15,7 @@ RESTfulAPI unit the same way; veles/restful_api.py:78).
 
 Without a snapshot, ``root.serve.layers`` (a ``layers`` spec as
 ``models/standard.make_forwards`` takes it, e.g. an embedding, some
-``lfm2_block`` and a ``rms_token_logits``) builds the chain with the
+``lfm2_block`` or ``solar_block`` and a ``rms_token_logits``) builds the chain with the
 units' own seeded filling, and ``root.serve.window`` bounds a request
 where no positional table does (rotary positions).
 

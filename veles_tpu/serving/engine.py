@@ -99,11 +99,10 @@ def hidden_supported(forwards):
         and not hasattr(last, "init_cache")
 
 
-#: keys of the routed layers' counts and of a looped stack's counts in
-#: the pools a paged step returns (ints like the chain indices: a
-#: pytree's keys must sort)
-MOE_COUNTS = -1
-STACK_COUNTS = -2
+#: keys of the counts that ride the pools a paged step returns (ints
+#: like the chain indices: a pytree's keys must sort), by the name a
+#: unit leaves its own under: the routed layers', a looped stack's
+STEP_COUNTS = {"moe": -1, "stack": -2}
 
 
 def _make_paged_step(forwards, want_hidden=False, attend=None):
@@ -124,7 +123,7 @@ def _make_paged_step(forwards, want_hidden=False, attend=None):
         h = toks[:, None]
         hid = None
         out = dict(pools)
-        moe, stack = [], []
+        counted = {name: [] for name in STEP_COUNTS}
         for i, u in enumerate(forwards):
             if want_hidden and i == last:
                 # the final unit's INPUT is the target's last hidden
@@ -135,10 +134,9 @@ def _make_paged_step(forwards, want_hidden=False, attend=None):
                     params[i], h, pos, tables, pools[i],
                     **({"slots": slots} if i in by_slot else
                        {"attend": attend} if i in told else {}))
-                if "moe" in out[i]:   # a routed layer's counts
-                    moe.append(out[i].pop("moe"))
-                if "stack" in out[i]:  # a looped stack's counts
-                    stack.append(out[i].pop("stack"))
+                for name, rows in counted.items():
+                    if name in out[i]:
+                        rows.append(out[i].pop(name))
             elif hasattr(u, "apply_step_slots"):
                 h = u.apply_step_slots(params[i], h, pos)
             else:
@@ -146,10 +144,9 @@ def _make_paged_step(forwards, want_hidden=False, attend=None):
         logits = h[:, 0].astype(jnp.float32)
         keys = _fold_keys(seeds, counts)
         nxt = sample_slots(logits, temps, topks, keys)
-        if moe:   # ONE small array a step: [routed layers, 4]
-            out[MOE_COUNTS] = jnp.stack(moe)
-        if stack:  # ONE small array a step: [stacks, 2 + passes]
-            out[STACK_COUNTS] = jnp.stack(stack)
+        for name, rows in counted.items():
+            if rows:   # ONE small array a step and kind: [layers, n]
+                out[STEP_COUNTS[name]] = jnp.stack(rows)
         if want_hidden:
             return nxt, hid[:, 0], out
         return nxt, out
@@ -281,12 +278,12 @@ def paged_decode_step(forwards, cache, toks, pos, tables, temps,
 
     ``slots`` [B] — the slot of each packed row, -1 for a padding row
     (the default for every row): what a unit with per-slot state
-    indexes its state pool by.  A chain with routed layers leaves
-    their counts of this step, one int32 [layers, 4] device array, in
-    ``cache.moe_counts`` (None otherwise); a chain with a looped stack
-    leaves its counts, float32 [stacks, 2 + passes] = (passes run, live
-    rows, the live rows' exit mass of each pass), in
-    ``cache.stack_counts``.
+    indexes its state pool by.  What the chain's units counted in this
+    step is left in ``cache.step_counts``, one small device array a
+    kind (``STEP_COUNTS``; a chain that counts nothing leaves it
+    empty): ``"moe"`` the routed layers' int32 [layers, 4 or 5],
+    ``"stack"`` a looped stack's float32 [stacks, 2 + passes] = (passes
+    run, live rows, the live rows' exit mass of each pass).
 
     A cache built with a tensor-parallel context (``cache.tp_`` —
     serving/tp.py) runs the step SPMD over the tp mesh: ``params`` ride
@@ -354,8 +351,8 @@ def paged_decode_step(forwards, cache, toks, pos, tables, temps,
         numpy.full((b,), -1, numpy.int32) if slots is None
         else numpy.asarray(slots, numpy.int32), cache.pools)
     pools = got[-1]
-    cache.moe_counts = pools.pop(MOE_COUNTS, None)
-    cache.stack_counts = pools.pop(STACK_COUNTS, None)
+    cache.step_counts = {name: pools.pop(key) for name, key
+                         in STEP_COUNTS.items() if key in pools}
     cache.pools = pools
     cache.note_swap(old)
     cache.token_sharding = got[0].sharding if got[0].committed else None

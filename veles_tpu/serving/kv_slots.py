@@ -389,12 +389,9 @@ class PagedKVCache:
         #: the caller instead of the free list; decode never writes
         #: them because the cold offset starts past the shared range)
         self.n_shared = numpy.zeros((self.max_slots,), numpy.int32)
-        #: the routed layers' counts of the last decode step, a device
-        #: array [layers, 4] (serving/engine.paged_decode_step)
-        self.moe_counts = None
-        #: a looped stack's counts of the last decode step, a device
-        #: array [stacks, 2 + passes] (serving/engine.paged_decode_step)
-        self.stack_counts = None
+        #: what the chain's units counted in the last decode step:
+        #: {kind: a small device array} (serving/engine.STEP_COUNTS)
+        self.step_counts = {}
         #: where the last decode step left its tokens, if it COMMITTED
         #: them there (None before the first step, and for a step over
         #: uncommitted parameters): the step places host tokens alike,
@@ -440,13 +437,16 @@ class PagedKVCache:
 
     def state_bytes(self):
         """{"kv": bytes of the paged pools, "conv": bytes of the
-        per-slot state pools} resident on the devices.  Metadata
-        alone (``nbytes``), so it answers from any thread, also on a
-        leaf that a step in flight has consumed."""
+        per-slot state pools' short-convolution rows, and a further
+        kind for each other array a state unit keeps a slot (a
+        delta-rule layer's matrix ``S``), by its name} resident on the
+        devices.  Metadata alone (``nbytes``), so it answers from any
+        thread, also on a leaf that a step in flight has consumed."""
         out = {"kv": 0, "conv": 0}
         for i, layer in self.pools.items():
-            out["conv" if i in self.state_units else "kv"] += sum(
-                a.nbytes for a in layer.values())
+            for name, a in layer.items():
+                kind = name if i in self.state_units else "kv"
+                out[kind] = out.get(kind, 0) + a.nbytes
         return out
 
     def first_leaf(self):
@@ -477,7 +477,7 @@ class PagedKVCache:
         self.pools = jax.tree.map(
             lambda a: jnp.zeros(a.shape, a.dtype, device=a.sharding),
             self.pools)
-        self.moe_counts = self.stack_counts = None
+        self.step_counts = {}
 
     def _blocks_only(self, what):
         refused = blocks_only_refusal(what, self.state_units,

@@ -339,8 +339,9 @@ def _registry_series():
         "state_bytes": metrics.gauge(
             "veles_serving_state_bytes",
             "bytes resident for the requests' state, by kind: kv (the "
-            "paged K/V pools) and conv (the fixed per-slot state of "
-            "short-convolution layers, serving/kv_slots.py)",
+            "paged K/V pools), conv (the fixed per-slot rows of "
+            "short convolutions) and S (the per-slot float32 matrix "
+            "state of delta-rule layers; serving/kv_slots.py)",
             labelnames=("kind", "replica")),
         "moe_layer_steps": metrics.counter(
             "veles_serving_moe_layer_steps_total",
@@ -358,6 +359,16 @@ def _registry_series():
             "veles_serving_moe_hottest_rows_total",
             "live rows on the most loaded expert, summed over routed "
             "layers and decode steps"),
+        "moe_held_pairs": metrics.counter(
+            "veles_serving_moe_held_pairs_total",
+            "routed (row, expert) pairs of decode steps that fell on "
+            "the experts held here, where a layer holds a share of "
+            "them (experts touched and hottest rows are then of the "
+            "held experts)"),
+        "state_rows": metrics.counter(
+            "veles_serving_state_rows_total",
+            "per-slot states read and written by decode steps: live "
+            "rows x the chain's layers that keep a state a slot"),
         "stack_passes": metrics.counter(
             "veles_serving_stack_passes_total",
             "passes of a looped stack that decode steps ran: decode "
@@ -1338,7 +1349,7 @@ class ServingMetrics:
                 step_after_prefill_seconds)
 
     def record_step(self, active, slots, tokens=None, moe=None,
-                    stack=None):
+                    stack=None, state_units=0):
         """One batched decode/verify boundary: ``active`` real rows
         rode a padded ``slots``-row bucket; ``tokens`` is what the
         step actually emitted (spec verify can emit up to k+1 per
@@ -1348,7 +1359,10 @@ class ServingMetrics:
         last healthy rate).  The step's seconds are the loop's
         ``step`` phase (:meth:`record_loop_pass`).  ``moe``: the
         routed layers' counts of a decode step, int [layers, 4] =
-        (1, pairs, experts touched, rows on the hottest expert).
+        (1, pairs, experts touched, rows on the hottest expert), or
+        [layers, 5] with the pairs on held experts last.
+        ``state_units``: how many of the chain's layers keep a state a
+        slot, each of which read and wrote ``active`` of them.
         ``stack``: a looped stack's counts of a decode step, float
         [stacks, 2 + passes] = (passes run, live rows, the live rows'
         exit mass of each pass)."""
@@ -1364,9 +1378,12 @@ class ServingMetrics:
         if moe is not None:
             for name, total in zip(
                     ("moe_layer_steps", "moe_pairs",
-                     "moe_experts_touched", "moe_hottest_rows"),
+                     "moe_experts_touched", "moe_hottest_rows",
+                     "moe_held_pairs"),
                     moe.sum(axis=0).tolist()):
                 self._global[name].inc(total)
+        if state_units:
+            self._global["state_rows"].inc(int(active) * state_units)
         now = time.monotonic()
         with self._lock:
             self.slot_busy_steps += int(active)

@@ -516,16 +516,16 @@ class _Request(object):
 class _Flight(object):
     """A decode step that was launched and has not been read: its
     packed slot order, the request of each row, the device arrays it
-    will hand over (a bucket of tokens; the routed layers' counts, a
-    looped stack's counts and the hidden state where the chain has
-    them) and its launch time.  Loop thread
+    will hand over (a bucket of tokens; the hidden state and the
+    units' counts of the step, ``cache.step_counts``, where the chain
+    has them) and its launch time.  Loop thread
     only; at most one exists (``InferenceScheduler._flight``)."""
 
-    __slots__ = ("slots", "reqs", "nxt", "hid", "moe", "stack", "t0")
+    __slots__ = ("slots", "reqs", "nxt", "hid", "counts", "t0")
 
-    def __init__(self, slots, reqs, nxt, hid, moe, stack):
+    def __init__(self, slots, reqs, nxt, hid, counts):
         self.slots, self.reqs = slots, reqs
-        self.nxt, self.hid, self.moe, self.stack = nxt, hid, moe, stack
+        self.nxt, self.hid, self.counts = nxt, hid, counts
         self.t0 = time.perf_counter()
 
 
@@ -2900,11 +2900,9 @@ class InferenceScheduler(Logger):
                 seeds, counts, want_hidden=want_h,
                 params=self.weights_.params, slots=rows)
             nxt, hid = got if want_h else (got, None)
-            # the routed layers' and a looped stack's counts come
-            # with the step
+            # what the units counted comes with the step
             self._flight = _Flight(slots, [active[s] for s in slots],
-                                   nxt, hid, cache.moe_counts,
-                                   cache.stack_counts)
+                                   nxt, hid, cache.step_counts)
         if ahead:
             self._phases.steps_ahead += 1
             self._land_flight(cache, flight)
@@ -2933,11 +2931,9 @@ class InferenceScheduler(Logger):
             nxt = numpy.asarray(flight.nxt)
             hid = None if flight.hid is None \
                 else numpy.asarray(flight.hid)
-            # computed by then: one small copy, no further wait
-            moe = None if flight.moe is None \
-                else numpy.asarray(flight.moe)
-            stack = None if flight.stack is None \
-                else numpy.asarray(flight.stack)
+            # computed by then: a small copy each, no further wait
+            counts = {name: numpy.asarray(rows)
+                      for name, rows in flight.counts.items()}
         # the step's own stretch of the clock: from its launch, or
         # from the landing before it where the device ran the two
         # back to back
@@ -2952,7 +2948,9 @@ class InferenceScheduler(Logger):
         self._phases.rows_discarded += len(flight.slots) - n
         with self._phases("observe"):
             # plain decode: every live row emits exactly one token
-            self.stats.record_step(n, b, tokens=n, moe=moe, stack=stack)
+            self.stats.record_step(
+                n, b, tokens=n, state_units=len(self._state_units),
+                **counts)
             self._meter_step({slot: req for _, slot, req in rows},
                              cache, dt)
         with self._phases("emit"):
