@@ -13,6 +13,7 @@ import types
 import numpy
 import pytest
 
+from veles_tpu import faults
 from veles_tpu.config import root
 
 pytestmark = pytest.mark.spec
@@ -159,6 +160,10 @@ def test_model_drafter_preempt_resume_parity(f32,
         try:
             futs = [sch.submit(p, 20, **kw) for p, kw in prompts]
             if preempt:
+                # the steps slowed, so that the preempt lands mid-stream
+                # however few passes the prefill and the drafts leave
+                faults.inject("serving.scheduler.step", "delay",
+                              arg=0.02)
                 deadline = time.monotonic() + 60
                 while sch.metrics()["slot_busy_steps"] < 4:
                     assert time.monotonic() < deadline
@@ -171,6 +176,7 @@ def test_model_drafter_preempt_resume_parity(f32,
             sch.check_kv()
             return outs, snap
         finally:
+            faults.clear()
             sch.close()
 
     base, _ = run(preempt=False)

@@ -334,6 +334,10 @@ def test_int8_preempt_resume_agreement(f32):
         try:
             futs = [sch.submit(p, 24, **kw) for p, kw in jobs]
             if preempt:
+                # the steps slowed, so that the preempt lands mid-stream
+                # however few passes the prefill and the drafts leave
+                faults.inject("serving.scheduler.step", "delay",
+                              arg=0.02)
                 deadline = time.monotonic() + 60
                 while sch.metrics()["slot_busy_steps"] < 4:
                     assert time.monotonic() < deadline

@@ -107,8 +107,10 @@ def test_full_forward_matches_the_reference(chain, f32):
 
 def _prefilled(fw, params, prompt, chunked):
     """(staging caches, last logits) of one prompt: one-shot, or chunk
-    by chunk of ``CHUNK``."""
+    by chunk of ``CHUNK``; ``chunked`` a width: in the scheduler's
+    widths between ``CHUNK`` and it (``chunk_width``)."""
     from veles_tpu.serving.prefill import prefill, prefill_chunk
+    from veles_tpu.serving.scheduler import chunk_width
     p_len = len(prompt)
     width = max(CHUNK, 1 << (p_len - 1).bit_length())
     if not chunked:
@@ -117,12 +119,15 @@ def _prefilled(fw, params, prompt, chunked):
         return prefill(fw, padded, prompt_lens=[p_len], window=width,
                        params=params)
     caches = {1: fw[1].init_cache(1, width, dtypes.compute_dtype())}
-    for off in range(0, p_len, CHUNK):
-        piece = prompt[off:off + CHUNK]
-        padded = numpy.zeros((1, CHUNK), numpy.int32)
+    widest, off = CHUNK if chunked is True else chunked, 0
+    while off < p_len:
+        c = chunk_width(p_len - off, off, CHUNK, widest)
+        piece = prompt[off:off + c]
+        padded = numpy.zeros((1, c), numpy.int32)
         padded[0, :len(piece)] = piece
         caches, last = prefill_chunk(fw, padded, off, [len(piece)],
                                      caches, params=params)
+        off += c
     return caches, last
 
 
@@ -148,6 +153,24 @@ def test_chunked_prefill_equals_one_shot(chain, f32, p_len):
     # pass 0's rows of a layer are not pass 1's
     k = numpy.asarray(whole[1]["k"])
     assert numpy.abs(k[0, 0, :p_len] - k[LAYERS, 0, :p_len]).max() > 1e-2
+
+
+@pytest.mark.parametrize("p_len", [45, 39], ids=["32+16", "32+8"])
+def test_mixed_width_chunks_equal_one_shot(chain, f32, p_len):
+    """The scheduler's widths (narrowest ``CHUNK``, widest four of it):
+    every cache layer of the stack holds one-shot prefill's rows after
+    chunks of DIFFERENT widths."""
+    from veles_tpu.serving.scheduler import widest_chunk
+    fw, params = chain
+    assert widest_chunk(fw, 64) == 256   # products: as wide as the ridge
+    prompt = numpy.random.default_rng(p_len).integers(
+        0, VOCAB, p_len).tolist()
+    whole, last = _prefilled(fw, params, prompt, chunked=False)
+    cut, last_cut = _prefilled(fw, params, prompt, chunked=4 * CHUNK)
+    numpy.testing.assert_allclose(last_cut, last, atol=5e-5)
+    for name in ("k", "v"):
+        numpy.testing.assert_allclose(cut[1][name], whole[1][name],
+                                      atol=5e-5, err_msg=name)
 
 
 def test_prefill_then_paged_decode_steps_match_the_reference(chain, f32):

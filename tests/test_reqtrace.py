@@ -489,7 +489,8 @@ def test_tracing_overhead_is_one_event_a_decode_boundary(f32,
     # one event a boundary, at occupancy 1 and at occupancy 2
     assert alone[0] == alone[2] > 0 and paired[0] == paired[2] > 0
     assert paired[2] < alone[2]      # two slots did share boundaries
-    # queue, admit, two prefill chunks, first token, retire: the same
-    # six a request whoever it shares the batch with
-    assert alone[1] == paired[1] == 4 * 6
+    # queue, admit, one prefill chunk (six positions go in one chunk
+    # of 8), first token, retire: the same five a request whoever it
+    # shares the batch with
+    assert alone[1] == paired[1] == 4 * 5
     assert silent[:2] == (0, 0) and silent[2] > 0

@@ -409,18 +409,21 @@ def test_chunked_prefill_matches_oneshot(f32):
                                   numpy.asarray(ref_last), atol=1e-4)
 
 
-def test_chunked_prefill_interleaves_decode(f32):
+def test_chunked_prefill_interleaves_decode(f32, monkeypatch):
     """A long prompt joining mid-traffic prefills in chunks: the
     chunk counters move, short in-flight requests keep decoding, and
     the long request's output still equals its solo decode."""
     from veles_tpu.models.generate import generate
     from veles_tpu.serving import InferenceScheduler
+    from veles_tpu.serving import scheduler as sched_mod
+    # a ridge as small as this window: 33 tokens go in 16 + 16 + 8
+    monkeypatch.setattr(sched_mod, "PREFILL_WIDEST", 16)
     fw = _tiny_fw("chunked-mix", window=64)
     sch = InferenceScheduler(fw, max_slots=2, window=64,
                              block_size=8, prefill_chunk=8).start()
     try:
         short = sch.submit([4, 2], 30)
-        long_p = list(range(1, 12)) * 3     # 33 tokens, 5 chunks
+        long_p = list(range(1, 12)) * 3     # 33 tokens, 3 chunks
         long_p = [t % 12 for t in long_p]
         fut = sch.submit(long_p, 6)
         out = fut.result(240)
@@ -430,10 +433,293 @@ def test_chunked_prefill_interleaves_decode(f32):
         assert out == ref
         assert len(short.result(240)) == 32
         snap = sch.metrics()
-        assert snap["prefill_chunks"] >= 5
-        assert snap["prefill_chunk_tokens"] >= 33
+        assert snap["prefill_chunks"] == 3
+        assert snap["prefill_chunk_tokens"] == 33
     finally:
         sch.close()
+
+
+# -- how wide the next chunk is -----------------------------------------------
+
+def _widths(p_len, narrowest, widest):
+    """[(offset, width)] of a cold prompt's chunks, as the scheduler's
+    ticks ask for them."""
+    from veles_tpu.serving.scheduler import chunk_width
+    out, off = [], 0
+    while off < p_len:
+        out.append((off, chunk_width(p_len - off, off, narrowest,
+                                     widest)))
+        off += out[-1][1]
+    return out
+
+
+@pytest.mark.parametrize("p_len, widths", [
+    (65, [128]), (128, [128]), (129, [256]), (200, [256]),
+    (256, [256]), (257, [256, 64]), (300, [256, 64]),
+    (320, [256, 64]), (321, [256, 128]), (384, [256, 128]),
+    (385, [256, 256]), (513, [256, 256, 64]),
+    (1000, [256] * 4), (1024, [256] * 4),
+    (1100, [256] * 4 + [128])])
+def test_chunk_width_examples(p_len, widths):
+    """The rule at the default sizes (narrowest 64, widest 256): one
+    stream of the weights for up to 256 positions."""
+    assert [w for _, w in _widths(p_len, 64, 256)] == widths
+
+
+@pytest.mark.parametrize("narrowest, widest", [
+    (64, 256), (64, 128), (64, 64), (8, 256), (2, 8), (16, 16),
+    (512, 512)], ids=lambda v: str(v))
+def test_chunk_width_rule(narrowest, widest):
+    """For every prompt length 1 ... 1,100: each offset is a multiple
+    of its chunk's width (and so of every later one), widths never
+    rise, the last chunk covers the tail and no earlier one passes
+    it, every width is a power of two in [narrowest, widest], and
+    the width changes only where the length passes a multiple of
+    ``narrowest`` -- so all prompts of one block count (the
+    benchmark's warm-up sweep) meet the same programs.  A chain
+    capped at its narrowest (a scanning unit) gets it throughout."""
+    before = None
+    for p_len in range(1, 1101):
+        chunks = _widths(p_len, narrowest, widest)
+        for (off, w), nxt in zip(chunks, chunks[1:] + [None]):
+            assert off % w == 0, (p_len, chunks)
+            assert narrowest <= w <= widest and w & (w - 1) == 0
+            if nxt is not None:
+                assert nxt[1] <= w and off + w == nxt[0] < p_len
+            else:
+                assert off < p_len <= off + w
+                # no wider than the tail needs
+                assert w == narrowest or w // 2 < p_len - off
+        widths = [w for _, w in chunks]
+        if narrowest == widest:
+            assert set(widths) == {narrowest}
+        if before is not None and (p_len - 1) % narrowest:
+            assert widths == before, (p_len, widths, before)
+        before = widths
+
+
+def test_chunk_width_narrows_to_an_unaligned_offset():
+    """An offset that is no multiple of the bucket halves the width
+    until it is (a caller that starts past 0)."""
+    from veles_tpu.serving.scheduler import chunk_width
+    assert chunk_width(500, 64, 64, 256) == 64
+    assert chunk_width(500, 128, 64, 256) == 128
+    assert chunk_width(500, 768, 64, 256) == 256
+
+
+def test_widest_chunk_follows_the_chain(f32):
+    """The cap comes from the chain's layer types: products over the
+    chunk's positions go as wide as the ridge, a unit that says it
+    scans them (``prefill_scans``) keeps the configured width; a
+    configured width past the ridge is its own cap; chunking off
+    stays off."""
+    from veles_tpu.serving import InferenceScheduler
+    from veles_tpu.serving.scheduler import PREFILL_WIDEST, widest_chunk
+    fw = _tiny_fw("widest")
+    assert widest_chunk(fw, 64) == PREFILL_WIDEST == 256
+    assert widest_chunk(fw, 512) == 512
+    fw[1].prefill_scans = True
+    try:
+        assert widest_chunk(fw, 64) == 64
+    finally:
+        del fw[1].prefill_scans
+    for chunk, widest in ((8, 256), (0, 0)):
+        sch = InferenceScheduler(fw, max_slots=1, window=16,
+                                 prefill_chunk=chunk)
+        assert sch.prefill_widest == widest
+        assert sch.metrics()["prefill_widest"] == widest
+
+
+@pytest.mark.parametrize("p_len", [5, 9, 11, 13, 16, 23, 32])
+def test_mixed_width_chunks_match_oneshot(f32, p_len):
+    """Chunks of the widths the rule gives (narrowest 2, widest 8, so
+    8 + 4 + 2 all occur) leave the staging rows and the first-token
+    logits that one-shot prefill leaves."""
+    from veles_tpu import dtypes
+    from veles_tpu.serving import prefill, prefill_chunk
+    fw = _tiny_fw("mixed-widths", window=32, blocks=2)
+    rng = numpy.random.default_rng(p_len)
+    p = rng.integers(0, 12, p_len).tolist()
+    w = 32
+    padded = numpy.zeros((1, w), numpy.int32)
+    padded[0, :p_len] = p
+    ref_caches, ref_last = prefill(fw, padded, prompt_lens=[p_len],
+                                   window=w)
+    caches = {i: u.init_cache(1, w, dtypes.compute_dtype())
+              for i, u in enumerate(fw) if hasattr(u, "init_cache")}
+    chunks = _widths(p_len, 2, 8)
+    for off, c in chunks:
+        end = min(off + c, p_len)
+        chunk = numpy.zeros((1, c), numpy.int32)
+        chunk[0, :end - off] = p[off:end]
+        kw = c
+        while kw < off + c:
+            kw *= 2
+        caches, last = prefill_chunk(fw, chunk, off, [end - off],
+                                     caches, key_width=min(kw, w))
+    for i in ref_caches:
+        for part in ("k", "v"):
+            numpy.testing.assert_allclose(
+                numpy.asarray(caches[i][part]),
+                numpy.asarray(ref_caches[i][part]), atol=1e-5,
+                err_msg="layer %d %s, chunks %s" % (i, part, chunks))
+    numpy.testing.assert_allclose(numpy.asarray(last),
+                                  numpy.asarray(ref_last), atol=1e-4)
+
+
+def _spy_on_chunks(monkeypatch, widest=None):
+    """Record (offset, width, live positions) of every chunk the
+    scheduler dispatches; ``widest`` stands in for the ridge of a chip
+    whose chunks are as small as these tests' windows."""
+    from veles_tpu.serving import scheduler as sched_mod
+    seen = []
+    real = sched_mod.prefill_chunk
+
+    def spy(forwards, chunk, offset, chunk_lens, caches, **kwargs):
+        seen.append((int(offset), int(chunk.shape[1]),
+                     int(chunk_lens[0])))
+        return real(forwards, chunk, offset, chunk_lens, caches,
+                    **kwargs)
+    monkeypatch.setattr(sched_mod, "prefill_chunk", spy)
+    if widest is not None:
+        monkeypatch.setattr(sched_mod, "PREFILL_WIDEST", widest)
+    return seen
+
+
+def test_scheduler_prefills_in_the_rules_widths(f32, monkeypatch):
+    """The scheduler's ticks ask for exactly the rule's widths (one
+    chunk a pass), count their live positions, and the stream is
+    generate()'s; prompts up to the narrowest width stay one-shot."""
+    from veles_tpu.models.generate import generate
+    from veles_tpu.serving import InferenceScheduler
+    seen = _spy_on_chunks(monkeypatch, widest=16)
+    fw = _tiny_fw("rule-widths", window=64)
+    sch = InferenceScheduler(fw, max_slots=2, window=64,
+                             block_size=4, prefill_chunk=4,
+                             spec=False, prefix_cache=False).start()
+    try:
+        assert sch.prefill_widest == 16
+        rng = numpy.random.default_rng(7)
+        for p_len in (4, 5, 16, 17, 27, 41):
+            del seen[:]
+            p = rng.integers(0, 12, p_len).tolist()
+            before = sch.metrics()
+            out = sch.submit(p, 3).result(240)
+            ref = numpy.asarray(generate(
+                fw, numpy.asarray([p], numpy.int32), 3,
+                kv_cache=True))[0].tolist()
+            assert out == ref, p_len
+            want = _widths(p_len, 4, 16) if p_len > 4 else []
+            assert [(o, w) for o, w, _ in seen] == want, p_len
+            assert sum(n for _, _, n in seen) == (p_len if want else 0)
+            snap = sch.metrics()
+            assert snap["prefill_chunks"] - before["prefill_chunks"] \
+                == len(want)
+            assert snap["prefill_chunk_tokens"] \
+                - before["prefill_chunk_tokens"] == (
+                    p_len if want else 0)
+    finally:
+        sch.close()
+
+
+def test_a_joiners_first_chunk_waits_for_the_pass_after_its_admission(
+        f32, monkeypatch):
+    """A pass that admits (the joiner's staging rows are built on the
+    loop thread) dispatches no chunk for the joiner: its first chunk
+    goes out in the next pass -- unless an OLDER request is mid-prefill,
+    whose chunk the admission never holds back."""
+    from veles_tpu import faults
+    from veles_tpu.serving import InferenceScheduler
+    from veles_tpu.serving import scheduler as sched_mod
+    monkeypatch.setattr(sched_mod, "PREFILL_WIDEST", 8)
+    log = []
+
+    def logged(name):
+        real = getattr(InferenceScheduler, name)
+
+        def wrapper(self, *args, **kwargs):
+            log.append(name)
+            return real(self, *args, **kwargs)
+        monkeypatch.setattr(InferenceScheduler, name, wrapper)
+    for name in ("_reap", "_begin_admit", "_prefill_tick"):
+        logged(name)        # _reap runs once a pass, before the rest
+    fw = _tiny_fw("first-chunk-next-pass", window=64)
+    sch = InferenceScheduler(fw, max_slots=2, window=64, block_size=4,
+                             prefill_chunk=4, spec=False,
+                             prefix_cache=False).start()
+    try:
+        rng = numpy.random.default_rng(5)
+        lone = rng.integers(0, 12, 7).tolist()
+        assert len(sch.submit(lone, 2).result(240)) == 9
+        passes = " ".join(log).split("_reap")
+        at = next(i for i, p in enumerate(passes) if "_begin_admit" in p)
+        assert "_prefill_tick" not in passes[at]
+        assert "_prefill_tick" in passes[at + 1]
+        # 40 positions go in five chunks of 8: the second request is
+        # admitted while the first still prefills, and that pass ticks
+        del log[:]
+        faults.inject("serving.scheduler.prefill", "delay", arg=0.05)
+        try:
+            first = sch.submit(rng.integers(0, 12, 40).tolist(), 2)
+            time.sleep(0.08)            # mid-prefill by now
+            second = sch.submit(lone, 2)
+            assert len(first.result(240)) == 42
+            assert len(second.result(240)) == 9
+        finally:
+            faults.clear()
+        passes = " ".join(log).split("_reap")
+        admitting = [p for p in passes if "_begin_admit" in p]
+        assert len(admitting) == 2
+        assert "_prefill_tick" not in admitting[0]
+        assert "_prefill_tick" in admitting[1]
+    finally:
+        sch.close()
+
+
+def test_preempt_resume_over_mixed_widths_is_bit_identical(
+        f32, monkeypatch):
+    """A preempted request re-prefills prompt + generated prefix
+    through the same ticks, in other widths than its first prefill
+    (the sequence is longer): the resumed stream, greedy and seeded,
+    equals the uninterrupted one."""
+    from veles_tpu.serving import InferenceScheduler
+    seen = _spy_on_chunks(monkeypatch, widest=16)
+    fw = _tiny_fw("resume-widths", window=64, blocks=2)
+    rng = numpy.random.default_rng(11)
+    prompts = [(rng.integers(0, 12, 21).tolist(), dict()),
+               (rng.integers(0, 12, 9).tolist(),
+                dict(temperature=0.9, top_k=5, seed=123))]
+
+    def run(preempt):
+        del seen[:]
+        sch = InferenceScheduler(fw, max_slots=2, window=64,
+                                 block_size=4, prefill_chunk=4,
+                                 spec=False, prefix_cache=False).start()
+        try:
+            futs = [sch.submit(p, 24, **kw) for p, kw in prompts]
+            if preempt:
+                deadline = time.monotonic() + 60
+                while sch.metrics()["slot_busy_steps"] < 6:
+                    assert time.monotonic() < deadline
+                    time.sleep(0.01)
+                sch.request_preempt()
+            outs = [f.result(120) for f in futs]
+            return outs, sch.metrics(), list(seen)
+        finally:
+            sch.close()
+
+    base, _, first = run(preempt=False)
+    assert [(o, w) for o, w, _ in first] == \
+        _widths(21, 4, 16) + _widths(9, 4, 16)
+    resumed, snap, chunks = run(preempt=True)
+    assert snap["preempt_resumes"] >= 1
+    assert resumed == base
+    # the resume's chunks follow the rule over ITS sequence
+    again = chunks[len(first):]
+    assert again and again[0][0] == 0
+    total = sum(n for _, _, n in again)
+    assert [(o, w) for o, w, _ in again] == _widths(total, 4, 16)
+    assert total > min(len(p) for p, _ in prompts)
 
 
 # -- scheduler ----------------------------------------------------------------

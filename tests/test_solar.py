@@ -132,8 +132,10 @@ def test_one_block_matches_the_reference_layer(chain, f32, operator):
 
 def _prefilled(fw, params, prompt, chunked):
     """(staging caches, last logits) of one prompt: one-shot, or chunk
-    by chunk of ``CHUNK``."""
+    by chunk of ``CHUNK``; ``chunked`` a width: in the scheduler's
+    widths between ``CHUNK`` and it (``chunk_width``)."""
     from veles_tpu.serving.prefill import prefill, prefill_chunk
+    from veles_tpu.serving.scheduler import chunk_width
     p_len = len(prompt)
     width = max(CHUNK, 1 << (p_len - 1).bit_length())
     if not chunked:
@@ -143,12 +145,15 @@ def _prefilled(fw, params, prompt, chunked):
                        params=params)
     caches = {i: u.init_cache(1, width, dtypes.compute_dtype())
               for i, u in enumerate(fw) if hasattr(u, "init_cache")}
-    for off in range(0, p_len, CHUNK):
-        piece = prompt[off:off + CHUNK]
-        padded = numpy.zeros((1, CHUNK), numpy.int32)
+    widest, off = CHUNK if chunked is True else chunked, 0
+    while off < p_len:
+        c = chunk_width(p_len - off, off, CHUNK, widest)
+        piece = prompt[off:off + c]
+        padded = numpy.zeros((1, c), numpy.int32)
         padded[0, :len(piece)] = piece
         caches, last = prefill_chunk(fw, padded, off, [len(piece)],
                                      caches, params=params)
+        off += c
     return caches, last
 
 
@@ -328,6 +333,24 @@ def _gaps(params, prompts, tokens, **kwargs):
         out += (rows.max(-1) - rows[numpy.arange(len(toks)),
                                     toks]).tolist()
     return numpy.asarray(out)
+
+
+def test_a_scanning_chain_keeps_its_chunk_width(chain, served):
+    """A delta-rule layer's chunk is a scan over its positions, and it
+    says so (``prefill_scans``): the scheduler's widest chunk is the
+    configured one whatever the prompt's length, so the chain's chunks
+    are what they were: 19 positions in 8 + 8 + 3, 16 in 8 + 8, 5
+    one-shot."""
+    from veles_tpu.serving.scheduler import widest_chunk
+    fw, _ = chain
+    assert [getattr(u, "prefill_scans", False) for u in fw] == \
+        [False] + [op == "kda" for op in KINDS] + [False]
+    assert widest_chunk(fw, 64) == 64 and widest_chunk(fw, CHUNK) == CHUNK
+    assert widest_chunk(fw[:2] + fw[-1:], 64) == 256   # GQA alone
+    _, _, _, snap, _ = served
+    assert snap["prefill_chunk"] == snap["prefill_widest"] == CHUNK
+    assert snap["prefill_chunks"] == 5
+    assert snap["prefill_chunk_tokens"] == 19 + 16
 
 
 def test_packed_steps_slot_reuse_and_no_state_leak(chain, served, f32):

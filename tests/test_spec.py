@@ -187,6 +187,10 @@ def test_spec_preempt_resume_parity(f32):
         try:
             futs = [sch.submit(p, 24, **kw) for p, kw in prompts]
             if preempt:
+                # the steps slowed, so that the preempt lands mid-stream
+                # however few passes the prefill and the drafts leave
+                faults.inject("serving.scheduler.step", "delay",
+                              arg=0.02)
                 deadline = time.monotonic() + 60
                 while sch.metrics()["slot_busy_steps"] < 4:
                     assert time.monotonic() < deadline

@@ -17,6 +17,7 @@ import numpy
 import pytest
 
 from veles_tpu.backends import Device
+from veles_tpu import faults
 from veles_tpu.config import root
 from veles_tpu.memory import Array
 
@@ -190,6 +191,10 @@ def test_tp2_preempt_resume_parity(f32, spec_trained_chain):
         try:
             futs = [sch.submit(p, 16, **kw) for p, kw in jobs]
             if preempt:
+                # the steps slowed, so that the preempt lands mid-stream
+                # however few passes the prefill and the drafts leave
+                faults.inject("serving.scheduler.step", "delay",
+                              arg=0.02)
                 deadline = time.monotonic() + 60
                 while sch.metrics()["slot_busy_steps"] < 4:
                     assert time.monotonic() < deadline
@@ -200,6 +205,7 @@ def test_tp2_preempt_resume_parity(f32, spec_trained_chain):
             sch.check_kv()
             return outs, snap
         finally:
+            faults.clear()
             sch.close()
 
     base, _ = run(preempt=False)

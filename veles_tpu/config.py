@@ -291,7 +291,13 @@ root.common.update({
     # continuous-batching serving knobs (serving/scheduler.py):
     # kv_blocks None derives the pool that holds max_slots requests
     # of full length (max_slots * ceil(window / block_size));
-    # prefill_chunk 0 disables chunked prefill; request_timeout is
+    # prefill_chunk is the NARROWEST prefill chunk in positions and
+    # the longest prompt that prefills one-shot (0 disables chunked
+    # prefill); above it the scheduler picks each chunk's width
+    # itself, the power-of-two bucket of what the request has left up
+    # to the chip's ridge (256 positions; a chain whose chunk scans
+    # its positions stays at prefill_chunk), serving/scheduler.py
+    # chunk_width; request_timeout is
     # the whole-request deadline in seconds (queued + decoding; 0
     # disables); watchdog is the stuck-decode-loop detector threshold
     # in seconds (0 disables — keep it far above the worst

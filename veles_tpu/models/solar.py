@@ -129,6 +129,11 @@ class SolarBlock(ForwardBase):
         #: what the serving cache keeps for this layer: one fixed state
         #: per slot or paged K/V rows (serving/kv_slots.PagedKVCache)
         self.cache_kind = "slot" if operator == "kda" else "paged"
+        #: a chunk of the delta rule is a scan over its positions
+        #: (``_kda``), so its time grows with the chunk's width: the
+        #: scheduler keeps such a chain's chunks at their narrowest
+        #: (serving/scheduler.widest_chunk)
+        self.prefill_scans = operator == "kda"
         self.PARAMS = ("input_norm", "post_norm") \
             + self.OPERATOR_PARAMS[operator] + self.FFN_PARAMS
         for p in self.PARAMS:

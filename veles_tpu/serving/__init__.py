@@ -8,7 +8,8 @@ per-token scan.  Here:
 - :mod:`veles_tpu.serving.prefill` — batched prefill: ONE jitted
   forward over the whole prompt fills the KV cache (TTFT O(1)
   compiled steps instead of O(prompt_len)), and CHUNKED prefill
-  (:func:`prefill_chunk`) splits long prompts into fixed-size chunks
+  (:func:`prefill_chunk`) splits long prompts into chunks (as wide
+  as still costs one stream of the weights: ``scheduler.chunk_width``)
   the scheduler interleaves with decode steps (Sarathi-style) so a
   joining long prompt cannot stall in-flight streams;
 - :mod:`veles_tpu.serving.kv_slots` — the KV cache: block-PAGED

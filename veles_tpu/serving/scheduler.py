@@ -14,12 +14,22 @@ one compiled step):
    requests pack many more concurrent streams into the same HBM than
    a window-sized row per slot would;
 2. **prefill** — prompts up to ``prefill_chunk`` prefill in ONE
-   compiled pass; longer prompts prefill in ``prefill_chunk``-token
-   CHUNKS, at most one chunk per loop iteration, INTERLEAVED with the
-   decode step below (Sarathi-style chunked prefill) — a joining long
-   prompt stalls in-flight decode streams by one chunk per iteration,
-   not by its whole prefill, which flattens the TTFT tail of short
-   requests stuck behind long ones.  Either way the K/V staging row
+   compiled pass; longer prompts prefill in CHUNKS, at most one chunk
+   per loop iteration, INTERLEAVED with the decode step below
+   (Sarathi-style chunked prefill) — a joining long prompt stalls
+   in-flight decode streams by one chunk per iteration, not by its
+   whole prefill, which flattens the TTFT tail of short requests
+   stuck behind long ones.  A chunk is as wide as it can be while it
+   still costs one stream of the weights (:func:`chunk_width`: the
+   power-of-two bucket of the positions the request has left, from
+   ``prefill_chunk`` up to ``PREFILL_WIDEST``; a chain with a unit
+   that scans a chunk position by position stays at
+   ``prefill_chunk``), so a prompt streams the weights once per 256
+   positions and not once per 64.  A joiner's first chunk goes out in
+   the pass AFTER its admission (unless an older request is still
+   prefilling): the admission's own host time and the readback that
+   ends a prompt's last chunk never fall into one gap between two
+   tokens of the decoding streams.  Either way the K/V staging row
    is inserted into the cache and the first token samples from the
    final logits (the TTFT edge);
 3. **step** — active slots advance one token through the shared
@@ -133,8 +143,11 @@ dequantized keys — while a warm radix resubmit REUSES its matched
 blocks exactly and recomputes only the cold tail (over dequantized
 keys: the same noise, one block deep); the fp32 default keeps every
 PR 7 bit-exactness contract),
-``prefill_chunk`` (chunk width in tokens, rounded up to a power of
-two; 0 disables chunking, default 64), ``request_timeout`` /
+``prefill_chunk`` (the NARROWEST chunk in tokens and the longest
+prompt that prefills one-shot, rounded up to a power of two; 0
+disables chunking, default 64; how wide a chunk is above it follows
+from the request's own length and the chain's layer types,
+:func:`chunk_width` and :func:`widest_chunk`), ``request_timeout`` /
 ``watchdog`` / ``shed_block_factor`` (lifecycle knobs above; 0
 disables each), ``spec`` / ``spec_k`` (speculative decoding),
 ``fused_verify`` (score the spec run single-pass instead of the
@@ -330,6 +343,44 @@ def _bucket(n, floor, cap):
     return min(b, cap)
 
 
+#: the widest prefill chunk, in positions: the power of two at the
+#: chip's ridge for compute-dtype weights (a v5e does 197e12 FLOP/s
+#: against 819e9 B/s, so bf16 weights are paid for by their STREAM up
+#: to ~240 rows).  Up to here a chunk costs about what the narrowest
+#: one costs; past it the stall a chunk puts in front of every
+#: decoding stream grows with its width
+PREFILL_WIDEST = 256
+
+
+def chunk_width(remaining, offset, narrowest, widest):
+    """Width of a prefilling request's next chunk: the power-of-two
+    bucket of the ``remaining`` positions between ``narrowest`` and
+    ``widest`` (powers of two), halved until ``offset`` is a multiple
+    of it.  A function of the request's own length and offset and of
+    nothing else, so every prompt of one length meets the same
+    compiled programs whoever else is decoding; every threshold is a
+    multiple of ``narrowest``.  From offset 0 on the widths never
+    rise along a prompt, so each offset is a multiple of every later
+    width (``prefill_chunk()``'s tiling contract)."""
+    width = _bucket(remaining, narrowest, widest)
+    while offset % width:
+        width //= 2
+    return width
+
+
+def widest_chunk(forwards, narrowest):
+    """The widest chunk the chain prefills in: ``PREFILL_WIDEST``
+    where a chunk is products over its positions (one stream of the
+    weights whatever its width), ``narrowest`` where a unit says its
+    chunk is a SCAN over positions (``prefill_scans``: its time grows
+    with every position, and so would the stall in front of the
+    decoding streams); 0 where chunking is off."""
+    if not narrowest or any(getattr(u, "prefill_scans", False)
+                            for u in forwards):
+        return narrowest
+    return max(narrowest, PREFILL_WIDEST)
+
+
 def _serving_conf(name, default):
     from veles_tpu.config import root
     return root.common.serving.get(name, default)
@@ -453,7 +504,8 @@ class _Request(object):
                  "stop_token", "seed", "deadline", "future", "slot",
                  "generated", "cancelled", "preempts", "t_submit",
                  "t_admit", "t_first", "pf_seq", "pf_caches",
-                 "pf_off", "pf_width", "pf_chunk", "pf_matched",
+                 "pf_off", "pf_width", "pf_chunk", "pf_widest",
+                 "pf_matched",
                  "prefix_handle", "priority", "sink", "trace",
                  "tenant", "export_only", "kv_import", "hid",
                  "draft_k", "accept_ema", "gram_ix")
@@ -487,7 +539,8 @@ class _Request(object):
         self.pf_caches = None
         self.pf_off = 0
         self.pf_width = 0
-        self.pf_chunk = 0
+        self.pf_chunk = 0        # narrowest chunk of this prefill
+        self.pf_widest = 0       # ... and its widest (chunk_width)
         self.pf_matched = 0      # warm prefix blocks heading the slot
         self.prefix_handle = None  # pinned radix-cache match
         self.export_only = False  # prefill-role: stop after export
@@ -609,8 +662,11 @@ class InferenceScheduler(Logger):
             self.info("chain cannot prefill in chunks; long prompts "
                       "will prefill one-shot")
             chunk = 0
-        #: chunk widths ride compiled executables — power-of-two
+        #: chunk widths ride compiled executables — power-of-two.
+        #: The narrowest chunk (and the one-shot limit) is configured;
+        #: the widest follows from the chain's layer types
         self.prefill_chunk = _bucket(chunk, 1, 1 << 30) if chunk else 0
+        self.prefill_widest = widest_chunk(forwards, self.prefill_chunk)
         self.warm_buckets = bool(
             _serving_conf("warm_buckets", True)
             if warm_buckets is None else warm_buckets)
@@ -1585,6 +1641,7 @@ class InferenceScheduler(Logger):
         # ``served_by`` record read the key
         out = {"kv_mode": "paged",
                "prefill_chunk": self.prefill_chunk,
+               "prefill_widest": self.prefill_widest,
                "prefilling": len(self._prefilling),
                "tp": self.tp,
                "role": self.role,
@@ -1960,6 +2017,13 @@ class InferenceScheduler(Logger):
         self._do_preempts(cache)
         with phases("observe"):
             self._sync_kv_gauges(cache)
+        # a pass that admits is the loop's longest (the joiner's
+        # staging rows are built on this thread, ``_begin_admit``),
+        # and a chunk that is a prompt's last ends in the first
+        # token's readback: where nothing older waits to prefill, the
+        # joiners' first chunk goes out in the NEXT pass, so the
+        # decoding streams never wait for both in one gap
+        fresh = bool(admits) and not self._prefilling
         for req in admits:
             if req.slot is not None:   # else: failed by a recovery
                 self._begin_admit(req, cache)
@@ -1971,7 +2035,7 @@ class InferenceScheduler(Logger):
         if self._prefix_jobs:
             with phases("aux"):
                 self._prefix_tick(cache)
-        if self._prefilling:
+        if self._prefilling and not fresh:
             with phases("prefill"):
                 self._prefill_tick(cache)
         if self._active or self._flight is not None:
@@ -2363,8 +2427,11 @@ class InferenceScheduler(Logger):
                 self._admit_oneshot(req, cache)
             return
         from veles_tpu import dtypes
-        req.pf_chunk = chunk
-        req.pf_width = self._staging_width(p_len, chunk)
+        req.pf_chunk, req.pf_widest = chunk, self.prefill_widest
+        # the first chunk is the prompt's widest: the staging row
+        # tiles it, and so every narrower one after it
+        req.pf_width = self._staging_width(
+            p_len, chunk_width(p_len, 0, chunk, req.pf_widest))
         req.pf_off = 0
         try:
             req.pf_caches = {
@@ -2388,7 +2455,7 @@ class InferenceScheduler(Logger):
         from veles_tpu import dtypes
         bs = self.block_size
         p_len = len(req.pf_seq)
-        req.pf_chunk = min(self.prefill_chunk, bs)
+        req.pf_chunk = req.pf_widest = min(self.prefill_chunk, bs)
         req.pf_width = self._staging_width(p_len, self.prefill_chunk)
         req.pf_off = req.pf_matched * bs
         try:
@@ -2434,7 +2501,8 @@ class InferenceScheduler(Logger):
         self._finish_admit(req, cache, row_caches, last)
 
     def _prefill_tick(self, cache):
-        """Advance the oldest mid-prefill request by ONE chunk — the
+        """Advance the oldest mid-prefill request by ONE chunk (as
+        wide as :func:`chunk_width` says for what it has left) — the
         per-iteration decode-stall bound; the decode step for every
         in-flight stream runs right after, in the same iteration."""
         with self._lock:
@@ -2442,8 +2510,8 @@ class InferenceScheduler(Logger):
                 return
             req = self._prefilling[0]
         p_len = len(req.pf_seq)
-        c = req.pf_chunk
         off = req.pf_off
+        c = chunk_width(p_len - off, off, req.pf_chunk, req.pf_widest)
         end = min(off + c, p_len)
         clen = end - off
         padded = numpy.zeros((1, c), numpy.int32)
