@@ -6,12 +6,16 @@ metrics of ``benchmark/metrics/sched_*.json`` and beside them.
 - the nine ``veles_serving_loop_<phase>_seconds_total`` partition the
   loop thread's time: they sum to ``loop_seconds`` + ``loop_parked``
   and to the wall time between two scrapes;
+- the seven parts lie inside their phases, and the dry account charges
+  every stretch between the switch that saw the tail ready and the next
+  dispatch to its phase, ``parked`` never;
 - ``veles_serving_steps_total`` is the number of decode launches,
   ``first_tokens`` the number of requests served, and both repeat;
 - one flush a pass reaches the registry, and the annotations do
   nothing without a profiler session;
 - the benchmark's own ``/metrics`` parser sees every counter under the
-  name its metric file gives (the ten ``ratio`` files of this account);
+  name its metric file gives (the twenty-one ``ratio`` files of this
+  account);
 - the jitted serving entry points and the attention's gather + GEMM
   carry their names into the lowered text.
 """
@@ -30,7 +34,9 @@ from veles_tpu.backends import Device
 from veles_tpu.config import root
 from veles_tpu.memory import Array
 from veles_tpu.serving import scheduler as scheduler_mod
-from veles_tpu.serving.scheduler import PHASES, InferenceScheduler
+from veles_tpu.serving.metrics import ServingMetrics
+from veles_tpu.serving.scheduler import (
+    PARTS, PHASES, InferenceScheduler, _LoopPhases)
 from veles_tpu.telemetry import metrics
 
 pytestmark = pytest.mark.serving
@@ -47,6 +53,16 @@ RATIO_METRICS = (
     "sched_emit_ms_per_step", "sched_observe_ms_per_step",
     "decode_step_ms", "decode_step_after_prefill_ms",
     "queue_wait_ms_mean", "prefill_wait_ms_mean")
+#: ... and those of its parts and of the dry account (PR 37)
+PART_METRICS = (
+    "device_dry_pct", "dry_admit_pct", "dry_prefill_pct", "dry_step_pct",
+    "sched_stage_ms_per_admission", "sched_admit_queue_ms_per_pass",
+    "sched_reap_ms_per_pass", "step_resolve_ms", "step_launch_ms",
+    "step_land_wait_ms", "prefill_first_wait_ms")
+
+
+def counter_of(stretch):
+    return "veles_serving_loop_%s_seconds_total" % stretch.replace(".", "_")
 
 
 @pytest.fixture
@@ -154,6 +170,192 @@ def test_phases_partition_the_loop_threads_time(f32):
         <= got["veles_serving_loop_step_seconds_total"]
 
 
+class _Clock(object):
+    """``time`` for ``_LoopPhases`` alone: the test moves it."""
+
+    def __init__(self):
+        self.now = 0.0
+
+    def perf_counter(self):
+        return self.now
+
+
+class _Tail(object):
+    """A dispatch's result whose ``is_ready()`` follows a script."""
+
+    def __init__(self, *script):
+        self.script, self.polls = list(script), 0
+
+    def is_deleted(self):
+        return False
+
+    def is_ready(self):
+        self.polls += 1
+        return self.script.pop(0)
+
+
+def test_the_dry_spell_runs_from_the_switch_that_saw_it_to_the_dispatch(
+        monkeypatch):
+    """The account alone, under an injected clock (powers of two, so
+    every sum is exact) and a scripted tail."""
+    clock = _Clock()
+    monkeypatch.setattr(scheduler_mod, "time", clock)
+    account = _LoopPhases()
+
+    def run(seconds, stretch):
+        clock.now += seconds
+        account.switch(stretch)
+    tail = _Tail(False, False, True)
+    account.dispatched(tail)              # in admit, nothing dry yet
+    run(1, "pack")                        # admit 1; the tail: not ready
+    run(2, "step.resolve")                # pack 2; not ready
+    run(4, "step.call")                   # resolve 4; READY: dry from here
+    assert account.is_dry and not any(account.dry.values())
+    run(8, "emit")                        # call 8: dry
+    run(16, "parked")                     # emit 16: dry
+    run(32, "admit.queue")                # parked 32: never charged
+    run(64, "prefill")                    # admit.queue 64: dry
+    clock.now += 128
+    later = _Tail(False)
+    account.dispatched(later)             # 128 of this prefill: dry
+    assert not account.is_dry
+    run(256, "prefill.first")             # the rest of it: not
+    run(512, "observe")
+    assert tail.polls == 3 and later.polls == 2   # one a switch, no more
+    assert account.dry == dict(
+        dict.fromkeys(PHASES[1:], 0.0), step=8.0, emit=16.0, admit=64.0,
+        prefill=128.0)
+    assert account.seconds == dict(
+        dict.fromkeys(PHASES, 0.0), admit=65.0, pack=2.0, step=12.0,
+        emit=16.0, parked=32.0, prefill=128.0 + 256.0 + 512.0)
+    assert account.parts == dict(
+        dict.fromkeys(PARTS, 0.0), **{
+            "step.resolve": 4.0, "step.call": 8.0, "admit.queue": 64.0,
+            "prefill.first": 512.0})
+    # a launch once, whether or not the engine said ``calling``
+    assert account.steps == 1
+    before = scrape()
+    stats = ServingMetrics()
+    stats.record_loop_pass(*account.drain(), passes=0)
+    got = delta(scrape(), before)
+    by_phase = [got["veles_serving_loop_dry_%s_seconds_total" % phase]
+                for phase in PHASES[1:]]
+    assert sum(by_phase) == got["veles_serving_loop_dry_seconds_total"] \
+        == 216.0
+    assert "veles_serving_loop_dry_parked_seconds_total" not in got
+    assert got["veles_serving_loop_seconds_total"] == 991.0
+    assert got["veles_serving_loop_step_launch_seconds_total"] == 12.0
+    assert got[counter_of("step.resolve")] == 4.0
+    assert stats.snapshot()["dry_share"] == round(216.0 / 991.0, 4)
+    assert not any(account.dry.values()) and not any(
+        account.parts.values())
+
+
+def test_a_lap_polls_inside_a_long_stretch(monkeypatch):
+    """The staging rows' stretch: the device runs dry inside it, and a
+    lap (the stretch ends, another of its name begins) sees it."""
+    clock = _Clock()
+    monkeypatch.setattr(scheduler_mod, "time", clock)
+    account = _LoopPhases()
+    tail = _Tail(False, False, True)
+    account.dispatched(tail)
+    account.switch("admit.stage")         # not ready
+    clock.now += 4
+    account.lap()                         # not ready
+    clock.now += 8
+    account.lap()                         # READY: dry from this lap on
+    clock.now += 16
+    account.lap()                         # 16: dry
+    clock.now += 32
+    account.dispatched(_Tail())           # 32 of the open stretch: dry
+    clock.now += 64
+    account.switch("admit")
+    assert tail.polls == 3 and account.current == "admit"
+    assert account.dry["admit"] == 48.0 == sum(account.dry.values())
+    assert account.parts["admit.stage"] == 124.0 \
+        == account.seconds["admit"]
+    assert account.steps == 0
+
+
+def test_a_deleted_tail_never_raises_out_of_a_switch(f32):
+    """``is_ready()`` on a buffer a later call donated raises: the
+    switch forgets such a tail and starts no spell; the loop lives."""
+    donating = jax.jit(lambda x: x + 1, donate_argnums=(0,))
+
+    def donated():
+        gone = jnp.zeros((4,)) + 1
+        donating(gone)
+        with pytest.raises(RuntimeError, match="deleted"):
+            gone.is_ready()
+        return gone
+    account = _LoopPhases()
+    account.dispatched(donated())
+    account.switch("pack")
+    assert account.tail is None and not account.is_dry
+    gone = jnp.zeros((4,)) + 1
+    account.dispatched(gone)
+    gone.delete()                   # deleted by hand: no poll at all
+    account.switch("emit")
+    assert account.tail is None and not account.is_dry
+    account.close()
+    # ... and in a running loop: the next pass meets the deleted tail
+    sch = _scheduler("phases-deleted-tail").start()
+    try:
+        want = sch.submit(PROMPT, 8, seed=3).result(240)
+        settle(sch)
+        gone = donated()
+        sch._phases.tail, sch._phases.is_dry = gone, False
+        assert sch.submit(PROMPT, 8, seed=3).result(240) == want
+        settle(sch)
+        assert sch._phases.tail is not gone
+    finally:
+        sch.close()
+
+
+def test_parts_lie_inside_their_phases_and_admissions_are_exact(f32):
+    sch = _scheduler("phases-parts").start()
+    try:
+        sch.submit(PROMPT, 8, seed=0).result(240)   # compile, settle
+        settle(sch)
+        before = scrape()
+        # longer than the chunk of 4: staging rows, chunks, first tokens
+        futs = [sch.submit(PROMPT + [i], 12, seed=i) for i in range(5)]
+        for f in futs:
+            f.result(240)
+        settle(sch)
+        got = delta(scrape(), before)
+        snapshot = sch.metrics()
+    finally:
+        sch.close()
+    assert got["veles_serving_loop_admissions_total"] == 5 \
+        == got["veles_serving_first_tokens_total"]
+    for part in PARTS:
+        phase = part.partition(".")[0]
+        assert 0 < got[counter_of(part)] <= got[counter_of(phase)], part
+    for phase in ("admit", "prefill", "step"):
+        inside = sum(got[counter_of(p)] for p in PARTS
+                     if p.startswith(phase + "."))
+        assert inside <= got[counter_of(phase)] * (1 + 1e-9), phase
+    # every instant of a step is its launch or its landing
+    assert got[counter_of("step.resolve")] + got[counter_of("step.call")] \
+        + got[counter_of("step.land")] == pytest.approx(
+            got[counter_of("step")], rel=1e-6)
+    assert got["veles_serving_loop_step_launch_seconds_total"] \
+        == pytest.approx(got[counter_of("step.resolve")]
+                         + got[counter_of("step.call")], rel=1e-6)
+    # the dry account: by phase it sums to the whole, inside the loop's
+    # seconds, and each phase's inside that phase's
+    dry = {phase: got["veles_serving_loop_dry_%s_seconds_total" % phase]
+           for phase in PHASES[1:]}
+    assert sum(dry.values()) == pytest.approx(
+        got["veles_serving_loop_dry_seconds_total"], rel=1e-6)
+    assert 0 < got["veles_serving_loop_dry_seconds_total"] \
+        <= got["veles_serving_loop_seconds_total"]
+    for phase, took in dry.items():
+        assert 0 <= took <= got[counter_of(phase)] * (1 + 1e-9), phase
+    assert 0 < snapshot["dry_share"] <= 1
+
+
 def test_step_and_request_counts_are_exact_and_repeat(f32, monkeypatch):
     launches = []
     real = scheduler_mod.paged_decode_step
@@ -253,25 +455,41 @@ def test_one_flush_a_pass_and_silent_annotations(f32):
         account.seconds.values())
 
 
-def test_the_benchmarks_parser_reads_every_ratio_metric():
-    """Through the benchmark's own tiny serve run: the driver's
-    ``/metrics`` parser and the ``ratio`` reader give every one of the
-    ten metrics of this account a finite number."""
-    from benchmark.readers import ratio
+@pytest.fixture(scope="module")
+def serve_record():
+    """The benchmark's own tiny serve run: what its driver's
+    ``/metrics`` parser kept of the window."""
     from benchmark.tests.test_benchmark import _serve_run
     ok, values, record = _serve_run()
     assert ok, values
+    return record
+
+
+def per_layer():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        manifest = {m["name"]: m for m in json.load(f)["per_layer"]}
-    read = {}
-    for name in RATIO_METRICS:
-        with open(os.path.join(ROOT, "benchmark", "metrics",
-                               name + ".json")) as f:
-            spec = json.load(f)
-        assert spec["reader"] == "ratio" and name in manifest
-        assert spec["params"]["num"] in record["counters"], name
-        assert spec["params"]["den"] in record["counters"], name
-        read[name] = ratio.read(record, spec["params"])
+        return {m["name"]: m for m in json.load(f)["per_layer"]}
+
+
+def read_ratio(name, record):
+    """(the manifest's entry, the ``ratio`` reader's number) of one
+    metric whose data file names two counters ``GET /metrics`` prints."""
+    from benchmark.readers import ratio
+    manifest = per_layer()
+    with open(os.path.join(ROOT, "benchmark", "metrics",
+                           name + ".json")) as f:
+        spec = json.load(f)
+    assert spec["reader"] == "ratio" and name in manifest
+    assert spec["params"]["num"] in record["counters"], name
+    assert spec["params"]["den"] in record["counters"], name
+    return manifest[name], ratio.read(record, spec["params"])
+
+
+def test_the_benchmarks_parser_reads_every_ratio_metric(serve_record):
+    """The driver's ``/metrics`` parser and the ``ratio`` reader give
+    every one of the ten metrics of the phases a finite number."""
+    manifest = per_layer()
+    read = {name: read_ratio(name, serve_record)[1]
+            for name in RATIO_METRICS}
     maybe = read.pop("decode_step_after_prefill_ms")
     assert maybe is None or 0 <= maybe < float("inf")
     for name, value in read.items():
@@ -281,6 +499,29 @@ def test_the_benchmarks_parser_reads_every_ratio_metric():
     assert read["sched_step_share_pct"] \
         + read["sched_prefill_share_pct"] <= 100
     assert read["decode_step_ms"] > 0
+
+
+@pytest.mark.parametrize("name", PART_METRICS)
+def test_the_benchmarks_parser_reads_a_part_or_dry_metric(
+        name, serve_record):
+    entry, value = read_ratio(name, serve_record)
+    assert value is not None and 0 <= value < float("inf")
+    assert entry["source"] == "program_counter"
+    assert entry["better"] == "lower" and len(entry["workloads"]) == 4
+    if entry["unit"] == "%":
+        assert value <= 100
+    coarser = {"step_resolve_ms": "step_launch_ms",
+               "step_launch_ms": "decode_step_ms",
+               "step_land_wait_ms": "decode_step_ms",
+               "sched_admit_queue_ms_per_pass": "sched_admit_ms_per_pass",
+               "sched_reap_ms_per_pass": "sched_admit_ms_per_pass",
+               "dry_admit_pct": "device_dry_pct",
+               "dry_prefill_pct": "device_dry_pct",
+               "dry_step_pct": "device_dry_pct"}.get(name)
+    if coarser:     # a part of what the coarser metric bounds
+        assert value <= read_ratio(coarser, serve_record)[1]
+    else:
+        assert value > 0
 
 
 # -- device-side names --------------------------------------------------------

@@ -250,7 +250,7 @@ def _paged_step_tp_cached(cache_key, closure):
 
 def paged_decode_step(forwards, cache, toks, pos, tables, temps,
                       topks, seeds, counts, want_hidden=False,
-                      params=None, slots=None):
+                      params=None, slots=None, resolved=None):
     """Run ONE decode step over a PACKED batch of active slots
     against ``cache`` (:class:`serving.kv_slots.PagedKVCache`,
     updated in place).
@@ -284,6 +284,10 @@ def paged_decode_step(forwards, cache, toks, pos, tables, temps,
     empty): ``"moe"`` the routed layers' int32 [layers, 4 or 5],
     ``"stack"`` a looped stack's float32 [stacks, 2 + passes] = (passes
     run, live rows, the live rows' exit mass of each pass).
+
+    ``resolved`` — called once, with no arguments, when the compiled
+    step is in hand and only its call is left (a caller that times the
+    two halves of a launch apart; it keys no executable).
 
     A cache built with a tensor-parallel context (``cache.tp_`` —
     serving/tp.py) runs the step SPMD over the tp mesh: ``params`` ride
@@ -338,6 +342,8 @@ def paged_decode_step(forwards, cache, toks, pos, tables, temps,
                 forwards, want_hidden=want_hidden,
                 attend=ctx.decode_attention if ctx is not None
                 else None)))
+    if resolved is not None:
+        resolved()
     old = cache.first_leaf()
     got = fn(
         params, jnp.asarray(toks, jnp.int32),
@@ -411,7 +417,7 @@ def _verify_step_cached(cache_key, closure):
 
 def verify_step_paged(forwards, cache, toks, pos, lens, tables,
                       temps, topks, seeds, counts,
-                      want_hidden=False, params=None):
+                      want_hidden=False, params=None, resolved=None):
     """Score a PACKED batch of speculative token runs in ONE model
     pass against ``cache`` (:class:`serving.kv_slots.PagedKVCache`,
     updated in place) — the batched verify step of speculative
@@ -435,7 +441,8 @@ def verify_step_paged(forwards, cache, toks, pos, lens, tables,
     ``want_hidden`` additionally returns the [B, K1, d] f32 hidden
     states (the final unit's input at every scored position) — after
     accepting L tokens the scheduler carries row position L-1's
-    hidden into the next iteration's model-based draft."""
+    hidden into the next iteration's model-based draft.
+    ``resolved`` as in :func:`paged_decode_step`."""
     from veles_tpu import dtypes
     from veles_tpu.config import root
     ctx = cache.tp_
@@ -462,6 +469,8 @@ def verify_step_paged(forwards, cache, toks, pos, lens, tables,
         cache_key,
         _StepClosure(_make_verify_step(forwards,
                                        want_hidden=want_hidden)))
+    if resolved is not None:
+        resolved()
     old = cache.first_leaf()
     got = fn(
         params, toks, jnp.asarray(pos, jnp.int32),

@@ -187,54 +187,89 @@ class SLOTracker:
         return out
 
 
+#: the scheduler loop's phases (``scheduler.PHASES`` is this dict's
+#: keys): what ``veles_serving_loop_<phase>_seconds_total`` counts
+LOOP_PHASES = {
+    "parked": "waiting for work (nothing queued, active or owed)",
+    "admit": "of its own bookkeeping: queue expiry, admission sizing "
+             "and block claims, the reap, owed preemptions",
+    "prefill": "of prompt processing: the input build, the prefill or "
+               "prefill-chunk dispatch, the KV insert, the first "
+               "token's readback",
+    "aux": "in embed/score and prefix export/import jobs",
+    "draft": "of speculative drafting (0 with speculation off)",
+    "pack": "building a decode/verify step's inputs: buckets, the "
+            "numpy rows, the block tables",
+    "step": "launching decode/verify steps and waiting for their "
+            "tokens (under launch-ahead: the launch of a step, then "
+            "the wait for the step BEFORE it): the one phase that "
+            "waits on the device",
+    "emit": "accepting tokens: stream sinks, retirement, slot and "
+            "block release, futures",
+    "observe": "spent on being observed: step statistics, tenant "
+               "metering, request tracing, the KV gauges, this "
+               "account's flush",
+}
+#: the parts (``scheduler.PARTS``): stretches with a name of their own
+#: inside a phase, whose seconds are the phase's too;
+#: ``veles_serving_loop_<phase>_<part>_seconds_total``
+LOOP_PARTS = {
+    "admit.queue": "from asking for the scheduler's lock to releasing "
+                   "it, less parked: the lock's wait, queue expiry, "
+                   "the export sweep, admission sizing and block "
+                   "claims, the owed preemption",
+    "admit.reap": "recovering lost pools, reaping cancelled and "
+                  "expired requests, evicting owed preemptions",
+    "admit.stage": "building a joiner's sequence and its staging "
+                   "rows (allocated and zeroed on the device from the "
+                   "loop thread)",
+    "prefill.first": "waiting for a first token: its chunk, whatever "
+                     "was queued before it, the sampler",
+    "step.resolve": "of a step's launch up to the compiled step in "
+                    "hand: the architecture signature, the cache key, "
+                    "the lookup",
+    "step.call": "of the rest of a step's launch: the argument "
+                 "hand-over and the call, until it returns",
+    "step.land": "waiting for a launched step's tokens: the one wait "
+                 "on the device for a step",
+}
+
+
 def _loop_series():
     """The scheduler loop's phase account (``scheduler._LoopPhases``:
-    seconds of the loop thread by phase, self time, flushed once a
-    pass) and the request boundaries beside it.  Each a family of its
-    own, unlabelled, ``veles_serving_*_total``, so a scraper that sums
-    label sets away and keeps ``_total`` names reads every one."""
-    return {
-        "parked": metrics.counter(
-            "veles_serving_loop_parked_seconds_total",
-            "loop seconds waiting for work (nothing queued, active "
-            "or owed)"),
-        "admit": metrics.counter(
-            "veles_serving_loop_admit_seconds_total",
-            "loop seconds of its own bookkeeping: queue expiry, "
-            "admission sizing and block claims, the reap, owed "
-            "preemptions"),
-        "prefill": metrics.counter(
-            "veles_serving_loop_prefill_seconds_total",
-            "loop seconds of prompt processing: the input build, the "
-            "prefill or prefill-chunk dispatch, the KV insert, the "
-            "first token's readback"),
-        "aux": metrics.counter(
-            "veles_serving_loop_aux_seconds_total",
-            "loop seconds in embed/score and prefix export/import "
-            "jobs"),
-        "draft": metrics.counter(
-            "veles_serving_loop_draft_seconds_total",
-            "loop seconds of speculative drafting (0 with "
-            "speculation off)"),
-        "pack": metrics.counter(
-            "veles_serving_loop_pack_seconds_total",
-            "loop seconds building a decode/verify step's inputs: "
-            "buckets, the numpy rows, the block tables"),
-        "step": metrics.counter(
-            "veles_serving_loop_step_seconds_total",
-            "loop seconds launching decode/verify steps and waiting "
-            "for their tokens (under launch-ahead: the launch of a "
-            "step, then the wait for the step BEFORE it): the one "
-            "phase that waits on the device"),
-        "emit": metrics.counter(
-            "veles_serving_loop_emit_seconds_total",
-            "loop seconds accepting tokens: stream sinks, "
-            "retirement, slot and block release, futures"),
-        "observe": metrics.counter(
-            "veles_serving_loop_observe_seconds_total",
-            "loop seconds spent on being observed: step statistics, "
-            "tenant metering, request tracing, the KV gauges, this "
-            "account's flush"),
+    seconds of the loop thread by phase and by part, self time, and
+    the seconds of each phase in which the device had run dry, flushed
+    once a pass) and the request boundaries beside it.  Each a family
+    of its own, unlabelled, ``veles_serving_*_total``, so a scraper
+    that sums label sets away and keeps ``_total`` names reads every
+    one."""
+    series = {}
+    for phase, what in LOOP_PHASES.items():
+        series[phase] = metrics.counter(
+            "veles_serving_loop_%s_seconds_total" % phase,
+            "loop seconds " + what)
+        if phase != "parked":
+            series["dry_" + phase] = metrics.counter(
+                "veles_serving_loop_dry_%s_seconds_total" % phase,
+                "loop seconds of %s in which the device had run dry: "
+                "the newest dispatch had finished and nothing had "
+                "been dispatched since (a lower bound)" % phase)
+    for part, what in LOOP_PARTS.items():
+        series[part] = metrics.counter(
+            "veles_serving_loop_%s_seconds_total"
+            % part.replace(".", "_"),
+            "loop seconds " + what)
+    series.update({
+        "dry": metrics.counter(
+            "veles_serving_loop_dry_seconds_total",
+            "loop seconds in which the device had run dry, over "
+            "every phase but parked"),
+        "step_launch": metrics.counter(
+            "veles_serving_loop_step_launch_seconds_total",
+            "loop seconds launching steps: step_resolve + step_call"),
+        "admissions": metrics.counter(
+            "veles_serving_loop_admissions_total",
+            "requests the loop began to admit (resumes included)"),
         "loop": metrics.counter(
             "veles_serving_loop_seconds_total",
             "loop seconds in every phase but parked"),
@@ -275,7 +310,8 @@ def _loop_series():
         "first_tokens": metrics.counter(
             "veles_serving_first_tokens_total",
             "requests that reached their first token"),
-    }
+    })
+    return series
 
 
 def _registry_series():
@@ -406,11 +442,6 @@ def _registry_series():
         "prefill_chunk_tokens": metrics.counter(
             "veles_serving_prefill_chunk_tokens_total",
             "prompt tokens prefilled through the chunked path"),
-        "prefill_chunk_ms": metrics.histogram(
-            "veles_serving_prefill_chunk_ms",
-            "wall time of one prefill chunk — the decode-stall bound "
-            "each loop iteration pays for a joining long prompt",
-            buckets=MS_BUCKETS),
         "cancelled": metrics.counter(
             "veles_serving_requests_cancelled_total",
             "requests cancelled mid-flight (client gone/disconnected)"
@@ -980,6 +1011,9 @@ class ServingMetrics:
         #: launches that ran ahead, the rows a landing discarded
         self.steps_launched = self.steps_ahead = 0
         self.rows_discarded = 0
+        #: ... and of its loop's seconds (all phases but parked), and
+        #: those of them in which the device had run dry
+        self.loop_seconds = self.dry_seconds = 0.0
         #: replica-side SLO accounting (TTFT + e2e vs the per-class
         #: objectives under root.common.slo.*)
         self.slo = SLOTracker("serving")
@@ -1266,13 +1300,12 @@ class ServingMetrics:
         self._loop["first_tokens"].inc()
         self.slo.record(cls, "ttft", ttft_ms)
 
-    def record_prefill_chunk(self, tokens, chunk_ms):
+    def record_prefill_chunk(self, tokens):
         with self._lock:
             self.prefill_chunks += 1
             self.prefill_chunk_tokens += int(tokens)
         self._global["prefill_chunks"].inc()
         self._global["prefill_chunk_tokens"].inc(int(tokens))
-        self._global["prefill_chunk_ms"].observe(chunk_ms)
 
     def set_kv_blocks(self, used, free):
         self._global["kv_blocks_used"].set(int(used))
@@ -1312,15 +1345,19 @@ class ServingMetrics:
 
     def record_loop_pass(self, seconds, steps, steps_after_prefill,
                          step_after_prefill_seconds, steps_ahead=0,
-                         rows_discarded=0, passes=1, pool_copies=None):
+                         rows_discarded=0, parts=None, dry=None,
+                         admissions=0, passes=1, pool_copies=None):
         """The scheduler loop's phase account since its last flush
-        (``scheduler._LoopPhases.drain()``): ``seconds`` by phase.
+        (``scheduler._LoopPhases.drain()``): ``seconds`` by phase,
+        ``parts`` by part (each inside its phase's seconds), ``dry``
+        by phase (the seconds in which the device had run dry).
         The loop calls this once a pass, so the account costs the
         registry one visit a pass however many phases ran.
         ``steps``: decode/verify launches (a step stretch that only
         waited adds seconds and none); ``steps_ahead`` /
         ``rows_discarded``: the launch-ahead's two counts
-        (``scheduler._step_paged``, ``_land_flight``).
+        (``scheduler._step_paged``, ``_land_flight``);
+        ``admissions``: requests that entered ``_begin_admit``.
         ``pool_copies``: the cache's running count (the warm-up's
         included); what it grew by since the last pass is counted."""
         if pool_copies is not None \
@@ -1328,12 +1365,29 @@ class ServingMetrics:
             self._global["pool_copies"].inc(
                 pool_copies - self._pool_copies_seen)
             self._pool_copies_seen = pool_copies
+        loop = self._loop
         for phase, took in seconds.items():
             if took > 0:
-                self._loop[phase].inc(took)
-        self._loop["loop"].inc(
-            sum(seconds.values()) - seconds["parked"])
-        self._loop["passes"].inc(passes)
+                loop[phase].inc(took)
+        busy = sum(seconds.values()) - seconds["parked"]
+        loop["loop"].inc(busy)
+        self.loop_seconds += busy
+        if parts:
+            for part, took in parts.items():
+                if took > 0:
+                    loop[part].inc(took)
+            loop["step_launch"].inc(parts["step.resolve"]
+                                    + parts["step.call"])
+        ran_dry = sum(dry.values()) if dry else 0.0
+        if ran_dry:     # most passes: the device never ran dry
+            for phase, took in dry.items():
+                if took > 0:
+                    loop["dry_" + phase].inc(took)
+            loop["dry"].inc(ran_dry)
+            self.dry_seconds += ran_dry
+        if admissions:
+            loop["admissions"].inc(admissions)
+        loop["passes"].inc(passes)
         if steps:
             self._loop["steps"].inc(steps)
             self.steps_launched += steps
@@ -1479,6 +1533,9 @@ class ServingMetrics:
                     self.steps_ahead / self.steps_launched, 4)
                 if self.steps_launched else None,
                 "rows_discarded": self.rows_discarded,
+                "dry_share": round(
+                    self.dry_seconds / self.loop_seconds, 4)
+                if self.loop_seconds else None,
                 "prefill_chunks": self.prefill_chunks,
                 "prefill_chunk_tokens": self.prefill_chunk_tokens,
                 "requests_cancelled": self.cancelled,

@@ -197,16 +197,37 @@ another), ``prefill``, ``aux``, ``draft``, ``pack`` (a step's inputs),
 under launch-ahead those of the step BEFORE it: the one phase that
 waits on the device; a wait alone adds seconds and no step), ``emit``
 and ``observe`` (statistics, metering, request
-tracing, gauges, the account's own flush).  ``with self._phases(name)``
+tracing, gauges, the account's own flush).  Seven stretches inside
+three of them have names of their own, the ``PARTS``: ``admit.queue``
+(from asking for the scheduler's lock to releasing it, less
+``parked``), ``admit.reap`` (lost pools, the reap, owed preemptions),
+``admit.stage`` (a joiner's sequence and its staging rows),
+``step.resolve`` (a launch up to the compiled step in hand),
+``step.call`` (the rest of the launch), ``step.land`` (the wait for a
+launched step's tokens) and ``prefill.first`` (the wait for a first
+token); a part's seconds are its phase's seconds too, and what no part
+names is the phase's remainder.  ``with self._phases(name)``
 marks a stretch; :class:`_LoopPhases` keeps plain floats on the loop
 thread and one ``ServingMetrics.record_loop_pass`` a pass moves them
-into ``veles_serving_loop_<phase>_seconds_total`` (self times: a
+into ``veles_serving_loop_<phase>_seconds_total`` and
+``veles_serving_loop_<phase>_<part>_seconds_total`` (self times: a
 nested block stops the outer clock).  Each stretch is also a
-``veles.sched.<phase>`` ``jax.profiler.TraceAnnotation`` with the same
-boundaries: under a profiler session the phases lie on the host plane
-beside the device's operations, and without one the annotation does
-nothing, so no switch guards it.  docs/observability.md, "Profiling
-the serving loop".
+``veles.sched.<phase>`` or ``veles.sched.<phase>.<part>``
+``jax.profiler.TraceAnnotation`` with the same
+boundaries: under a profiler session the stretches lie on the host
+plane beside the device's operations, and without one the annotation
+does nothing, so no switch guards it.
+
+The dry account rides the same switches: every dispatch site on the
+loop thread hands the account its newest result
+(``_LoopPhases.dispatched``), a switch polls it (``is_ready()``, no
+wait), and from the first switch that finds it ready until the next
+dispatch every stretch is charged to
+``veles_serving_loop_dry_<phase>_seconds_total`` too: the seconds in
+which the device had nothing left to do, by what the host was doing.
+A lower bound (the stretch in which the device ran dry is not
+charged); ``parked`` is never charged.  docs/observability.md,
+"Profiling the serving loop".
 """
 
 import collections
@@ -228,7 +249,8 @@ from veles_tpu.serving.engine import (
 from veles_tpu.serving.kv_host import HostKVTier
 from veles_tpu.serving.kv_slots import (
     PagedKVCache, blocks_only_refusal, slot_state_units, stacked_units)
-from veles_tpu.serving.metrics import ServingMetrics
+from veles_tpu.serving.metrics import (
+    LOOP_PARTS, LOOP_PHASES, ServingMetrics)
 from veles_tpu.serving.prefill import (
     chunked_supported, prefill, prefill_chunk, serving_refusal,
     serving_window)
@@ -394,103 +416,176 @@ def _metering_enabled():
     return bool(root.common.tsdb.get("metering", True))
 
 
-#: the phases of a loop pass (module docstring, "Phase account"); one
-#: ``veles_serving_loop_<phase>_seconds_total`` each (serving/metrics.py)
-PHASES = ("parked", "admit", "prefill", "aux", "draft", "pack", "step",
-          "emit", "observe")
+#: the phases of a loop pass (module docstring, "Phase account"):
+#: parked, admit, prefill, aux, draft, pack, step, emit, observe; one
+#: ``veles_serving_loop_<phase>_seconds_total`` each (serving/metrics.py,
+#: which says what each counts)
+PHASES = tuple(LOOP_PHASES)
+#: the parts, ``<phase>.<part>``: stretches with a name of their own
+#: inside admit (queue, reap, stage), prefill (first) and step (resolve,
+#: call, land); one ``veles_serving_loop_<phase>_<part>_seconds_total``
+#: each, whose seconds are their phase's seconds too
+PARTS = tuple(LOOP_PARTS)
+#: every stretch the account can be in -> the phase it is charged to
+_PHASE_OF = {s: s.partition(".")[0] for s in PHASES + PARTS}
+#: ... -> its span's name
+_SPAN_OF = {s: "veles.sched." + s for s in PHASES + PARTS}
+
+
+def _newest(row_caches):
+    """The array asked for last of a staging dict's ({layer: {name:
+    array}}): the device runs in order, so it is ready last."""
+    return list(list(row_caches.values())[-1].values())[-1]
 
 
 class _Phase(object):
-    """``with phases("step") as ph``: one stretch of one phase;
-    ``ph.seconds`` is its last uninterrupted stretch, read after the
-    block (the whole of it where nothing nests, as in ``step``).
-    ``launch=False``: a ``step`` stretch that only waits for a step
-    launched earlier, so it adds seconds and no step."""
+    """``with phases("pack")`` / ``with phases("step.land")``: one
+    stretch of a phase or of one of its parts; leaving it resumes the
+    stretch it interrupted."""
 
-    __slots__ = ("account", "name", "launch", "outer", "seconds")
+    __slots__ = ("account", "name", "outer")
 
-    def __init__(self, account, name, launch=True):
-        self.account, self.name, self.launch = account, name, launch
+    def __init__(self, account, name):
+        self.account, self.name = account, name
 
     def __enter__(self):
         self.outer = self.account.switch(self.name)
-        if self.outer != self.name:    # a stretch of its own
-            self.account.launching = self.launch
         return self
 
     def __exit__(self, *exc):
-        self.seconds = self.account.elapsed()
         self.account.switch(self.outer)
         return False
 
 
 class _LoopPhases(object):
-    """The loop thread's flat phase account.  Exactly one phase is
-    current at any instant (``admit``, the loop's own bookkeeping,
-    where no block says otherwise); switching charges the seconds
-    since the last switch to the phase that was current, in a plain
-    float, and moves the ``veles.sched.<phase>`` annotation with it,
-    so the spans in a profiler trace and the counters share their
-    boundaries.  An inner block stops the outer phase's clock and
-    leaving it resumes it: the numbers are self times.  Loop thread
-    only: no lock, no registry; :meth:`drain` hands a pass's totals
-    to ``ServingMetrics.record_loop_pass``, once a pass."""
+    """The loop thread's flat phase account.  Exactly one stretch is
+    current at any instant: a phase (``admit``, the loop's own
+    bookkeeping, where no block says otherwise) or one of its
+    ``PARTS``; switching charges the seconds since the last switch to
+    the phase of the stretch that was current, and to the part if it
+    was one, in plain floats, and moves the ``veles.sched.<stretch>``
+    annotation with it, so the spans in a profiler trace and the
+    counters share their boundaries.  An inner block stops the outer
+    stretch's clock and leaving it resumes it: the numbers are self
+    times.
+
+    The dry account rides the same switches.  ``tail`` is the result
+    of the newest dispatch (:meth:`dispatched`); a switch that finds it
+    ready starts a dry spell AT that switch, and from then on every
+    stretch is charged in full to ``dry[phase]`` too, until the next
+    dispatch (which charges what has run of its own stretch and ends
+    the spell).  The stretch in which the device ran dry is not
+    charged, so the account is a lower bound, short by at most one
+    stretch a spell; ``parked`` is never charged.
+
+    Loop thread only: no lock, no registry; :meth:`drain` hands a
+    pass's totals to ``ServingMetrics.record_loop_pass``, once a
+    pass."""
 
     def __init__(self):
         self._reset()
         self.current = "admit"
-        self.launching = True      # a step stretch counts one step
+        self.tail = None           # the newest dispatch's result
+        self.is_dry = False        # ... is known to be ready
         self._since = time.perf_counter()
         self._span = annotation("veles.sched.admit")
         self._span.__enter__()
 
     def _reset(self):
         self.seconds = dict.fromkeys(PHASES, 0.0)
+        self.parts = dict.fromkeys(PARTS, 0.0)
+        self.dry = dict.fromkeys(PHASES[1:], 0.0)
         self.steps = self.steps_after_prefill = 0
         self.step_after_prefill_seconds = 0.0
         self.prefilled = False     # a prefill phase ran this pass
+        self.admissions = 0        # requests that entered _begin_admit
         # launch-ahead (InferenceScheduler._step_paged), flushed with
         # the pass: steps launched from the tokens of a step nobody
         # had read yet, and rows of a landed step that were discarded
         self.steps_ahead = self.rows_discarded = 0
 
-    def __call__(self, name, launch=True):
-        return _Phase(self, name, launch)
+    def __call__(self, name):
+        return _Phase(self, name)
 
     def elapsed(self):
-        """Seconds since the current phase was entered or resumed."""
+        """Seconds since the current stretch was entered or resumed."""
         return time.perf_counter() - self._since
 
     def switch(self, name):
-        """Make ``name`` the current phase; returns the one it was.
-        A ``step`` stretch counts as one step unless its block said
-        ``launch=False`` (the wait for a step launched before)."""
+        """Make ``name`` the current stretch; returns the one it was.
+        A step is counted where its ``step.resolve`` stretch ends: a
+        launch once, whether or not the engine said when it had its
+        executable (:meth:`calling`), and a landing never."""
         was = self.current
-        if name == was:
-            return was
+        if name != was:
+            self._turn(was, name)
+        return was
+
+    def lap(self):
+        """A boundary inside the current stretch, for one that is long
+        and dispatches as it goes (the staging rows): the stretch ends
+        and another of its name begins, so the dry account polls here
+        too.  Not for ``step.resolve``, whose end counts a step."""
+        self._turn(self.current, self.current)
+
+    def _turn(self, was, name):
         now = time.perf_counter()
         took = now - self._since
-        self.seconds[was] += took
-        if was == "step":
-            self.steps += self.launching
+        phase = _PHASE_OF[was]
+        self.seconds[phase] += took
+        if was != phase:
+            self.parts[was] += took
+        if phase == "step":
+            launched = was == "step.resolve"
+            self.steps += launched
             if self.prefilled:
-                self.steps_after_prefill += self.launching
+                self.steps_after_prefill += launched
                 self.step_after_prefill_seconds += took
-        if name == "prefill":
+        dry, tail = self.is_dry, self.tail
+        if dry:
+            if phase != "parked":
+                self.dry[phase] += took
+        elif tail is not None:
+            # a tail some later call donated is forgotten: its
+            # ``is_ready()`` raises (and kills the process outright
+            # after an explicit ``delete()``)
+            try:
+                if tail.is_deleted():
+                    self.tail = None
+                else:
+                    self.is_dry = tail.is_ready()
+            except Exception:
+                self.tail = None
+        if _PHASE_OF[name] == "prefill":
             self.prefilled = True
-        self.launching = True
         self.current, self._since = name, now
         self._span.__exit__(None, None, None)
-        self._span = annotation("veles.sched." + name)
+        self._span = annotation(_SPAN_OF[name])
         self._span.__enter__()
-        return was
+
+    def calling(self):
+        """The launch has its executable (the engine's ``resolved``
+        hook): what is left of it is ``step.call``."""
+        self.switch("step.call")
+
+    def dispatched(self, tail):
+        """Work went to the device's queue in the current stretch, and
+        ``tail`` is its newest result: ends a dry spell, charging what
+        has run of this stretch.  Hand over an array that no later
+        call donates (a switch forgets a tail it finds deleted).
+        ``None``: work whose results are donated on or read back at
+        once; no spell starts before the next hand-over."""
+        dry, self.is_dry, self.tail = self.is_dry, False, tail
+        if dry and self.current != "parked":
+            self.dry[_PHASE_OF[self.current]] += self.elapsed()
 
     def drain(self):
         """The totals since the last drain, as the arguments of
         ``ServingMetrics.record_loop_pass``; the pass ends here."""
         out = (self.seconds, self.steps, self.steps_after_prefill,
                self.step_after_prefill_seconds, self.steps_ahead,
-               self.rows_discarded)
+               self.rows_discarded, self.parts, self.dry,
+               self.admissions)
         self._reset()
         return out
 
@@ -1475,6 +1570,8 @@ class InferenceScheduler(Logger):
             return
         from veles_tpu.serving.openai_api import (
             pooled_embeddings, score_rows)
+        # read back at once: nothing of it is left to poll
+        self._phases.dispatched(None)
         try:
             faults.fire("serving.scheduler.aux")
             if kind == "embed":
@@ -1505,6 +1602,7 @@ class InferenceScheduler(Logger):
             kind, payload, fut = self._prefix_jobs.popleft()
         if fut.done():   # consumer already gave up
             return
+        self._phases.dispatched(None)   # a gather read back, or pools
         try:
             if kind == "export":
                 out = self._prefix_export_job(cache, payload)
@@ -1957,7 +2055,9 @@ class InferenceScheduler(Logger):
         Every instant is charged to one of ``PHASES``: ``admit``
         where no block names another."""
         phases = self._phases
-        with self._wake:
+        # the wait for the lock (the HTTP threads hold it in submit())
+        # and the work under it
+        with phases("admit.queue"), self._wake:
             self._working = False
             if self._idle_locked():
                 with phases("parked"):
@@ -2010,11 +2110,13 @@ class InferenceScheduler(Logger):
         # jax work OUTSIDE the lock: submit() must never block on
         # a device step
         faults.fire("serving.scheduler.loop")
-        # the except paths below recover at once; this catches a call
-        # that failed where none could (a promotion, under the lock)
-        self._recover_pools(cache)
-        self._reap(cache)
-        self._do_preempts(cache)
+        with phases("admit.reap"):
+            # the except paths below recover at once; this catches a
+            # call that failed where none could (a promotion, under
+            # the lock)
+            self._recover_pools(cache)
+            self._reap(cache)
+            self._do_preempts(cache)
         with phases("observe"):
             self._sync_kv_gauges(cache)
         # a pass that admits is the loop's longest (the joiner's
@@ -2173,6 +2275,7 @@ class InferenceScheduler(Logger):
         if not pairs:
             return []
         demoted = 0
+        self._phases.dispatched(None)   # a gather, read back at once
         try:
             layers = cache.export_blocks([b for b, _ in pairs])
             for j, (bid, path) in enumerate(pairs):
@@ -2219,6 +2322,7 @@ class InferenceScheduler(Logger):
             cache.reclaim(ids)
             self.info("host-tier promotion failed: %r", e)
             return 0
+        self._phases.dispatched(None)
         covered = (len(dev) + len(entries)) * bs
         _, rejected = self.prefix_.insert(list(seq[:covered]),
                                           dev + ids)
@@ -2386,6 +2490,7 @@ class InferenceScheduler(Logger):
         prompt + the kept generated prefix, so the re-prefill rebuilds
         exactly the K/V its decode steps had written before eviction."""
         req.t_admit = time.monotonic()
+        self._phases.admissions += 1
         if req.kv_import is not None and not req.preempts:
             # disaggregated handoff: the exported blocks ARE the
             # prefill — scatter them in and go straight to decode.
@@ -2395,6 +2500,12 @@ class InferenceScheduler(Logger):
             with self._phases("prefill"):
                 self._admit_import(req, cache)
             return
+        with self._phases("admit.stage"):
+            self._stage(req, cache)
+
+    def _stage(self, req, cache):
+        """``admit.stage``: the joiner's sequence and its staging
+        rows; what prefills at once does so as ``prefill``."""
         seq = list(req.prompt) + list(req.generated)
         if req.preempts and req.generated:
             self.stats.record_resume(len(seq))
@@ -2426,7 +2537,6 @@ class InferenceScheduler(Logger):
             with self._phases("prefill"):
                 self._admit_oneshot(req, cache)
             return
-        from veles_tpu import dtypes
         req.pf_chunk, req.pf_widest = chunk, self.prefill_widest
         # the first chunk is the prompt's widest: the staging row
         # tiles it, and so every narrower one after it
@@ -2434,16 +2544,31 @@ class InferenceScheduler(Logger):
             p_len, chunk_width(p_len, 0, chunk, req.pf_widest))
         req.pf_off = 0
         try:
-            req.pf_caches = {
-                i: u.init_cache(1, req.pf_width,
-                                dtypes.compute_dtype())
-                for i, u in enumerate(self.forwards)
-                if hasattr(u, "init_cache")}
+            self._staging_rows(req)
         except Exception as e:
             self._retire(req, cache, error=e)
             return
         with self._lock:  # close() swaps the list under the same lock
             self._prefilling.append(req)
+
+    def _staging_rows(self, req):
+        """A fresh batch-1 staging row a cacheable layer, allocated
+        and zeroed on the device from this thread, with a lap after
+        each layer: the stretch is the loop's longest (milliseconds of
+        host time for microseconds of fills), and the device runs dry
+        INSIDE it, which one poll at its end would never charge.  The
+        fills are handed over together, last (no program donates a
+        staging row), so a spell that a lap starts counts their own
+        few microseconds on the device as dry."""
+        from veles_tpu import dtypes
+        phases, rows = self._phases, {}
+        for i, u in enumerate(self.forwards):
+            if hasattr(u, "init_cache"):
+                rows[i] = u.init_cache(1, req.pf_width,
+                                       dtypes.compute_dtype())
+                phases.lap()
+        req.pf_caches = rows
+        phases.dispatched(_newest(rows))
 
     def _admit_warm(self, req, cache):
         """Prefix-cache hit: the matched blocks already hold the K/V
@@ -2452,20 +2577,17 @@ class InferenceScheduler(Logger):
         tail only (near-zero TTFT when the tail is short).  The
         chunk narrows to block_size so every offset stays
         chunk-aligned from the warm boundary."""
-        from veles_tpu import dtypes
         bs = self.block_size
         p_len = len(req.pf_seq)
         req.pf_chunk = req.pf_widest = min(self.prefill_chunk, bs)
         req.pf_width = self._staging_width(p_len, self.prefill_chunk)
         req.pf_off = req.pf_matched * bs
         try:
-            req.pf_caches = {
-                i: u.init_cache(1, req.pf_width,
-                                dtypes.compute_dtype())
-                for i, u in enumerate(self.forwards)
-                if hasattr(u, "init_cache")}
+            with self._phases("admit.stage"):
+                self._staging_rows(req)
             req.pf_caches = cache.load_staging(
                 req.pf_caches, req.prefix_handle.blocks)
+            self._phases.dispatched(_newest(req.pf_caches))
         except Exception as e:
             self._retire(req, cache, error=e)
             return
@@ -2492,6 +2614,7 @@ class InferenceScheduler(Logger):
         except Exception as e:
             self._retire(req, cache, error=e)
             return
+        self._phases.dispatched(last)
         if self._tron:
             # the prefill phase so far: the input build and the call
             dt = self._phases.elapsed()
@@ -2529,12 +2652,13 @@ class InferenceScheduler(Logger):
                     self._prefilling.remove(req)
             self._retire(req, cache, error=e)
             return
+        self._phases.dispatched(last)
         # the prefill phase so far: the input build and the DISPATCH
         # of the chunk (no readback here: its device time rides in the
         # next step phase, veles_serving_steps_after_prefill_total)
-        dt = self._phases.elapsed()
+        dt = self._phases.elapsed() if self._tron else None
         with self._phases("observe"):
-            self.stats.record_prefill_chunk(clen, dt * 1e3)
+            self.stats.record_prefill_chunk(clen)
             if self._tron:
                 reqtrace.record(req.trace, "prefill_chunk",
                                 duration=dt, off=off, tokens=clen)
@@ -2560,6 +2684,7 @@ class InferenceScheduler(Logger):
             self._retire(req, cache, error=e)
             self._recover_pools(cache, e)
             return
+        self._phases.dispatched(None)   # the pools: the next step's
         if req.export_only:
             # prefill-role terminus: the blocks now hold the whole
             # prompt's K/V — gather them raw + the first-token
@@ -2575,9 +2700,14 @@ class InferenceScheduler(Logger):
         ``len(generated)`` of the request's stream) and join the
         active decode set — the shared tail of a finished prefill
         and an adopted KV import."""
-        tok = int(numpy.asarray(first_tokens(
+        first = first_tokens(
             last, [req.temperature], [req.top_k], [req.seed],
-            counts=[len(req.generated)]))[0])
+            counts=[len(req.generated)])
+        self._phases.dispatched(first)
+        # the wait for the chunk, for whatever was queued before it,
+        # and for the sampler
+        with self._phases("prefill.first"):
+            tok = int(numpy.asarray(first)[0])
         with self._phases("emit"):
             self._emit(req, tok)
         if req.t_first is None:  # TTFT is the FIRST first-token only
@@ -2614,6 +2744,7 @@ class InferenceScheduler(Logger):
             self._retire(req, cache, error=e)
             self._recover_pools(cache, e)
             return
+        self._phases.dispatched(None)
         if self._tron:
             with self._phases("observe"):
                 reqtrace.record(
@@ -2768,6 +2899,7 @@ class InferenceScheduler(Logger):
         # zeroed before anyone is told: a client that sees its request
         # fail may send the next one at once
         cache.reset_pools()
+        self._phases.dispatched(None)
         for req in victims:
             self._retire(req, cache, error=err)
         pfx = self.prefix_   # loop-owned, like the cache
@@ -2962,17 +3094,20 @@ class InferenceScheduler(Logger):
                 counts[j] = drawn
             tables[:n] = cache.table_rows(slots, t)
             want_h = self._draft_head is not None
-        with self._phases("step"):
+        phases = self._phases
+        with phases("step.resolve"):
             got = paged_decode_step(
                 self.forwards, cache, toks, pos, tables, temps, topks,
                 seeds, counts, want_hidden=want_h,
-                params=self.weights_.params, slots=rows)
+                params=self.weights_.params, slots=rows,
+                resolved=phases.calling)
             nxt, hid = got if want_h else (got, None)
+            phases.dispatched(nxt)
             # what the units counted comes with the step
             self._flight = _Flight(slots, [active[s] for s in slots],
                                    nxt, hid, cache.step_counts)
         if ahead:
-            self._phases.steps_ahead += 1
+            phases.steps_ahead += 1
             self._land_flight(cache, flight)
         if self.spec:
             self._land(cache)
@@ -2980,8 +3115,8 @@ class InferenceScheduler(Logger):
     def _land_flight(self, cache, flight):
         """Read a launched step's tokens and hand them on: count the
         step, meter it, emit a token a row, retire what finished.
-        The one wait on the device, charged to ``step`` as the launch
-        is, and counted as no step.
+        The one wait on the device for a step (``step.land``), charged
+        to ``step`` as the launch is, and counted as no step.
 
         A row whose request no longer holds its slot (it ended on a
         stop token with this step already launched, or was cancelled,
@@ -2995,7 +3130,7 @@ class InferenceScheduler(Logger):
         slot it gave back, which overwrite them; a prefix block
         promoted at retire lies below the row and holds the same
         values either way."""
-        with self._phases("step", launch=False):
+        with self._phases("step.land"):
             nxt = numpy.asarray(flight.nxt)
             hid = None if flight.hid is None \
                 else numpy.asarray(flight.hid)
@@ -3092,18 +3227,19 @@ class InferenceScheduler(Logger):
                 counts[j] = len(req.generated)
             tables[:n] = cache.table_rows(slots, t)
             want_h = self._draft_head is not None
-        with self._phases("step") as launch:
+        phases, t0 = self._phases, time.perf_counter()
+        with phases("step.resolve"):
             got = verify_step_paged(
                 self.forwards, cache, toks, pos, lens, tables, temps,
                 topks, seeds, counts, want_hidden=want_h,
-                params=self.weights_.params)
-            if want_h:
-                nxt, hid = got
-                hid = numpy.asarray(hid)
-            else:
-                nxt = got
-            nxt = numpy.asarray(nxt)
-        dt = launch.seconds
+                params=self.weights_.params, resolved=phases.calling)
+            nxt, hid = got if want_h else (got, None)
+            phases.dispatched(nxt)
+            with phases("step.land"):   # a verify step lands at once
+                if want_h:
+                    hid = numpy.asarray(hid)
+                nxt = numpy.asarray(nxt)
+        dt = time.perf_counter() - t0
         with self._phases("observe"):
             # metered BEFORE acceptance retires finished slots — the
             # step's residency belongs to everyone who rode the batch

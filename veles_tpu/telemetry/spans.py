@@ -28,6 +28,7 @@ import time
 from veles_tpu.logger import events as default_sink
 
 _span_ids = itertools.count(1)
+_TraceAnnotation = None
 
 
 def annotation(name):
@@ -36,8 +37,10 @@ def annotation(name):
     profiler session is active and does nothing otherwise (well under
     a microsecond an enter/exit pair), so no switch guards it.  Names
     of the program's own spans start ``veles.``."""
-    from jax.profiler import TraceAnnotation
-    return TraceAnnotation(name)
+    global _TraceAnnotation
+    if _TraceAnnotation is None:    # jax is imported at first use only
+        from jax.profiler import TraceAnnotation as _TraceAnnotation
+    return _TraceAnnotation(name)
 
 
 def next_span_id():
