@@ -15,7 +15,8 @@ counts and identities only, never a time limit:
 - a step that fails at its launch or at its landing fails the riders
   of both steps, and the next request is served;
 - the counters: ``steps_ahead`` > 0 on the plain path, exactly 0 under
-  speculation, ``steps_total`` the number of launches once settled.
+  speculation, ``steps_total`` the number of launches once settled,
+  ``steps_greedy`` those of them that carried no sampled row.
 """
 
 import contextlib
@@ -425,6 +426,58 @@ def test_counters_reach_the_registry_with_the_pass(gpt, f32):
     text = metrics.render_prometheus()
     assert "veles_serving_steps_ahead_total" in text
     assert "veles_serving_rows_discarded_total" in text
+
+
+@pytest.mark.parametrize("spec", [False, True], ids=["plain", "spec"])
+@pytest.mark.parametrize("traffic", ["greedy", "mixed"])
+def test_greedy_steps_are_the_launches_with_no_sampled_row(
+        gpt, f32, monkeypatch, traffic, spec):
+    """``veles_serving_steps_greedy_total`` counts the launches whose
+    every packed row is greedy (their sampler takes the argmax alone):
+    all of them under greedy traffic, and short of ``steps_total`` by
+    exactly the launches that carried a sampled row, on the decode
+    path and the verify path alike."""
+    from veles_tpu.telemetry import metrics
+    carried = []
+    # where each entry point takes the packed temperatures
+    for name, at in (("paged_decode_step", 5), ("verify_step_paged", 6)):
+        real = getattr(sched_mod, name)
+
+        def counting(*args, _real=real, _at=at, **kwargs):
+            carried.append(bool((args[_at] > 0).any()))
+            return _real(*args, **kwargs)
+        monkeypatch.setattr(sched_mod, name, counting)
+
+    def read():
+        return {name: metrics.counter(
+            "veles_serving_%s_total" % name).value
+            for name in ("steps", "steps_greedy")}
+    before = read()
+    sch = _scheduler(gpt, spec=spec, spec_k=3)
+    try:
+        sampled = dict(temperature=0.9, top_k=5)
+        futs = [sch.submit([3, 1, 4, 3, 1, 4, 3, 1], n, seed=i,
+                           **(sampled if traffic == "mixed" and i == 1
+                              else {}))
+                for i, n in enumerate((13, 6, 9))]
+        for f in futs:
+            f.result(240)
+        _settle(sch)
+        got = {k: v - before[k] for k, v in read().items()}
+        stats = sch.stats
+        assert got["steps"] == stats.steps_launched == len(carried) > 0
+        assert got["steps_greedy"] == stats.steps_greedy \
+            == carried.count(False)
+        if traffic == "greedy":
+            assert got["steps_greedy"] == got["steps"]
+        else:
+            assert 0 < carried.count(True) <= 6
+        assert sch.metrics()["greedy_steps_share"] == round(
+            stats.steps_greedy / stats.steps_launched, 4)
+    finally:
+        sch.close()
+    assert "veles_serving_steps_greedy_total" in \
+        metrics.render_prometheus()
 
 
 @pytest.mark.parametrize("weights", ["committed", "uncommitted"])

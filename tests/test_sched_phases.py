@@ -524,6 +524,23 @@ def test_the_benchmarks_parser_reads_a_part_or_dry_metric(
         assert value > 0
 
 
+def test_the_benchmarks_parser_reads_the_greedy_step_share(serve_record):
+    """The benchmark's serve traffic is greedy: every launch's sampler
+    took the argmax alone (PR 38); a program without the counter, as
+    the parent of PR 38 is, gives the reader nothing to read."""
+    from benchmark.readers import ratio
+    entry, value = read_ratio("sampler_greedy_steps_pct", serve_record)
+    assert entry["source"] == "program_counter"
+    assert entry["better"] == "higher" and len(entry["workloads"]) == 4
+    assert value == 100
+    parent = dict(serve_record, counters={
+        k: v for k, v in serve_record["counters"].items()
+        if k != "veles_serving_steps_greedy_total"})
+    with open(os.path.join(ROOT, "benchmark", "metrics",
+                           "sampler_greedy_steps_pct.json")) as f:
+        assert ratio.read(parent, json.load(f)["params"]) is None
+
+
 # -- device-side names --------------------------------------------------------
 
 def _closure(fn):

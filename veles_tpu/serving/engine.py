@@ -35,16 +35,32 @@ def sample_slots(logits, temps, topks, keys):
     """Per-slot next-token sampler: rows with ``temps[n] == 0`` take
     the greedy argmax; sampling rows draw categorical(logits / temp)
     restricted to each row's top-k (0 = full vocab; ties with the
-    k-th value stay in, matching ``generate``'s masking)."""
+    k-th value stay in, matching ``generate``'s masking).
+
+    Only the work the rows ask for runs: the divide and the draw sit
+    behind a ``lax.cond`` on "any row samples", the vocabulary sort
+    and the k-th-value mask behind a second on "any sampling row has
+    a top-k", so an all-greedy batch (padding rows carry temperature
+    0) runs the argmax alone.  One program either way, and the
+    tokens of every row mix are those of the unconditional form."""
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    v = logits.shape[-1]
-    z = logits / jnp.maximum(temps, 1e-6)[:, None]
-    zs = jnp.sort(z, axis=-1)
-    kth = jnp.take_along_axis(
-        zs, jnp.clip(v - topks, 0, v - 1)[:, None], axis=-1)
-    z = jnp.where((topks[:, None] > 0) & (z < kth), -jnp.inf, z)
-    drawn = jax.vmap(jax.random.categorical)(keys, z)
-    return jnp.where(temps > 0, drawn.astype(jnp.int32), greedy)
+    sampled = temps > 0
+
+    def top_k(z):
+        v = z.shape[-1]
+        zs = jnp.sort(z, axis=-1)
+        kth = jnp.take_along_axis(
+            zs, jnp.clip(v - topks, 0, v - 1)[:, None], axis=-1)
+        return jnp.where((topks[:, None] > 0) & (z < kth), -jnp.inf, z)
+
+    def draw():
+        z = logits / jnp.maximum(temps, 1e-6)[:, None]
+        z = jax.lax.cond(jnp.any(sampled & (topks > 0)), top_k,
+                         lambda z: z, z)
+        drawn = jax.vmap(jax.random.categorical)(keys, z)
+        return jnp.where(sampled, drawn.astype(jnp.int32), greedy)
+
+    return jax.lax.cond(jnp.any(sampled), draw, lambda: greedy)
 
 
 def _fold_keys(seeds, counts):

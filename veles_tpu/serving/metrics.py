@@ -284,6 +284,10 @@ def _loop_series():
             "decode steps launched from the device-resident tokens "
             "of the step before them, before the host had read "
             "those (scheduler launch-ahead; 0 under speculation)"),
+        "steps_greedy": metrics.counter(
+            "veles_serving_steps_greedy_total",
+            "decode/verify steps launched whose every packed row has "
+            "temperature 0: their sampler took the argmax alone"),
         "rows_discarded": metrics.counter(
             "veles_serving_rows_discarded_total",
             "rows of a landed decode step whose request had left its "
@@ -1006,11 +1010,12 @@ class ServingMetrics:
         self._global = _registry_series()
         self._loop = _loop_series()
         self._pool_copies_seen = 0
-        #: this scheduler's own share of three of those counters (the
+        #: this scheduler's own share of four of those counters (the
         #: registry's are the process's): decode/verify launches, the
-        #: launches that ran ahead, the rows a landing discarded
+        #: launches that ran ahead, those whose rows were all greedy,
+        #: the rows a landing discarded
         self.steps_launched = self.steps_ahead = 0
-        self.rows_discarded = 0
+        self.steps_greedy = self.rows_discarded = 0
         #: ... and of its loop's seconds (all phases but parked), and
         #: those of them in which the device had run dry
         self.loop_seconds = self.dry_seconds = 0.0
@@ -1345,8 +1350,9 @@ class ServingMetrics:
 
     def record_loop_pass(self, seconds, steps, steps_after_prefill,
                          step_after_prefill_seconds, steps_ahead=0,
-                         rows_discarded=0, parts=None, dry=None,
-                         admissions=0, passes=1, pool_copies=None):
+                         steps_greedy=0, rows_discarded=0, parts=None,
+                         dry=None, admissions=0, passes=1,
+                         pool_copies=None):
         """The scheduler loop's phase account since its last flush
         (``scheduler._LoopPhases.drain()``): ``seconds`` by phase,
         ``parts`` by part (each inside its phase's seconds), ``dry``
@@ -1357,6 +1363,7 @@ class ServingMetrics:
         waited adds seconds and none); ``steps_ahead`` /
         ``rows_discarded``: the launch-ahead's two counts
         (``scheduler._step_paged``, ``_land_flight``);
+        ``steps_greedy``: launches whose rows were all greedy;
         ``admissions``: requests that entered ``_begin_admit``.
         ``pool_copies``: the cache's running count (the warm-up's
         included); what it grew by since the last pass is counted."""
@@ -1394,6 +1401,9 @@ class ServingMetrics:
         if steps_ahead:
             self._loop["steps_ahead"].inc(steps_ahead)
             self.steps_ahead += steps_ahead
+        if steps_greedy:
+            self._loop["steps_greedy"].inc(steps_greedy)
+            self.steps_greedy += steps_greedy
         if rows_discarded:
             self._loop["rows_discarded"].inc(rows_discarded)
             self.rows_discarded += rows_discarded
@@ -1531,6 +1541,9 @@ class ServingMetrics:
                 "slot_busy_steps": self.slot_busy_steps,
                 "steps_ahead_share": round(
                     self.steps_ahead / self.steps_launched, 4)
+                if self.steps_launched else None,
+                "greedy_steps_share": round(
+                    self.steps_greedy / self.steps_launched, 4)
                 if self.steps_launched else None,
                 "rows_discarded": self.rows_discarded,
                 "dry_share": round(

@@ -503,6 +503,9 @@ class _LoopPhases(object):
         # the pass: steps launched from the tokens of a step nobody
         # had read yet, and rows of a landed step that were discarded
         self.steps_ahead = self.rows_discarded = 0
+        # steps launched with every packed row at temperature 0: their
+        # sampler took the argmax alone (engine.sample_slots)
+        self.steps_greedy = 0
 
     def __call__(self, name):
         return _Phase(self, name)
@@ -584,8 +587,8 @@ class _LoopPhases(object):
         ``ServingMetrics.record_loop_pass``; the pass ends here."""
         out = (self.seconds, self.steps, self.steps_after_prefill,
                self.step_after_prefill_seconds, self.steps_ahead,
-               self.rows_discarded, self.parts, self.dry,
-               self.admissions)
+               self.steps_greedy, self.rows_discarded, self.parts,
+               self.dry, self.admissions)
         self._reset()
         return out
 
@@ -3095,6 +3098,7 @@ class InferenceScheduler(Logger):
             tables[:n] = cache.table_rows(slots, t)
             want_h = self._draft_head is not None
         phases = self._phases
+        phases.steps_greedy += not temps.any()
         with phases("step.resolve"):
             got = paged_decode_step(
                 self.forwards, cache, toks, pos, tables, temps, topks,
@@ -3228,6 +3232,7 @@ class InferenceScheduler(Logger):
             tables[:n] = cache.table_rows(slots, t)
             want_h = self._draft_head is not None
         phases, t0 = self._phases, time.perf_counter()
+        phases.steps_greedy += not temps.any()
         with phases("step.resolve"):
             got = verify_step_paged(
                 self.forwards, cache, toks, pos, lens, tables, temps,
