@@ -546,6 +546,11 @@ def test_served_streams_are_the_references_and_the_cache_says_so(
     rows = _counter(moved, "veles_serving_slot_busy_steps_total")
     assert steps_n > 0
     assert _counter(moved, "veles_serving_state_rows_total") == 2 * rows
+    # both GDN layers' states advanced by the delta rule, none of them
+    # through the Pallas call off the chip
+    assert _counter(moved, "veles_serving_delta_state_rows_total") \
+        == 2 * rows
+    assert _counter(moved, "veles_serving_delta_state_fused_total") == 0
     # every decode step a live row reads its whole length in each of
     # the two latent layers: prompt .. prompt + n - 1 rows, + 1 each
     want = sum(len(p) + j for p, n in zip(prompts, steps)

@@ -384,6 +384,11 @@ def test_counters_of_the_state_and_of_the_held_experts(served):
     # counted on the host: live rows x the layers that keep a state
     assert _counter(moved, "veles_serving_state_rows_total") \
         == kda * rows
+    # each KDA layer's matrix advanced by the delta rule, none of them
+    # through the Pallas call off the chip (ops/pallas_delta.py)
+    assert _counter(moved, "veles_serving_delta_state_rows_total") \
+        == kda * rows
+    assert _counter(moved, "veles_serving_delta_state_fused_total") == 0
     assert _counter(moved, "veles_serving_moe_layer_steps_total") \
         == layers * steps
     # all the live rows' choices, and those of them on experts 4-9

@@ -435,15 +435,47 @@ def _solar_programs(one_chip):
             pools[2], staged, arr(jnp.int32)), 2)}
 
 
+def _on_the_chip(monkeypatch):
+    """Trace the serving step as the chip runs it: its delta-rule
+    layers advance their state through the Pallas call."""
+    from veles_tpu.ops import pallas_delta
+    monkeypatch.setattr(pallas_delta, "_use_interpret",
+                        lambda backend=None: False)
+
+
+def _delta_state_calls(text, pool, gathered):
+    """Assert that no fusion, gather, scatter, copy or loop of a compiled
+    module reads a ``pool``-shaped parameter (a float32 state pool) and
+    that no ``gathered``-shaped array of the live rows' states is made;
+    -> how many ``veles_delta_state`` calls the module holds."""
+    import re
+    params = re.findall(r"%%(\S+) = %s\S* parameter\(" % pool, text)
+    assert params
+    for name in params:
+        readers = set(re.findall(r" ([a-z][a-z-]*)\([^()]*%%%s[,)]"
+                                 % re.escape(name), text))
+        assert not readers & {"fusion", "gather", "scatter", "copy",
+                              "copy-start", "dynamic-slice",
+                              "dynamic-update-slice", "while"}, readers
+    assert not re.search(r"\b%s" % gathered, text)
+    return len(re.findall(r"%veles_delta_state\S* = .*custom-call\(",
+                          text))
+
+
 @pytest.mark.parametrize("name", ["kv_insert_state", "paged_step"])
 def test_matrix_state_is_written_in_place_on_v5e(name, one_chip,
-                                                 no_compile_cache):
+                                                 no_compile_cache,
+                                                 monkeypatch):
     """Compiled for the described chip at the cell's widths, the decode
     step of a chain that keeps a float32 matrix state a slot beside its
     paged rows, and the insert of a prefilled state, alias every donated
     leaf to its output and copy no state pool whole (74 MB a layer).
-    The grouped products are the chip's own calls."""
+    The grouped products are the chip's own calls.  The step's state
+    layer reads and writes its pool through ONE Pallas call: no gather
+    of the live rows' states, no scatter back (the jnp step moved each
+    state six times, ``PERF.md`` §5)."""
     import re
+    _on_the_chip(monkeypatch)
     fn, args, donated = _solar_programs(one_chip)[name]
     text = fn.lower(*args).compile().as_text()
     head = text.split("\n", 1)[0]
@@ -452,6 +484,8 @@ def test_matrix_state_is_written_in_place_on_v5e(name, one_chip,
         assert re.findall(r"= %s\S* copy\(" % pool, text) == []
     if name == "paged_step":
         assert "custom-call" in text
+        assert _delta_state_calls(text, r"f32\[17,64,128,128\]",
+                                  r"f32\[16,64,128,128\]") == 1
         # the program's named scopes ride every op's metadata: what a
         # reader of the device trace can tell the parts of a layer by
         for scope in ("veles_solar_kda_conv", "veles_solar_kda_state",
@@ -460,13 +494,57 @@ def test_matrix_state_is_written_in_place_on_v5e(name, one_chip,
             assert scope in text, scope
 
 
+#: lowers the Solar cell's decode step as the chip runs it, in a process
+#: of its own, and prints the digest of its lowered text
+_LOWER_SOLAR_STEP = """
+import hashlib, sys
+sys.path[:0] = [%r, %r]
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+from veles_tpu.ops import pallas_delta
+pallas_delta._use_interpret = lambda backend=None: False
+import test_tpu_compile
+topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+fn, args, _ = test_tpu_compile._solar_programs(
+    SingleDeviceSharding(topo.devices[0]))["paged_step"]
+text = fn.lower(*args).as_text()
+assert text.count("veles_delta_state") >= 1
+print(hashlib.sha256(text.encode()).hexdigest())
+"""
+
+
+def test_solar_step_lowers_to_the_same_text_in_two_processes(topo):
+    """The persistent compile cache keys a program on its lowered text,
+    and a Pallas call's text carries its whole Mosaic module, the
+    locations of the frames that traced it included: two processes
+    (with other hash seeds) lowering the Solar step from the same code
+    read the same text, Mosaic payload and all, or every run of the
+    cell would compile its step programs again."""
+    import os
+    import subprocess
+    import sys
+    here = os.path.dirname(os.path.abspath(__file__))
+    code = _LOWER_SOLAR_STEP % (here, os.path.dirname(here))
+    digests = []
+    for seed in ("1", "2"):
+        env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONHASHSEED=seed,
+                   ALLOW_MULTIPLE_LIBTPU_LOAD="1")
+        run = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=600)
+        assert run.returncode == 0, run.stderr[-2000:]
+        digests.append(run.stdout.split()[-1])
+    assert digests[0] == digests[1]
+
+
 def test_latent_and_gdn_step_compiles_on_v5e(one_chip, no_compile_cache,
                                              monkeypatch):
     """The GigaChat cell's decode step at its widths (5 layers, a 4-row
     bucket of 8 blocks), the latent attention through its Pallas call:
     it compiles for the described chip, aliases every donated pool
     leaf, copies no GDN state pool whole, holds ONE ``veles_mla_decode``
-    call and carries the five named scopes."""
+    call and ONE ``veles_delta_state`` call a GDN layer (each the only
+    reader and writer of its state pool) and carries the five named
+    scopes."""
     import json
     import os
     import re
@@ -482,6 +560,7 @@ def test_latent_and_gdn_step_compiles_on_v5e(one_chip, no_compile_cache,
     monkeypatch.setattr(common, "use_interpret", lambda backend=None: False)
     monkeypatch.setattr(pallas_latent, "_use_interpret",
                         lambda backend=None: False)
+    _on_the_chip(monkeypatch)
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(here, "benchmark", "configs",
                            "gigachat3.5-432b-ep16.json")) as f:
@@ -515,6 +594,9 @@ def test_latent_and_gdn_step_compiles_on_v5e(one_chip, no_compile_cache,
         assert re.findall(r"= %s\S* copy\(" % pool, text) == []
     assert len(re.findall(r"%veles_mla_decode\S* = .*custom-call\(",
                           text)) == 1
+    # each of the four GDN layers advances its pool through its own call
+    assert _delta_state_calls(text, r"f32\[5,64,128,128\]",
+                              r"f32\[4,64,128,128\]") == 4
     for scope in ("veles_gigachat_mla", "veles_gigachat_gdn_conv",
                   "veles_gigachat_gdn_state", "veles_gigachat_held_experts",
                   "veles_gigachat_shared_expert"):

@@ -44,7 +44,9 @@ latent space and then multiplied by ``W_uv``
 
 Its cache is per-SLOT state (``cache_kind == "slot"``): ``conv``, the
 last ``conv_kernel - 1`` rows of [q~ | k~ | v~] in the compute dtype,
-and ``S`` [Hv, K, K], float32 whatever the compute dtype.
+and ``S`` [Hv, K, K], float32 whatever the compute dtype, which a decode
+step on the chip advances in place through the Pallas call
+``veles_delta_state`` (``ops/pallas_delta.py``).
 
 FFN: every SwiGLU is clamped, ``E(u) = (silu(min(u w1, L)) *
 clip(u w3, -L, L)) w2`` (L = ``swiglu_limit``).  ``ffn="dense"``: one
@@ -72,7 +74,7 @@ from veles_tpu.memory import Array
 from veles_tpu.models.lfm2 import (
     NormedTokenLogits, _dot, clamp_swiglu, rms_norm, routed_ffn)
 from veles_tpu.models.nn_units import ForwardBase
-from veles_tpu.models.solar import delta_step
+from veles_tpu.models.solar import delta_step, pool_step
 
 
 def rms0(x, weight, eps):
@@ -209,6 +211,11 @@ class GigaChatBlock(ForwardBase):
         #: per slot, or paged rows of ONE latent array a token
         #: (serving/kv_slots.PagedKVCache)
         self.cache_kind = "slot" if operator == "gdn" else "paged"
+        #: the slot state holds a matrix the delta rule advances, and
+        #: whether the decode step this layer traced last advanced it
+        #: through the Pallas call (``SolarBlock.delta_state``)
+        self.delta_state = operator == "gdn"
+        self.state_in_place = False
         if operator == "mla":
             #: the names of a paged layer's row arrays: not a K and V
             #: pair (serving/kv_slots.latent_units)
@@ -316,11 +323,12 @@ class GigaChatBlock(ForwardBase):
         gate = 2.0 * jax.nn.sigmoid(_dot(u, params["wg"]))
         return _dot(ctx.reshape(b, c, -1) * gate, params["wo"])
 
-    def _gdn(self, params, u, conv, S, lens=None):
+    def _gdn(self, params, u, conv, S, lens=None, step=delta_step):
         """u [b, c, d] continuing ``conv`` [b, K - 1, (2 Hk + Hv) K],
         the last rows of [q~ | k~ | v~] before the run, and ``S``
         [b, Hv, K, K] -> (out [b, c, d], both states at each row's
-        ``lens`` [b], default c)."""
+        ``lens`` [b], default c).  A run of one position takes its state
+        through ``step`` (``SolarBlock._kda``'s convention)."""
         from veles_tpu import dtypes
         b, c, _ = u.shape
         hk, hv, hd = (self.linear_key_heads, self.linear_value_heads,
@@ -356,8 +364,8 @@ class GigaChatBlock(ForwardBase):
         beta = jax.nn.sigmoid(_dot(u, params["wb"]))
         with jax.named_scope("veles_gigachat_gdn_state"):
             if c == 1 and lens is None:
-                S, o = delta_step(S, q[:, 0], k[:, 0], v[:, 0],
-                                  alpha[:, 0], beta[:, 0])
+                S, o = step(S, q[:, 0], k[:, 0], v[:, 0], alpha[:, 0],
+                            beta[:, 0])
                 o = o[:, None]
             else:
                 def position(S, at):
@@ -466,10 +474,11 @@ class GigaChatBlock(ForwardBase):
         if self.operator == "gdn":
             row = jnp.arange(b) if slots is None else jnp.where(
                 live, slots, pool["S"].shape[0] - 1)
+            step, in_place = pool_step(row, slots)
             op, conv, S = self._gdn(params, u, pool["conv"][row],
-                                    pool["S"][row])
-            out = {"conv": pool["conv"].at[row].set(conv),
-                   "S": pool["S"].at[row].set(S)}
+                                    pool["S"], step=step)
+            self.state_in_place = in_place
+            out = {"conv": pool["conv"].at[row].set(conv), "S": S}
         else:
             with jax.named_scope("veles_gigachat_mla"):
                 q_nope, q_pe, latent = self._mla_project(

@@ -29,7 +29,11 @@ the normed input):
 
 Its cache is per-SLOT state of two arrays (``cache_kind == "slot"``):
 ``conv``, the last 3 rows of [q~ | k~ | v~] in the compute dtype, and
-``S``, float32 whatever the compute dtype.
+``S``, float32 whatever the compute dtype.  On the chip a decode step
+advances ``S`` in its pool in place, through the Pallas call
+``veles_delta_state`` (``ops/pallas_delta.py``); elsewhere it gathers
+the rows' states, runs :func:`delta_step` and scatters them back
+(:func:`pool_step`).
 
 ``operator="gqa"``: ``heads`` query heads over ``kv_heads`` K/V heads of
 ``head_dim``, NO rotary and no positions, causal softmax at scale
@@ -55,6 +59,8 @@ accumulation; the recurrence is float32 on the vector units (no matrix
 unit's rounding).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy
@@ -62,6 +68,7 @@ import numpy
 from veles_tpu.memory import Array
 from veles_tpu.models.lfm2 import _dot, rms_norm, routed_ffn
 from veles_tpu.models.nn_units import ForwardBase
+from veles_tpu.ops import pallas_delta
 
 
 def delta_step(S, q, k, v, alpha, beta):
@@ -73,6 +80,25 @@ def delta_step(S, q, k, v, alpha, beta):
     S = S + (beta[..., None] * k)[..., None] \
         * (v - pred)[..., None, :]
     return S, (S * q[..., None]).sum(axis=-2)                # S^T q
+
+
+def pool_step(row, slots=None):
+    """``delta_step`` as a decode step takes it over a slot pool: ->
+    ``(step, in_place)``, ``step`` being ``(pool, q, k, v, alpha, beta)
+    -> (pool', o)`` for the rows whose states lie in ``row`` [b] of the
+    pool (``slots``: the same with -1 for a padding row, whose ``row`` is
+    the trash).  On the chip ``step`` is the Pallas call, which reads and
+    writes each live state once, in place (``ops/pallas_delta.py``), and
+    ``in_place`` is True; elsewhere the rows' states are gathered,
+    advanced and scattered back."""
+    if pallas_delta.fused():
+        return functools.partial(pallas_delta.step_call(),
+                                 row if slots is None else slots), True
+
+    def step(pool, *args):
+        S, o = delta_step(pool[row], *args)
+        return pool.at[row].set(S), o
+    return step, False
 
 
 class SolarBlock(ForwardBase):
@@ -129,6 +155,13 @@ class SolarBlock(ForwardBase):
         #: what the serving cache keeps for this layer: one fixed state
         #: per slot or paged K/V rows (serving/kv_slots.PagedKVCache)
         self.cache_kind = "slot" if operator == "kda" else "paged"
+        #: the slot state holds a matrix the delta rule advances: a
+        #: decode step on the chip advances it in place through the
+        #: Pallas call (ops/pallas_delta.py)
+        self.delta_state = operator == "kda"
+        #: whether the decode step this layer traced last advanced its
+        #: state through that call (the serving cache counts it)
+        self.state_in_place = False
         #: a chunk of the delta rule is a scan over its positions
         #: (``_kda``), so its time grows with the chunk's width: the
         #: scheduler keeps such a chain's chunks at their narrowest
@@ -192,11 +225,13 @@ class SolarBlock(ForwardBase):
 
     # -- the operators over a run of positions ---------------------------
 
-    def _kda(self, params, u, conv, S, lens=None):
+    def _kda(self, params, u, conv, S, lens=None, step=delta_step):
         """u [b, c, d] continuing ``conv`` [b, K - 1, 3 H K], the last
         rows of [q~ | k~ | v~] before the run, and ``S`` [b, H, K, K]
         -> (out [b, c, d], both states at each row's ``lens`` [b],
-        default c)."""
+        default c).  A run of one position takes its state through
+        ``step`` (``delta_step``'s arguments and results: a decode step
+        hands the whole pool and :func:`pool_step`)."""
         from veles_tpu import dtypes
         b, c, _ = u.shape
         heads, hd, taps_n = self.heads, self.head_dim, self.conv_kernel
@@ -229,8 +264,8 @@ class SolarBlock(ForwardBase):
         beta = 2.0 * jax.nn.sigmoid(_dot(u, params["wb"]))
         with jax.named_scope("veles_solar_kda_state"):
             if c == 1 and lens is None:
-                S, o = delta_step(S, q[:, 0], k[:, 0], v[:, 0],
-                                  alpha[:, 0], beta[:, 0])
+                S, o = step(S, q[:, 0], k[:, 0], v[:, 0], alpha[:, 0],
+                            beta[:, 0])
                 o = o[:, None]
             else:
                 def position(S, at):
@@ -343,10 +378,11 @@ class SolarBlock(ForwardBase):
         if self.operator == "kda":
             row = jnp.arange(b) if slots is None else jnp.where(
                 live, slots, pool["S"].shape[0] - 1)
+            step, in_place = pool_step(row, slots)
             op, conv, S = self._kda(params, u, pool["conv"][row],
-                                    pool["S"][row])
-            out = {"conv": pool["conv"].at[row].set(conv),
-                   "S": pool["S"].at[row].set(S)}
+                                    pool["S"], step=step)
+            self.state_in_place = in_place
+            out = {"conv": pool["conv"].at[row].set(conv), "S": S}
         else:
             q, k_new, v_new = self._qkv(params, u)
             pk, pv, ctx = paged_decode_attention(

@@ -385,6 +385,12 @@ class PagedKVCache:
         self.state_units = slot_state_units(forwards)
         self.stack_units = stacked_units(forwards)
         self.latent_units = latent_units(forwards)
+        #: the slot layers whose state is a matrix the delta rule
+        #: advances (``delta_state``); the scheduler counts their states
+        #: at each landed step, and :attr:`delta_fused` of them
+        self._delta_units = [forwards[i] for i in self.state_units
+                             if getattr(forwards[i], "delta_state", False)]
+        self.delta_layers = len(self._delta_units)
         if tp is not None:
             self._blocks_only("tp")
         if kv_dtype == "int8":
@@ -462,6 +468,14 @@ class PagedKVCache:
     @property
     def used_blocks(self):
         return self.capacity_blocks - len(self._free_blocks)
+
+    @property
+    def delta_fused(self):
+        """How many of the delta-rule layers advanced their states in
+        place through the Pallas call ``veles_delta_state``
+        (ops/pallas_delta.py), as each layer recorded when it traced its
+        decode step (``state_in_place``): all on the chip, none off it."""
+        return sum(1 for u in self._delta_units if u.state_in_place)
 
     def bytes_per_token(self):
         """PER-CHIP HBM bytes ONE cached token costs across every

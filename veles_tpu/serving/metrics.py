@@ -409,6 +409,17 @@ def _registry_series():
             "veles_serving_state_rows_total",
             "per-slot states read and written by decode steps: live "
             "rows x the chain's layers that keep a state a slot"),
+        "delta_state_rows": metrics.counter(
+            "veles_serving_delta_state_rows_total",
+            "matrix states that decode steps advanced by the delta "
+            "rule: live rows x the chain's delta-rule layers (a part "
+            "of state_rows)"),
+        "delta_state_fused": metrics.counter(
+            "veles_serving_delta_state_fused_total",
+            "of those, the states the Pallas call veles_delta_state "
+            "read and wrote in place, once each way "
+            "(ops/pallas_delta.py): all of them on the chip, none off "
+            "it"),
         "latent_rows": metrics.counter(
             "veles_serving_latent_rows_total",
             "latent rows that decode steps read: the live rows' "
@@ -1418,7 +1429,8 @@ class ServingMetrics:
                 step_after_prefill_seconds)
 
     def record_step(self, active, slots, tokens=None, moe=None,
-                    stack=None, state_units=0, latent_rows=0):
+                    stack=None, state_units=0, latent_rows=0,
+                    delta_rows=0, delta_fused=0):
         """One batched decode/verify boundary: ``active`` real rows
         rode a padded ``slots``-row bucket; ``tokens`` is what the
         step actually emitted (spec verify can emit up to k+1 per
@@ -1434,6 +1446,9 @@ class ServingMetrics:
         slot, each of which read and wrote ``active`` of them.
         ``latent_rows``: the latent rows the step's latent-attention
         layers read (the live rows' lengths x those layers).
+        ``delta_rows``: the matrix states its delta-rule layers advanced
+        (live rows x those layers); ``delta_fused``: how many of them
+        the Pallas call advanced.
         ``stack``: a looped stack's counts of a decode step, float
         [stacks, 2 + passes] = (passes run, live rows, the live rows'
         exit mass of each pass)."""
@@ -1455,6 +1470,9 @@ class ServingMetrics:
                 self._global[name].inc(total)
         if state_units:
             self._global["state_rows"].inc(int(active) * state_units)
+        if delta_rows:
+            self._global["delta_state_rows"].inc(int(delta_rows))
+            self._global["delta_state_fused"].inc(int(delta_fused))
         if latent_rows:
             self._global["latent_rows"].inc(int(latent_rows))
         now = time.monotonic()

@@ -3169,7 +3169,9 @@ class InferenceScheduler(Logger):
             self.stats.record_step(
                 n, b, tokens=n, state_units=len(self._state_units),
                 latent_rows=len(self._latent_units) * int(sum(
-                    flight.lens[j] for j, _, _ in rows)), **counts)
+                    flight.lens[j] for j, _, _ in rows)),
+                delta_rows=n * cache.delta_layers,
+                delta_fused=n * cache.delta_fused, **counts)
             self._meter_step({slot: req for _, slot, req in rows},
                              cache, dt)
         with self._phases("emit"):
