@@ -8,3 +8,4 @@ from benchmark.tests.test_lfm2 import *  # noqa: F401,F403
 from benchmark.tests.test_ouro import *  # noqa: F401,F403
 from benchmark.tests.test_solar import *  # noqa: F401,F403
 from benchmark.tests.test_gigachat import *  # noqa: F401,F403
+from benchmark.tests.test_cmdaplus import *  # noqa: F401,F403

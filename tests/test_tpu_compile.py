@@ -603,6 +603,62 @@ def test_latent_and_gdn_step_compiles_on_v5e(one_chip, no_compile_cache,
         assert scope in text, scope
 
 
+def test_ring_and_full_layer_step_compiles_on_v5e(one_chip,
+                                                   no_compile_cache):
+    """The Command A+ cell's decode step at its widths (4 layers: three
+    sliding-window layers on rings, one full layer on paged rows; a 4-row
+    bucket of 8 blocks): it compiles for the described chip, aliases
+    every donated pool leaf (a K and a V array a layer), copies no ring
+    pool whole, and carries the four named scopes."""
+    import json
+    import os
+    import re
+
+    import numpy
+
+    from benchmark import cmdaplus_glue, cmdaplus_weights
+    from veles_tpu.accelerated_units import AcceleratedWorkflow
+    from veles_tpu.memory import Array
+    from veles_tpu.models.standard import make_forwards
+    from veles_tpu.serving import engine
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs",
+                           "command-a-plus-ep8.json")) as f:
+        shapes = json.load(f)["shapes"]
+    fw = make_forwards(AcceleratedWorkflow(None, name="cmda-lowering"),
+                       Array(numpy.zeros((1, 128), numpy.int32)),
+                       cmdaplus_glue.layer_spec(shapes))
+
+    def arr(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    layout = cmdaplus_weights.chain_layout(shapes)
+    params = {i: {name: arr(jnp.float32 if name in cmdaplus_weights.FLOAT32
+                            else jnp.bfloat16, *shape)
+                  for name, shape in leaves.items()}
+              for i, leaves in enumerate(layout[:-1])}
+    params[len(fw) - 1] = {"final_norm": arr(jnp.float32, shapes["dim"]),
+                           "table": params[0]["weights"]}
+    pools = {}
+    for i, u in enumerate(fw):
+        if hasattr(u, "init_cache"):
+            got = jax.eval_shape(lambda u=u: u.init_cache(
+                5 if u.cache_kind == "window" else 33, 16, jnp.bfloat16))
+            pools[i] = {k: arr(v.dtype, *v.shape) for k, v in got.items()}
+    b, t = 4, 8
+    step = jax.jit(engine._make_paged_step(fw, block_size=16),
+                   donate_argnums=(9,))
+    text = step.lower(
+        params, arr(jnp.int32, b), arr(jnp.int32, b), arr(jnp.int32, b, t),
+        arr(jnp.float32, b), arr(jnp.int32, b), arr(jnp.uint32, b),
+        arr(jnp.int32, b), arr(jnp.int32, b), pools).compile().as_text()
+    head = text.split("\n", 1)[0]
+    assert head.count("-alias)") == 2 * 4, head[:400]
+    assert re.findall(r"= bf16\[5,4096,1024\]\S* copy\(", text) == []
+    for scope in ("veles_cmda_window_attn", "veles_cmda_full_attn",
+                  "veles_cmda_held_experts", "veles_cmda_shared_experts"):
+        assert scope in text, scope
+
+
 # -- compiled: the decode steps relay no gathered rows and copy no weight ------
 
 def _looped_program(one_chip):

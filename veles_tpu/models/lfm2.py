@@ -88,7 +88,8 @@ def routed_ffn(params, u, top_k, norm_topk, scaling, live=None,
     counts.
 
     ``swiglu_limit`` (None: no clamp) clamps each expert's two
-    products before they meet (:func:`clamp_swiglu`)."""
+    products before they meet (:func:`clamp_swiglu`).  ``params``
+    without ``expert_bias``: the choice is of ``s`` alone."""
     from veles_tpu import dtypes
     cd = dtypes.compute_dtype()
     n, d = u.shape
@@ -96,8 +97,10 @@ def routed_ffn(params, u, top_k, norm_topk, scaling, live=None,
     s = jax.nn.sigmoid(jnp.matmul(
         u, params["router"].astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
+    # a router with no selection bias (Command A+) holds no leaf for it
+    bias = params.get("expert_bias")
     _, chosen = jax.lax.top_k(
-        s + params["expert_bias"].astype(jnp.float32), top_k)
+        s if bias is None else s + bias.astype(jnp.float32), top_k)
     if live is None:
         live = jnp.ones((n,), bool)
     else:

@@ -25,6 +25,7 @@ from veles_tpu.models.ouro import OuroStack, PlainTokenLogits
 from veles_tpu.models.gigachat import GigaChatBlock, \
     ZeroCenteredTokenLogits
 from veles_tpu.models.solar import SolarBlock
+from veles_tpu.models.cohere_moe import CohereMoeBlock, CohereTiedLogits
 from veles_tpu.models.transformer import MeanPoolSeq, TransformerBlock, TokenProjection
 from veles_tpu.models.all2all import (
     All2All, All2AllRELU, All2AllSigmoid, All2AllSoftmax,
@@ -72,13 +73,16 @@ LAYER_TYPES = {
     "rms_token_logits": NormedTokenLogits,
     "ouro_stack": OuroStack,
     "plain_token_logits": PlainTokenLogits,
+    "cohere_moe_block": CohereMoeBlock,
+    "tied_token_logits": CohereTiedLogits,
 }
 
 
 def make_forwards(workflow, input_array, layers):
     """Instantiate the forward chain from a znicz-style ``layers`` spec;
     returns the unit list (uninitialized — the workflow's dependency-
-    ordered initialize fills parameters)."""
+    ordered initialize fills parameters).  A unit that reads another's
+    table (``tie``: a tied head) is tied to the chain's first unit."""
     units = []
     prev = input_array
     for i, spec in enumerate(dict(s) for s in layers):
@@ -90,6 +94,8 @@ def make_forwards(workflow, input_array, layers):
         u = cls(workflow, name="%s%d" % (ltype, i), **kwargs)
         u.input = prev
         prev = u.output
+        if hasattr(u, "tie"):
+            u.tie(units[0])
         units.append(u)
     return units
 

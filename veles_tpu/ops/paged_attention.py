@@ -165,7 +165,7 @@ def grouped_attend(q, keys, values, qpos, kv_heads):
 
 
 def staged_chunk_attend(q, k_new, v_new, cache, offset, chunk_lens,
-                        key_width, kv_heads):
+                        key_width, kv_heads, by_head=False):
     """A prefill chunk's grouped attention over its staging rows: ``q``
     [b, C, heads, hd] and the chunk's new ``k_new``/``v_new``
     [b, C, kv_heads * hd] at positions [offset, offset + C) continuing
@@ -173,6 +173,8 @@ def staged_chunk_attend(q, k_new, v_new, cache, offset, chunk_lens,
     written at ``offset`` (those at or past a row's ``chunk_lens``
     zeroed, as a one-shot prefill's ragged rows) and the queries attend
     causally over the first ``key_width`` (default W) rows.
+    ``by_head``: through :func:`positioned_attend` one K/V head at a
+    time (the same arithmetic, a head's float32 scores alive at once).
     -> (context [b, C, heads * hd] float32, {"k", "v"})."""
     b, c = q.shape[:2]
     positions = offset + jnp.arange(c)[None, :] \
@@ -187,6 +189,11 @@ def staged_chunk_attend(q, k_new, v_new, cache, offset, chunk_lens,
     cv = jax.lax.dynamic_update_slice(
         cache["v"], v_new.astype(cache["v"].dtype), at)
     kw = int(key_width or ck.shape[1])
+    if by_head:
+        return positioned_attend(
+            q, ck[:, :kw], cv[:, :kw], positions,
+            jnp.broadcast_to(jnp.arange(kw), (b, kw)), kv_heads,
+            by_head=True), {"k": ck, "v": cv}
     return grouped_attend(q, ck[:, :kw], cv[:, :kw], positions,
                           kv_heads), {"k": ck, "v": cv}
 
@@ -270,6 +277,134 @@ def paged_decode_attention(q, k_new, v_new, pool_k, pool_v, tables,
         return pk, pv, grouped_attend(
             q.reshape(b, 1, h, hd), pk[tables].reshape(b, rows, -1),
             pv[tables].reshape(b, rows, -1), pos[:, None], kv_heads)
+
+
+# -- sliding-window rows in a per-slot ring -----------------------------------
+#
+# A window layer's query at position p sees the keys at positions
+# (p - window, p].  Its cache is a RING a slot, ``[slots, window, d]``:
+# the key of position p lives at row p mod window, so a slot never needs
+# more rows, whatever the length of its request.  Which position a row
+# holds follows from the newest position written alone
+# (:func:`ring_positions`); a row that no position of the current
+# occupant reached yet reads as a negative position and is masked, so a
+# ring is never zeroed between occupants.
+
+#: the score of a key that is not visible: finite, so that a padding
+#: query that sees no key at all takes a finite mean instead of 0/0;
+#: beside any visible score its exponential is exactly 0
+_HIDDEN = -1e30
+
+
+def ring_positions(last, width, window):
+    """[b, width]: the position ring row r in [0, width) holds when
+    ``last`` [b] is the newest position written (-1: none yet):
+    ``last - ((last - r) mod window)``, negative for a row not written."""
+    r = jnp.arange(width)
+    return last[:, None] - jnp.mod(last[:, None] - r[None, :], window)
+
+
+def positioned_attend(q, keys, values, qpos, kpos, kv_heads, window=None,
+                      by_head=False):
+    """:func:`grouped_attend`'s arithmetic with every key's position
+    given: ``q`` [b, s, heads, hd] at ``qpos`` [b, s] over ``keys`` /
+    ``values`` [b, L, kv_heads * hd] at ``kpos`` [b, L] (negative: no
+    key there).  A key is visible when ``0 <= kpos <= qpos`` and, with
+    ``window``, ``qpos - kpos < window``.  ``by_head``: one K/V head at a
+    time (``lax.map``), so a wide chunk's float32 scores are
+    [b, g, s, L] at a time and not ``kv_heads`` times that.
+    -> [b, s, heads * hd] float32."""
+    from veles_tpu import dtypes
+    cd = dtypes.compute_dtype()
+    b, s, heads, hd = q.shape
+    length = keys.shape[1]
+    g = heads // kv_heads
+    seen = (kpos[:, None, :] >= 0) & (kpos[:, None, :] <= qpos[..., None])
+    if window is not None:
+        seen = seen & (qpos[..., None] - kpos[:, None, :] < window)
+    qg = q.astype(cd).reshape(b, s, kv_heads, g, hd)
+    kh = keys.astype(cd).reshape(b, length, kv_heads, hd)
+    vh = values.astype(cd).reshape(b, length, kv_heads, hd)
+    scale = 1.0 / jnp.sqrt(jnp.float32(hd))
+
+    def head(qj, kj, vj):
+        # qj [b, s, g, hd], kj/vj [b, L, hd] -> [b, s, g, hd]
+        scores = jnp.einsum("bqgd,bkd->bgqk", qj, kj,
+                            preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(seen[:, None], scores, _HIDDEN)
+        probs = jax.nn.softmax(scores, axis=-1).astype(cd)
+        return jnp.einsum("bgqk,bkd->bqgd", probs, vj,
+                          preferred_element_type=jnp.float32)
+    if by_head:
+        out = jax.lax.map(lambda a: head(*a), (
+            jnp.moveaxis(qg, 2, 0), jnp.moveaxis(kh, 2, 0),
+            jnp.moveaxis(vh, 2, 0)))
+        return jnp.moveaxis(out, 0, 2).reshape(b, s, heads * hd)
+    scores = jnp.einsum("bqjgd,bkjd->bjgqk", qg, kh,
+                        preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(seen[:, None, None], scores, _HIDDEN)
+    probs = jax.nn.softmax(scores, axis=-1).astype(cd)
+    return jnp.einsum("bjgqk,bkjd->bqjgd", probs, vh,
+                      preferred_element_type=jnp.float32).reshape(
+                          b, s, heads * hd)
+
+
+def ring_chunk_attend(q, k_new, v_new, ring, offset, chunk_lens,
+                      kv_heads):
+    """A prefill chunk's sliding-window attention over a ring: ``q``
+    [b, C, heads, hd] and the chunk's ``k_new``/``v_new``
+    [b, C, kv_heads * hd] at positions [offset, offset + C) continuing
+    ``ring`` = {"k", "v"} [b, window, kv_heads * hd], which holds the
+    positions before ``offset``.  The queries attend over the ring's
+    rows and the chunk's own keys (those at or past a row's
+    ``chunk_lens`` left out), each over its last ``window`` positions
+    alone; then the chunk's keys up to ``chunk_lens`` take their rows.
+    -> (context [b, C, heads * hd] float32, {"k", "v"})."""
+    from veles_tpu import dtypes
+    b, c = q.shape[:2]
+    window = ring["k"].shape[1]
+    lens = jnp.full((b,), c, jnp.int32) if chunk_lens is None \
+        else chunk_lens
+    qpos = offset + jnp.arange(c)[None, :] + jnp.zeros((b, 1), jnp.int32)
+    own = jnp.where(jnp.arange(c)[None, :] < lens[:, None], qpos, -1)
+    held = ring_positions(jnp.full((b,), offset - 1), window, window)
+    cd = dtypes.compute_dtype()
+    keys = jnp.concatenate([ring["k"].astype(cd), k_new.astype(cd)], 1)
+    values = jnp.concatenate([ring["v"].astype(cd), v_new.astype(cd)], 1)
+    ctx = positioned_attend(q, keys, values, qpos,
+                            jnp.concatenate([held, own], axis=1),
+                            kv_heads, window=window, by_head=True)
+    # each ring row takes the newest of the chunk's kept positions that
+    # falls on it, or keeps what it holds
+    src = ring_positions(offset + lens - 1, window, window) - offset
+    fresh = (src >= 0)[..., None]
+    idx = jnp.clip(src, 0, c - 1)[..., None]
+
+    def put(old, new):
+        return jnp.where(fresh, jnp.take_along_axis(
+            new.astype(old.dtype), idx, axis=1), old)
+    return ctx, {"k": put(ring["k"], k_new), "v": put(ring["v"], v_new)}
+
+
+def ring_decode_attention(q, k_new, v_new, ring_k, ring_v, rows, pos,
+                          kv_heads, width):
+    """One decode position a row over a window layer's rings: ``q``
+    [B, heads, hd], ``k_new``/``v_new`` [B, kv_heads * hd] of row n at
+    ``pos[n]``, its ring ``rows[n]`` of ``ring_k``/``ring_v``
+    [slots, window, kv_heads * hd] (a padding row's: the trash ring).
+    The new key takes row ``pos mod window``; then the first ``width``
+    rows (static: the step's depth bucket in positions, at most
+    ``window``; a position the bucket covers lies in them) are read,
+    each at the position it holds.
+    -> (ring_k', ring_v', context [B, 1, heads * hd] float32)."""
+    b, heads, hd = q.shape
+    at = pos % ring_k.shape[1]
+    rk = ring_k.at[rows, at].set(k_new.astype(ring_k.dtype))
+    rv = ring_v.at[rows, at].set(v_new.astype(ring_v.dtype))
+    held = ring_positions(pos, width, ring_k.shape[1])
+    return rk, rv, positioned_attend(
+        q[:, None], rk[rows, :width], rv[rows, :width], pos[:, None],
+        held, kv_heads)
 
 
 # -- latent (MLA) rows -------------------------------------------------------

@@ -101,8 +101,6 @@ class ServeWorkflow(AcceleratedWorkflow):
         for a, b in zip(self.forwards, self.forwards[1:]):
             b.link_from(a)
 
-        from veles_tpu.models.lfm2 import NormedTokenLogits
-        from veles_tpu.models.transformer import TokenProjection
         self.api = RESTfulAPI(
             self, loader=self.loader,
             port=int(cfg.get("port", 0)),
@@ -113,11 +111,10 @@ class ServeWorkflow(AcceleratedWorkflow):
             max_slots=int(cfg.get("max_slots", 4)),
             max_queue=int(cfg.get("max_queue", 32)),
             serving_window=int(window) if window else None,
-            # an LM chain (per-token logits head) also serves
+            # an LM chain (a per-token logits head) also serves
             # POST /generate — autoregressive decode off the same chain
             forwards=self.forwards
-            if isinstance(self.forwards[-1],
-                          (TokenProjection, NormedTokenLogits))
+            if getattr(self.forwards[-1], "DECODE_POINTWISE", False)
             else None)
         self.api.output = self.forwards[-1].output
         self.api.gate_skip = self.loader.idle

@@ -121,13 +121,18 @@ def hidden_supported(forwards):
 STEP_COUNTS = {"moe": -1, "stack": -2}
 
 
-def _make_paged_step(forwards, want_hidden=False, attend=None):
+def _make_paged_step(forwards, want_hidden=False, attend=None,
+                     block_size=None):
     cacheable = frozenset(i for i, u in enumerate(forwards)
                           if hasattr(u, "init_cache"))
     # units that keep per-slot state or count live rows are told which
     # slot each packed row is (-1: a padding row)
     by_slot = frozenset(i for i in cacheable
                         if hasattr(forwards[i], "cache_kind"))
+    # a sliding-window layer's ring is told the positions the step's
+    # block bucket covers: it reads no more of its rows
+    ringed = frozenset(i for i in by_slot
+                       if forwards[i].cache_kind == "window")
     # a tp step hands the units that declare a tp layout its
     # per-shard attention (ServingTP.decode_attention)
     told = frozenset(i for i in cacheable if attend is not None
@@ -148,7 +153,10 @@ def _make_paged_step(forwards, want_hidden=False, attend=None):
             if i in cacheable:
                 h, out[i] = u.apply_step_paged(
                     params[i], h, pos, tables, pools[i],
-                    **({"slots": slots} if i in by_slot else
+                    **({"slots": slots,
+                        "depth": tables.shape[1] * (block_size or 0)}
+                       if i in ringed else
+                       {"slots": slots} if i in by_slot else
                        {"attend": attend} if i in told else {}))
                 for name, rows in counted.items():
                     if name in out[i]:
@@ -357,7 +365,7 @@ def paged_decode_step(forwards, cache, toks, pos, tables, temps,
             cache_key, _StepClosure(_make_paged_step(
                 forwards, want_hidden=want_hidden,
                 attend=ctx.decode_attention if ctx is not None
-                else None)))
+                else None, block_size=cache.block_size)))
     if resolved is not None:
         resolved()
     old = cache.first_leaf()

@@ -233,6 +233,25 @@ def latent_units(forwards):
             and tuple(getattr(u, "cache_rows", ("k", "v"))) != ("k", "v")}
 
 
+def window_units(forwards):
+    """{chain index: unit name} of the cacheable units whose cache is a
+    RING of K/V rows a slot (``cache_kind == "window"``: a sliding-window
+    layer keeps its last ``window`` positions, the key of position p at
+    row p mod window), whatever the length of the request."""
+    return _units_of_kind(forwards, "window")
+
+
+def window_refusal(what, units):
+    """The error of asking, for a chain with a sliding-window layer's
+    ring a slot, for what moves blocks of K/V rows (``units``:
+    :func:`window_units`)."""
+    return ValueError(
+        "%s is not carried for a sliding-window layer's ring of K/V rows "
+        "a slot (%s): its programs move blocks behind a block table, and "
+        "a ring holds the last positions at rows of their own"
+        % (what, ", ".join(sorted(units.values()))))
+
+
 def latent_refusal(what, units):
     """The error of asking, for a chain with a paged layer whose rows are
     not a K and V pair, for what moves such pairs (``units``:
@@ -263,17 +282,20 @@ def stack_refusal(what, units):
 
 
 def blocks_only_refusal(what, state_units, stack_units,
-                        latent=None):
+                        latent=None, window=None):
     """The error of asking for ``what``, which moves blocks of ONE
     layer's K and V pair, on a chain that holds more than such blocks
     (:func:`slot_state_units`, :func:`stacked_units`,
-    :func:`latent_units`); None when the chain holds nothing else."""
+    :func:`latent_units`, :func:`window_units`); None when the chain
+    holds nothing else."""
     if state_units:
         return state_refusal(what, state_units)
     if stack_units:
         return stack_refusal(what, stack_units)
     if latent:
         return latent_refusal(what, latent)
+    if window:
+        return window_refusal(what, window)
     return None
 
 
@@ -358,7 +380,30 @@ class PagedKVCache:
     size a token) and :meth:`insert` (``serving.kv_insert_blocks``, one
     dispatch a layer) take the rows by their names and widths; the
     programs that move K and V pairs (int8 pools, export/import, the
-    host tier, the warm gather, a tp mesh) refuse it in words."""
+    host tier, the warm gather, a tp mesh) refuse it in words.
+
+    THE FOURTH KIND: A RING.  A sliding-window layer
+    (:func:`window_units`) keeps a ring of ``window`` K/V rows a slot,
+    ``[max_slots + 1, window, d]`` (row ``max_slots`` the trash ring of
+    padding rows), indexed by SLOT as a per-slot state is: position p at
+    row p mod window, so a slot never needs more rows and nothing is
+    claimed or freed while a request runs.  It costs no block:
+    admission and ``bytes_per_token`` count the paged layers alone,
+    :meth:`state_bytes` gives the rings as ``window_kv``.  :meth:`insert`
+    copies a staging ring (the prefill's, ``init_cache(1, ...)``: the
+    same ``window`` rows) whole into the slot's, one program whatever
+    the prompt's length.  Release needs no zeroing: a row is read at
+    the position it holds, from the newest position written, and a row
+    the occupant did not reach reads as a negative position, masked.
+    The programs that move blocks (int8 pools, export/import, the host
+    tier, the warm gather, a tp mesh) refuse it in words.
+
+    Inserts of paged rows move a power of two of blocks: the table's
+    blocks of the prompt, then as many trash ids (block 0) as fill the
+    bucket, where the staging row is wide enough to read them (it is a
+    power-of-two bucket itself, so a cold prompt's always is).  So the
+    inserts of prompts of any length compile one program a bucket, not
+    one a block count."""
 
     #: state-returning calls that came back / that copied
     pool_swaps = pool_copies = 0
@@ -385,6 +430,9 @@ class PagedKVCache:
         self.state_units = slot_state_units(forwards)
         self.stack_units = stacked_units(forwards)
         self.latent_units = latent_units(forwards)
+        self.window_units = window_units(forwards)
+        #: the units whose pool is indexed by slot: a state or a ring
+        self._by_slot = set(self.state_units) | set(self.window_units)
         #: the slot layers whose state is a matrix the delta rule
         #: advances (``delta_state``); the scheduler counts their states
         #: at each landed step, and :attr:`delta_fused` of them
@@ -413,7 +461,7 @@ class PagedKVCache:
         else:
             self.pools = {
                 i: u.init_cache(
-                    self.max_slots + 1 if i in self.state_units
+                    self.max_slots + 1 if i in self._by_slot
                     else num, self.block_size, dtypes.compute_dtype())
                 for i, u in enumerate(forwards)
                 if hasattr(u, "init_cache")}
@@ -489,7 +537,7 @@ class PagedKVCache:
         shards = self.tp_.size if self.tp_ is not None else 1
         total = 0
         for i, layer in self.pools.items():
-            if i in self.state_units:    # costs a slot, not a token
+            if i in self._by_slot:    # costs a slot, not a token
                 continue
             for name, arr in layer.items():
                 if name.endswith("_scale"):   # one scale per row
@@ -503,13 +551,16 @@ class PagedKVCache:
         """{"kv": bytes of the paged pools, "conv": bytes of the
         per-slot state pools' short-convolution rows, and a further
         kind for each other array a state unit keeps a slot (a
-        delta-rule layer's matrix ``S``), by its name} resident on the
-        devices.  Metadata alone (``nbytes``), so it answers from any
-        thread, also on a leaf that a step in flight has consumed."""
+        delta-rule layer's matrix ``S``), by its name; "window_kv":
+        the sliding-window layers' rings, where the chain has any}
+        resident on the devices.  Metadata alone (``nbytes``), so it
+        answers from any thread, also on a leaf that a step in flight
+        has consumed."""
         out = {"kv": 0, "conv": 0}
         for i, layer in self.pools.items():
             for name, a in layer.items():
-                kind = name if i in self.state_units else "kv"
+                kind = name if i in self.state_units \
+                    else "window_kv" if i in self.window_units else "kv"
                 out[kind] = out.get(kind, 0) + a.nbytes
         return out
 
@@ -545,7 +596,8 @@ class PagedKVCache:
 
     def _blocks_only(self, what):
         refused = blocks_only_refusal(what, self.state_units,
-                                      self.stack_units, self.latent_units)
+                                      self.stack_units, self.latent_units,
+                                      self.window_units)
         if refused is not None:
             raise refused
 
@@ -694,14 +746,24 @@ class PagedKVCache:
             raise ValueError(
                 "from_block %d leaves nothing of the %d-block insert"
                 % (f, need))
-        # a host COPY: on the CPU backend ``jnp.asarray`` of a numpy
-        # view may alias the host table, which ``release`` zeroes while
-        # the asynchronous scatter has yet to read its ids
-        ids = jnp.asarray(self.tables[slot, f:need].copy())
+        paged = [i for i in self.pools if i not in self._by_slot]
+        # a power of two of blocks, trash ids past the table's, where
+        # the staging row holds the rows to read (module docstring); a
+        # host COPY: on the CPU backend ``jnp.asarray`` of a numpy view
+        # may alias the host table, which ``release`` zeroes while the
+        # asynchronous scatter has yet to read its ids
+        wide = min((next(iter(row_caches[i].values())).shape[-2]
+                    for i in paged), default=0)
+        n = need - f
+        bucket = 1 << (n - 1).bit_length()
+        host = numpy.zeros((bucket if (f + bucket) * self.block_size
+                            <= wide else n,), numpy.int32)
+        host[:n] = self.tables[slot, f:need]
+        ids = jnp.asarray(host)
         start = jnp.int32(f * self.block_size)
         for i in self.pools:
             src = row_caches[i]
-            if i in self.state_units:
+            if i in self._by_slot:
                 self._insert_state(i, src, slot)
                 continue
             wk = next(iter(src.values())).shape[-2]

@@ -425,6 +425,16 @@ def _registry_series():
             "latent rows that decode steps read: the live rows' "
             "lengths, the new token's row included, x the chain's "
             "latent-attention layers"),
+        "window_kv_rows": metrics.counter(
+            "veles_serving_window_kv_rows_total",
+            "ring rows that decode steps' sliding-window attention read: "
+            "min(length, window) a live row, the new token's row "
+            "included, summed over the chain's sliding-window layers"),
+        "full_kv_rows": metrics.counter(
+            "veles_serving_full_kv_rows_total",
+            "K/V rows that decode steps' full attention read in a chain "
+            "with sliding-window layers: the live rows' lengths, the new "
+            "token's row included, x its paged K/V layers"),
         "stack_passes": metrics.counter(
             "veles_serving_stack_passes_total",
             "passes of a looped stack that decode steps ran: decode "
@@ -1430,7 +1440,8 @@ class ServingMetrics:
 
     def record_step(self, active, slots, tokens=None, moe=None,
                     stack=None, state_units=0, latent_rows=0,
-                    delta_rows=0, delta_fused=0):
+                    delta_rows=0, delta_fused=0, window_rows=0,
+                    full_rows=0):
         """One batched decode/verify boundary: ``active`` real rows
         rode a padded ``slots``-row bucket; ``tokens`` is what the
         step actually emitted (spec verify can emit up to k+1 per
@@ -1449,6 +1460,9 @@ class ServingMetrics:
         ``delta_rows``: the matrix states its delta-rule layers advanced
         (live rows x those layers); ``delta_fused``: how many of them
         the Pallas call advanced.
+        ``window_rows`` / ``full_rows``: the K/V rows a chain with
+        sliding-window layers read, in its rings and in its paged
+        layers (``InferenceScheduler._kv_rows``).
         ``stack``: a looped stack's counts of a decode step, float
         [stacks, 2 + passes] = (passes run, live rows, the live rows'
         exit mass of each pass)."""
@@ -1475,6 +1489,9 @@ class ServingMetrics:
             self._global["delta_state_fused"].inc(int(delta_fused))
         if latent_rows:
             self._global["latent_rows"].inc(int(latent_rows))
+        if window_rows or full_rows:
+            self._global["window_kv_rows"].inc(int(window_rows))
+            self._global["full_kv_rows"].inc(int(full_rows))
         now = time.monotonic()
         with self._lock:
             self.slot_busy_steps += int(active)

@@ -24,7 +24,7 @@ import numpy
 
 from veles_tpu.models.generate import (
     _StepClosure, _arch_sig, _check_positions, _device_params)
-from veles_tpu.serving.kv_slots import slot_state_units
+from veles_tpu.serving.kv_slots import slot_state_units, window_units
 from veles_tpu.telemetry import trace_named, track_jit
 
 
@@ -165,7 +165,8 @@ def prefill_chunk(forwards, chunk, offset, chunk_lens, caches,
         params = _device_params(forwards)
     chunk = jnp.asarray(chunk, jnp.int32)
     b, c = chunk.shape
-    state = slot_state_units(forwards)   # a fixed state has no width
+    # a fixed state, or a sliding-window layer's ring, has no width
+    state = set(slot_state_units(forwards)) | set(window_units(forwards))
     widths = {tuple(a.shape[-2] for a in layer.values())
               for i, layer in caches.items() if i not in state}
     w = next(iter(widths))[0]

@@ -249,7 +249,7 @@ from veles_tpu.serving.engine import (
 from veles_tpu.serving.kv_host import HostKVTier
 from veles_tpu.serving.kv_slots import (
     PagedKVCache, blocks_only_refusal, latent_units, slot_state_units,
-    stacked_units)
+    stacked_units, window_units)
 from veles_tpu.serving.metrics import (
     LOOP_PARTS, LOOP_PHASES, ServingMetrics)
 from veles_tpu.serving.prefill import (
@@ -738,6 +738,10 @@ class InferenceScheduler(Logger):
         #: layer's one row a token): the same options are refused or
         #: turned off the same way
         self._latent_units = latent_units(forwards)
+        #: sliding-window layers' rings a slot: the same options are
+        #: refused or turned off the same way; a ring costs its slot and
+        #: no block, so admission counts the paged layers alone
+        self._window_units = window_units(forwards)
         self.block_size = int(
             block_size or _serving_conf("block_size", 16))
         if self.block_size < 1:
@@ -1001,16 +1005,19 @@ class InferenceScheduler(Logger):
         turns on is turned off and logged; never run wrongly."""
         value = default if asked is None else asked
         if not value or not (self._state_units or self._stack_units
-                             or self._latent_units):
+                             or self._latent_units
+                             or self._window_units):
             return value
         if asked is not None:
             raise blocks_only_refusal(what, self._state_units,
                                       self._stack_units,
-                                      self._latent_units)
+                                      self._latent_units,
+                                      self._window_units)
         self.info("%s off: not carried for %s", what, ", ".join(sorted(
             list(self._state_units.values())
             + list(self._stack_units.values())
-            + list(self._latent_units.values()))))
+            + list(self._latent_units.values())
+            + list(self._window_units.values()))))
         return type(value)()
 
     # -- client side ----------------------------------------------------
@@ -3170,6 +3177,8 @@ class InferenceScheduler(Logger):
                 n, b, tokens=n, state_units=len(self._state_units),
                 latent_rows=len(self._latent_units) * int(sum(
                     flight.lens[j] for j, _, _ in rows)),
+                **self._kv_rows(cache, [flight.lens[j]
+                                        for j, _, _ in rows]),
                 delta_rows=n * cache.delta_layers,
                 delta_fused=n * cache.delta_fused, **counts)
             self._meter_step({slot: req for _, slot, req in rows},
@@ -3189,6 +3198,21 @@ class InferenceScheduler(Logger):
                     emitted[req.trace] = emitted.get(req.trace, 0) + 1
                 reqtrace.record_step(emitted, duration=dt,
                                      mode="decode", slots=n, bucket=b)
+
+    def _kv_rows(self, cache, lens):
+        """A chain with sliding-window layers: the K/V rows its decode
+        attention read, given the live rows' lengths ``lens`` (the new
+        token's row included) -- ``window_rows``: ``min(len, window)``
+        a row and ring, ``full_rows``: ``len`` a row and paged K/V
+        layer.  Nothing for any other chain."""
+        if not self._window_units:
+            return {}
+        rings = [self.forwards[i].window for i in self._window_units]
+        full = len(cache.pools) - len(self._window_units) \
+            - len(self._state_units) - len(self._latent_units)
+        return {"window_rows": sum(int(min(n, w)) for w in rings
+                                   for n in lens),
+                "full_rows": full * int(sum(lens))}
 
     def _step_verify(self, cache, active, drafts, sources):
         """Speculative step: every active slot rides ONE batched

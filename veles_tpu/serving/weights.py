@@ -51,6 +51,9 @@ class ServingWeights:
         self.bytes_by_dtype = {}
         self.params = {}
         self._owned = []
+        #: {id(Array): leaf} of the leaves placed so far: an array that
+        #: two units read (a tied table) is held once, in both places
+        self._placed = {}
         try:
             for i, u in enumerate(forwards):
                 self.params[i] = self._layer(u, tp)
@@ -70,6 +73,9 @@ class ServingWeights:
             if tp is not None else None
         layer = {}
         for name, arr in unit.param_arrays().items():
+            if id(arr) in self._placed:
+                layer[name] = self._placed[id(arr)]
+                continue
             # the CURRENT device value: the host mirror can be stale
             # after training until a map_read
             leaf = mine = arr.devmem
@@ -94,13 +100,14 @@ class ServingWeights:
             key = str(leaf.dtype)
             self.bytes_by_dtype[key] = self.bytes_by_dtype.get(key, 0) \
                 + sum(nbytes for _, nbytes in resident)
-            layer[name] = leaf
+            layer[name] = self._placed[id(arr)] = leaf
         return layer
 
     def close(self):
         """Drop the pytree.  The device buffers go with the last
         reference: the compiled-step caches hold none."""
         self.params = None
+        self._placed = {}
         for dev, nbytes in self._owned:
             Watcher.free(dev, nbytes)
         self._owned = []
